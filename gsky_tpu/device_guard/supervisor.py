@@ -319,10 +319,7 @@ class DeviceSupervisor:
             if backend not in ("cpu",):
                 # a real accelerator rebuild must not reuse executables
                 # compiled against the pre-incident device state
-                try:
-                    jax.clear_caches()
-                except Exception:  # cache clear is best-effort on older jax
-                    pass
+                jax.clear_caches()
             supervised_sync(
                 "device.probe",
                 lambda: jax.block_until_ready(

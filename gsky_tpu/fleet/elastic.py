@@ -323,7 +323,10 @@ class LocalSubprocessProvider(NodeProvider):
     def launch(self) -> str:
         port = self.free_port()
         addr = f"{self.host}:{port}"
-        env = {**os.environ, **self.extra_env,
+        # a locally launched worker is a child of the process that
+        # holds this host's chip, so it is TOLD the CPU; a launcher that
+        # owns other chips says so through extra_env
+        env = {**os.environ, "JAX_PLATFORMS": "cpu", **self.extra_env,
                "GSKY_ELASTIC_SELF": addr}
         out = subprocess.DEVNULL
         if self.log_dir:

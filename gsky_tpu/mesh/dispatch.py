@@ -45,7 +45,7 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..obs.metrics import (MESH_CHIP_OCCUPANCY, MESH_SHARD_SKEW_MS,
@@ -170,7 +170,7 @@ class MeshDispatcher:
             local, mesh=self.mesh,
             in_specs=(P(), P(MESH_AXES), P(MESH_AXES), P(MESH_AXES),
                       P(MESH_AXES)),
-            out_specs=P(MESH_AXES), check_rep=False)
+            out_specs=P(MESH_AXES), check_vma=False)
         return jax.jit(fn)
 
     def _build_wave_expr(self, method, n_ns, out_hw, step, auto,
@@ -193,7 +193,7 @@ class MeshDispatcher:
             local, mesh=self.mesh,
             in_specs=(P(), P(MESH_AXES), P(MESH_AXES), P(MESH_AXES),
                       P(MESH_AXES), P(MESH_AXES)),
-            out_specs=P(MESH_AXES), check_rep=False)
+            out_specs=P(MESH_AXES), check_vma=False)
         return jax.jit(fn)
 
     def _build_wave_expr_sb(self, method, n_ns, out_hw, step, auto,
@@ -212,7 +212,7 @@ class MeshDispatcher:
             local, mesh=self.mesh,
             in_specs=(P(), P(MESH_AXES), P(MESH_AXES), P(MESH_AXES),
                       P(MESH_AXES), P(MESH_AXES), P(MESH_AXES)),
-            out_specs=P(MESH_AXES), check_rep=False)
+            out_specs=P(MESH_AXES), check_vma=False)
         return jax.jit(fn)
 
     def _build_wave_scored(self, method, n_ns, out_hw, step, T,
@@ -232,7 +232,7 @@ class MeshDispatcher:
         fn = shard_map(
             local, mesh=self.mesh,
             in_specs=(P(), P(MESH_AXES), P(MESH_AXES), P(MESH_AXES)),
-            out_specs=(P(MESH_AXES), P(MESH_AXES)), check_rep=False)
+            out_specs=(P(MESH_AXES), P(MESH_AXES)), check_vma=False)
         return jax.jit(fn)
 
     def _build_wave_byte_sb(self, method, n_ns, out_hw, step, auto,
@@ -255,7 +255,7 @@ class MeshDispatcher:
             local, mesh=self.mesh,
             in_specs=(P(), P(MESH_AXES), P(MESH_AXES), P(MESH_AXES),
                       P(MESH_AXES), P(MESH_AXES)),
-            out_specs=P(MESH_AXES), check_rep=False)
+            out_specs=P(MESH_AXES), check_vma=False)
         return jax.jit(fn)
 
     def _build_wave_scored_sb(self, method, n_ns, out_hw, step, T,
@@ -274,7 +274,7 @@ class MeshDispatcher:
             local, mesh=self.mesh,
             in_specs=(P(), P(MESH_AXES), P(MESH_AXES), P(MESH_AXES),
                       P(MESH_AXES)),
-            out_specs=(P(MESH_AXES), P(MESH_AXES)), check_rep=False)
+            out_specs=(P(MESH_AXES), P(MESH_AXES)), check_vma=False)
         return jax.jit(fn)
 
     # -- per-layout dispatch -------------------------------------------

@@ -185,7 +185,9 @@ def decode_token(token_repr: str):
 def stats() -> Dict:
     """The /debug "kernels" block + the bench/probe dump: ledger path,
     per-kernel verdict counts and entries, and the in-process race
-    state."""
+    state — in counts (``session``) and by name
+    (`pallas_tpu.kernel_state`: failed / demoted / promoted /
+    lowered), so a fallback is readable beside the durable verdicts."""
     path = ledger_path()
     doc: Dict = {"ledger_path": path,
                  "ledger_present": os.path.exists(path), "kernels": {}}
@@ -207,6 +209,7 @@ def stats() -> Dict:
             "failed_kernels": sorted(pt._FAILED),
             "demoted_pairs": len(pt._SLOW),
             "proven_pairs": len(pt._PROVEN)}
+        doc.update(pt.kernel_state())
     except Exception:   # observability must never fail a request
         pass
     return doc

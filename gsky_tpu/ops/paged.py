@@ -26,8 +26,9 @@ indirection:
   (affine -> true-extent oob NaN-poisoning -> page-table gather ->
   tap-side validity -> strictly-greater priority mosaic -> optional
   byte-scale epilogue), so parity transfers: nearest is bit-exact and
-  interpolated methods are <= 2 ulp vs the XLA reference
-  (tests/test_paged.py).
+  interpolated methods are <= 2 ulp vs the XLA reference — for cubic,
+  whose negative weights let the sum cancel, 2 ulp of the tap
+  magnitude (tests/test_paged.py).
 
 Shape axes that remain static are RAGGED-PADDED, not shape-bucketed:
 the granule axis pads to the pow2 of the LARGEST tile in the dispatch
@@ -52,9 +53,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .pallas_tpu import (_HAVE_PLTPU, _WARP_BLK, _WARP_VMEM_BUDGET,
+from .pallas_tpu import (_WARP_BLK, _WARP_VMEM_BUDGET, _note_lowered,
                          pallas_interpret, pltpu, run_with_fallback,
-                         use_pallas)
+                         warp_pallas_enabled)
 
 # token scheme version for paged-kernel ledger verdicts: bump when the
 # paged program's meaning changes (page walk, params layout) so old
@@ -101,20 +102,23 @@ def page_slots() -> int:
 
 
 def paged_enabled() -> bool:
-    """Paged dispatch gate: on by default wherever the pallas kernels
-    run (real TPU or GSKY_PALLAS=interpret); GSKY_PAGED=0 restores the
-    bucketed path byte-identically.  XLA-only serving (plain CPU)
-    keeps buckets — the paged walk is a pallas formulation."""
-    return os.environ.get("GSKY_PAGED", "1") != "0" and use_pallas()
+    """Paged dispatch gate: on wherever the paged pallas kernels are
+    selectable (`warp_pallas_enabled` — today GSKY_PALLAS=interpret
+    only, because Mosaic refuses their gather on a real TPU);
+    GSKY_PAGED=0 restores the bucketed path byte-identically.  XLA
+    serving (plain CPU, and the TPU until the gather is repaired) keeps
+    buckets — the paged walk is a pallas formulation."""
+    return os.environ.get("GSKY_PAGED", "1") != "0" \
+        and warp_pallas_enabled()
 
 
 def paged_vmem_ok(slots: int, n_ns: int, pr: int, pc: int,
                   blk=None) -> bool:
     """Eligibility gate, checked BEFORE the race: a page list too big
-    for VMEM must go to the bucketed path, not burn the kernel-name
-    blacklist on a predictable OOM.  ``blk`` is the (block_h, block_w)
-    output tile the cost model picked; None keeps the fixed
-    `_WARP_BLK` square."""
+    for VMEM must go to the bucketed path, not through the kernel
+    failure handler on a predictable over-allocation.  ``blk`` is the
+    (block_h, block_w) output tile the cost model picked; None keeps
+    the fixed `_WARP_BLK` square."""
     bh, bw = blk if blk is not None else (_WARP_BLK, _WARP_BLK)
     pages = slots * pr * pc * 4 * 2          # page block, x2 DMA
     acc = n_ns * bh * bw * 4 * 2 * 2         # canv+best
@@ -308,6 +312,7 @@ def _paged_scored(pool, tables, params, ctrls, method, n_ns, out_hw,
     transfers unconditionally.  ``blk`` retiles the output grid from
     the cost model; None keeps the fixed `_WARP_BLK` square."""
     from .warp import _bilerp_grid
+    _note_lowered("warp_scored_paged", interpret)
     bh, bw = blk if blk is not None else (_WARP_BLK, _WARP_BLK)
     h, w = out_hw
     T, S = int(tables.shape[1]), int(tables.shape[2])
@@ -328,12 +333,11 @@ def _paged_scored(pool, tables, params, ctrls, method, n_ns, out_hw,
         sx = jnp.pad(sx, ((0, 0), (0, hp - h), (0, wp - w)))
         sy = jnp.pad(sy, ((0, 0), (0, hp - h), (0, wp - w)))
     kernel = _paged_render_kernel(method, n_ns, T, S, pr, pc)
-    if _HAVE_PLTPU and not interpret:
-        params_spec = pl.BlockSpec(
-            memory_space=getattr(pltpu, "SMEM", None))
-    else:
+    if interpret:
         params_spec = pl.BlockSpec((N * T, PARAMS_W),
                                    lambda n, i, j, t: (0, 0))
+    else:
+        params_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
     canv, best = pl.pallas_call(
         kernel,
         grid=(N, hp // bh, wp // bw, T),

@@ -25,7 +25,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from ..ops.scale import auto_byte_scale
 from ..ops.warp import _METHODS
@@ -141,7 +141,7 @@ def make_sharded_render(mesh: Mesh, method: str = "near",
                   P(AXIS_GRANULE, None, AXIS_X),
                   P()),
         out_specs=P(None, AXIS_X, None),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(step)
 
 
@@ -214,5 +214,5 @@ def make_sharded_drill(mesh: Mesh) -> Callable:
                   P(AXIS_GRANULE, None, AXIS_X),
                   P(None, AXIS_X)),
         out_specs=(P(AXIS_GRANULE), P(AXIS_GRANULE)),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(step)

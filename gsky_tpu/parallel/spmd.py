@@ -49,7 +49,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from ..ops.scale import auto_byte_scale, scale_to_byte
 from ..ops.warp import _bilerp_grid, _warp_scenes_scored
@@ -147,7 +147,7 @@ class SpmdRenderer:
             in_specs=(P(AXIS_GRANULE, None, None), P(), P(AXIS_GRANULE),
                       P()),
             out_specs=(P(None, None, AXIS_X), P(None, None, AXIS_X)),
-            check_rep=False)
+            check_vma=False)
         return jax.jit(fn)
 
     # -- production entries ------------------------------------------------
@@ -223,7 +223,7 @@ class SpmdRenderer:
             in_specs=(P(AXIS_GRANULE, None, None), P(), P(AXIS_GRANULE),
                       P(), P()),
             out_specs=P(None, AXIS_X),
-            check_rep=False)
+            check_vma=False)
         return jax.jit(fn)
 
     def render_composite(self, stack, ctrl, params, scale_params,
@@ -269,7 +269,7 @@ class SpmdRenderer:
             in_specs=(P(AXIS_GRANULE, AXIS_X), P(AXIS_GRANULE, AXIS_X),
                       P()),
             out_specs=(P(AXIS_GRANULE), P(AXIS_GRANULE)),
-            check_rep=False)
+            check_vma=False)
         return jax.jit(fn)
 
     def masked_stats(self, dataf, validf, clip_lower: float,

@@ -18,6 +18,7 @@ Reference dataflow: DrillIndexer -> GeoDrillGRPC -> DrillMerger
 
 from __future__ import annotations
 
+import logging
 import math
 import xml.etree.ElementTree as ET
 from collections import defaultdict
@@ -36,6 +37,8 @@ from ..io.netcdf import NetCDF
 from ..ops import drill as D
 from ..ops.raster import nodata_mask
 from .types import DrillResult, GeoDrillRequest
+
+log = logging.getLogger("gsky.drill")
 
 _BIG = 3.0e38
 
@@ -473,6 +476,9 @@ def _drill_file(ds: Dataset, sel: List[int], g4326: geom.Geometry,
         # reductions run on device, and this request ships only the
         # polygon mask + timestep indices — KBs instead of the
         # (B, window) raster through the host link
+        # which leg answered lands in /debug executor.dispatches,
+        # beside the render legs
+        from .executor import default_executor
         if not is_vrt:
             from . import drill_cache as DC
             if DC.enabled():
@@ -489,12 +495,19 @@ def _drill_file(ds: Dataset, sel: List[int], g4326: geom.Geometry,
                         if st is not None else None
                 except Exception:
                     # any device-path failure (upload OOM, compile)
-                    # degrades to host reads, not a failed request
+                    # degrades to host reads, not a failed request —
+                    # but loudly, and counted
+                    log.exception("drill device path failed for %s; "
+                                  "answering from host reads",
+                                  ds.file_path)
+                    default_executor._count("drill_device_error")
                     dev = None
                 if dev is not None:
+                    default_executor._count("drill_device")
                     vals, counts, dec = dev
                     return _maybe_interp(vals, counts, dec, read_idx,
                                          sel, stride, req)
+        default_executor._count("drill_host")
 
         bands_data = []
         for k in read_idx:

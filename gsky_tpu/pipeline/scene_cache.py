@@ -2,10 +2,9 @@
 
 The reference amortises IO with a per-process GDAL block cache
 (`worker/gdalprocess/warp.go:278-332`); the TPU-native analogue keeps whole
-decoded scenes in HBM.  Host->device upload is the scarcest resource when
-the accelerator sits behind a network link (measured ~10-40 MB/s with
-~90 ms/MB serial latency), while HBM is plentiful — so each (path, band)
-source raster is decoded and shipped ONCE — NaN-encoded f32, invalid
+decoded scenes in HBM.  Decode and host->device upload are paid per
+byte while HBM is plentiful — so each (path, band) source raster is
+decoded and shipped ONCE — NaN-encoded f32, invalid
 pixels pre-baked to NaN so per-dispatch validity is one isnan on the
 gathered tap — and every subsequent tile request warps from the cached
 device array (`ops.warp.warp_scenes_batch`) with only a ~2 KB

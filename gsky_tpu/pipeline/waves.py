@@ -1,13 +1,11 @@
 """Wave-level device serving: amortise the per-dispatch host tax
 across whole admission waves.
 
-BENCH_r05 (PERF.md) measured the per-dispatch overhead on a real v5e:
-a 256px mosaic tile costs ~78.8 ms synchronous against ~12.8 ms
-pipelined, and a 1000-point drill ~73.4 ms against ~4.7 ms — the
-device is idle most of every request; the ~75 ms is host-side dispatch
-tax (upload enqueue, program launch, sync) paid PER CALL.  The ragged
-paged kernels (ops/paged.py) already serve any tile shape from one
-program, so nothing but the call convention forces tax-per-tile.
+Per-call serving pays a host-side dispatch tax (upload enqueue,
+program launch, sync) PER CALL while the device sits idle; what that
+tax is on a directly attached v5e is not measured (root PERF.md).  The
+ragged paged kernels (ops/paged.py) already serve any tile shape from
+one program, so nothing but the call convention forces tax-per-tile.
 
 This module stops dispatching per tile/drill.  Every scheduler tick,
 everything currently eligible — WMS tile renders, drill reductions,
@@ -28,9 +26,8 @@ invocation per result kind:
   (kind, statics) program family);
 - the DISPATCH stage pops staged waves off a host-written wave queue
   and enqueues the device programs back-to-back, so wave N+1 plans,
-  stacks, and uploads while wave N executes — the inter-wave host gap
-  the r05 record measured as 0.01–3.5% HBM utilisation
-  (docs/PERF.md "Continuous device occupancy");
+  stacks, and uploads while wave N executes, closing the inter-wave
+  host gap (docs/PERF.md "Continuous device occupancy");
 - results land in an on-device `OutputRing` (donated in/out buffers,
   ops/paged.py) that persists ACROSS waves — pow2-padded result
   blocks reuse the same ring lanes wave after wave — and a readback

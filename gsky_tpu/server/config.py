@@ -340,11 +340,6 @@ class ServiceConfig:
     # MAS index HTTP timeout (seconds); further clamped per request by
     # the resilience deadline budget
     mas_timeout: int = 60
-    # persistent XLA compilation cache directory: compiled render
-    # programs survive process restarts, so the shape-bucket prewarm
-    # after a rolling restart loads from disk instead of recompiling
-    # (env GSKY_JAX_CACHE_DIR overrides; empty = in-memory only)
-    jax_compilation_cache_dir: str = ""
 
 
 @dataclass
@@ -547,8 +542,6 @@ def load_config_file(path: str, namespace: str = "") -> Config:
             max_grpc_buffer_size=int(sc.get("max_grpc_buffer_size") or 0),
             namespace=namespace,
             mas_timeout=_int_or(sc.get("mas_timeout"), 60),
-            jax_compilation_cache_dir=sc.get(
-                "jax_compilation_cache_dir", ""),
         ),
         layers=[Layer.from_json(l) for l in j.get("layers", []) or []],
         processes=[ProcessConfig.from_json(p)

@@ -15,7 +15,11 @@ from .metrics import MetricsLogger
 from .ows import OWSServer
 
 
-def main(argv=None):
+def main(argv=None, run_app=web.run_app):
+    """gsky-ows.  ``run_app`` is aiohttp's blocking runner; chip_smoke.py
+    substitutes a driver that serves the same app on a background loop,
+    sends it requests and returns — so the smoke boots through every
+    start-up step an operator's process does."""
     # GSKY_TSAN=1: patch threading.Lock/RLock BEFORE any server lock
     # exists so every lock participates in lockset race tracking
     from ..obs import tsan
@@ -71,12 +75,17 @@ def main(argv=None):
                              default=str)[:100000])
         return 0
 
-    from ..device import ensure_platform
-    plat = ensure_platform()
-    if plat["fallback"]:
-        print("accelerator unreachable after "
-              f"{plat['probe_attempts']} probe(s); serving on CPU",
-              file=sys.stderr)
+    # this process takes the chip (JAX_PLATFORMS=cpu: it was told the
+    # worker holds it); no TPU and no such instruction is an error
+    from ..device import PlatformError, ensure_platform
+    try:
+        plat = ensure_platform()
+    except PlatformError as e:
+        print(f"gsky-ows: {e}", file=sys.stderr)
+        return 1
+    print(f"gsky-ows: platform {plat['platform']} "
+          f"({plat['device_kind']} x{plat['device_count']}), "
+          f"compile cache {plat['cache_dir']}")
 
     if args.log_dir:
         # durable kernel race verdicts live next to the metrics log
@@ -90,13 +99,15 @@ def main(argv=None):
         from ..device_guard import journal
         journal.set_default_dir(args.log_dir)
 
-    # persistent compilation cache + shape-bucket prewarm: every
-    # bucketed render program the configured layers can dispatch is
-    # compiled BEFORE the listen socket opens, so the first burst of
-    # real traffic sees zero compile stalls (GSKY_PREWARM=0 skips)
-    from .prewarm import prewarm_from_watcher
-    warm = prewarm_from_watcher(watcher)
-    if warm is not None:
+    # shape-bucket prewarm: every bucketed render program the
+    # configured layers can dispatch is compiled BEFORE the listen
+    # socket opens, so the first burst of real traffic sees zero
+    # compile stalls (GSKY_PREWARM=0 skips).  A failure here raises:
+    # the server does not come up on a path it knows is broken
+    from .prewarm import prewarm, prewarm_enabled
+    warm = None
+    if prewarm_enabled():
+        warm = prewarm(watcher.configs)
         print(f"prewarm: {warm['programs']} program(s) for "
               f"{warm['specs']} layer spec(s) in {warm['seconds']}s "
               f"({warm['compiles']} fresh compile(s))")
@@ -104,6 +115,7 @@ def main(argv=None):
     metrics = MetricsLogger(args.log_dir, verbose=args.verbose)
     server = OWSServer(watcher, mas_factory, metrics,
                        static_dir=args.static, temp_dir=args.temp_dir)
+    server.prewarm = warm        # /debug "prewarm" block
     app = server.app()
 
     # graceful drain on SIGTERM/SIGINT: aiohttp's run_app stops the
@@ -122,10 +134,10 @@ def main(argv=None):
     # when the client drops the connection; the end-to-end cancellation
     # path (resilience/cancel.py) depends on that CancelledError to
     # fire the request's token and reclaim permits/pins/threads
-    web.run_app(app, host=args.host, port=args.port,
-                handler_cancellation=True,
-                print=lambda *a: print(
-                    f"gsky-ows listening on {args.host}:{args.port}"))
+    run_app(app, host=args.host, port=args.port,
+            handler_cancellation=True,
+            print=lambda *a: print(
+                f"gsky-ows listening on {args.host}:{args.port}"))
     return 0
 
 

@@ -153,6 +153,8 @@ class OWSServer:
         # graceful drain (SIGTERM): the accept gate for /ows requests —
         # /debug keeps answering so operators can watch the drain land
         self.drain = DrainController("ows")
+        # start-up prewarm result (main.py sets it): /debug "prewarm"
+        self.prewarm: Optional[Dict] = None
         # cache fabric (docs/FABRIC.md): peer replay of encoded
         # responses across gateways.  Default: built from env when the
         # master gate + peer list are set; explicit instances let the
@@ -363,12 +365,16 @@ class OWSServer:
 
     async def _debug(self, request: web.Request) -> web.Response:
         doc = self.metrics.summary()
-        try:
-            import jax
-            doc["jax"] = {"backend": jax.default_backend(),
-                          "devices": len(jax.devices())}
-        except Exception:  # jax absent or unbooted - /debug still serves
-            pass
+        import jax
+        from .prewarm import compile_count
+        doc["jax"] = {"backend": jax.default_backend(),
+                      "device_kind": jax.devices()[0].device_kind,
+                      "devices": len(jax.devices()),
+                      "cache_dir": jax.config.jax_compilation_cache_dir,
+                      # fresh backend compiles since the probe went in
+                      # at prewarm (persistent-cache hits do not count)
+                      "compiles": compile_count()}
+        doc["prewarm"] = self.prewarm
         try:
             from ..parallel.spmd import spmd_enabled
             doc["spmd"] = spmd_enabled()

@@ -1,29 +1,28 @@
-"""Server-start shape-bucket prewarm + persistent compilation cache.
+"""Server-start shape-bucket prewarm.
 
 The staged tile path (`pipeline/tile_stages.py`) removes host stalls
 from the GetMap hot path, but the FIRST request of every
 (kernel, shape-bucket, statics) combination still pays an XLA compile —
 hundreds of milliseconds to seconds of latency a client sees as a
-timeout spike after every deploy.  This module eliminates that cliff
-twice over:
+timeout spike after every deploy.  `prewarm` walks the configured
+layers/styles at server start and compiles every bucketed render
+program they can dispatch — the same entry points the executor calls
+(`render_byte_raced`, `warp_scored_raced`, `render_rgba_ctrl`,
+`render_scenes_bands_ctrl`) at the shapes the scene cache buckets to
+(pixel dims padded to multiples of 256, batch dims to powers of two).
+The raced entry points also run their pallas-vs-XLA race here, so the
+kernel ledger's verdict lands off the request path too.  Compiled
+programs survive restarts through jax's persistent compilation cache,
+which `gsky_tpu.device.ensure_platform` places for every entry point.
 
-1. `configure_compilation_cache` wires jax's persistent compilation
-   cache (`service_config.jax_compilation_cache_dir`, env
-   GSKY_JAX_CACHE_DIR overrides) so compiled programs survive process
-   restarts entirely.
-2. `prewarm` walks the configured layers/styles at server start and
-   compiles every bucketed render program they can dispatch — the same
-   entry points the executor calls (`render_byte_raced`,
-   `warp_scored_raced`, `render_rgba_ctrl`, `render_scenes_bands_ctrl`)
-   at the shapes the scene cache buckets to (pixel dims padded to
-   multiples of 256, batch dims to powers of two).  The raced entry
-   points also run their pallas-vs-XLA race here, so the kernel
-   ledger's verdict lands off the request path too.
+A program that fails to compile here, or a pallas kernel that lands in
+`pallas_tpu._FAILED` during the sweep, raises `PrewarmError`: the
+server does not start on a path it already knows is broken.
 
 `install_compile_probe` counts fresh backend compiles in this process
-via `jax.monitoring` — `compile_count()` deltas back the
-zero-recompile assertions in tests/test_tile_pipeline.py and
-`tools/soak.py --scenario burst`.
+via `jax.monitoring` (compile requests minus persistent-cache hits) —
+`compile_count()` deltas back the zero-recompile assertions in
+tests/test_tile_pipeline.py and `tools/soak.py --scenario burst`.
 
 Under paged serving (GSKY_PAGED on a pallas-capable backend,
 ops/paged.py) the single-band sweep collapses: instead of one program
@@ -86,29 +85,40 @@ import numpy as np
 log = logging.getLogger("gsky.prewarm")
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _lock = threading.Lock()
 _compiles = 0
 _probe_installed = False
 
 
-def _on_event(event: str, duration: float, **kw) -> None:
+def _on_duration(event: str, duration: float, **kw) -> None:
     global _compiles
     if event == _COMPILE_EVENT:
         with _lock:
             _compiles += 1
 
 
+def _on_event(event: str, **kw) -> None:
+    global _compiles
+    if event == _CACHE_HIT_EVENT:
+        with _lock:
+            _compiles -= 1
+
+
 def install_compile_probe() -> None:
     """Count fresh XLA backend compiles in this process (idempotent).
-    Persistent-cache HITS do not fire this event, so the counter
-    isolates genuinely new compilation work."""
+    jax times every compile REQUEST under the compile event, whether
+    the persistent cache answers it or the backend does, and records a
+    cache-hit event inside that window — so fresh compiles are requests
+    minus hits."""
     global _probe_installed
     with _lock:
         if _probe_installed:
             return
         _probe_installed = True
     import jax.monitoring
-    jax.monitoring.register_event_duration_secs_listener(_on_event)
+    jax.monitoring.register_event_duration_secs_listener(_on_duration)
+    jax.monitoring.register_event_listener(_on_event)
 
 
 def compile_count() -> int:
@@ -121,31 +131,8 @@ def prewarm_enabled() -> bool:
     return os.environ.get("GSKY_PREWARM", "1") != "0"
 
 
-def configure_compilation_cache(path: str) -> bool:
-    """Point jax's persistent compilation cache at ``path`` (env
-    GSKY_JAX_CACHE_DIR wins over the config value).  Thresholds are
-    zeroed so even the small byte-scaling programs persist — a render
-    program cached at 10 ms compile time is still a 10 ms stall saved
-    on every future cold start."""
-    path = os.environ.get("GSKY_JAX_CACHE_DIR", "") or path
-    if not path:
-        return False
-    try:
-        os.makedirs(path, exist_ok=True)
-    except OSError as e:
-        log.warning("compilation cache dir %s unusable: %s", path, e)
-        return False
-    import jax
-    ok = True
-    for k, v in (("jax_compilation_cache_dir", path),
-                 ("jax_persistent_cache_min_entry_size_bytes", -1),
-                 ("jax_persistent_cache_min_compile_time_secs", 0.0)):
-        try:
-            jax.config.update(k, v)
-        except Exception as e:   # older jax: knob may not exist
-            log.warning("jax config %s: %s", k, e)
-            ok = False
-    return ok
+class PrewarmError(RuntimeError):
+    """A program of the prewarm sweep failed to compile or run."""
 
 
 def _env_list(name: str, default: str) -> List[int]:
@@ -283,12 +270,14 @@ def prewarm(configs: Dict,
     hit, through the SAME entry points the executor dispatches.  Safe
     to call on a serving process (pure compile + one throwaway run per
     program).  Returns {"specs", "programs", "failures", "compiles",
-    "seconds"}."""
+    "seconds"}; raises `PrewarmError` when any program or pallas
+    kernel failed."""
     import jax.numpy as jnp
     from ..ops.paged import (page_slots, paged_enabled, paged_vmem_ok,
                              render_byte_paged_raced,
                              warp_scored_paged_raced)
-    from ..ops.pallas_tpu import render_byte_raced, warp_scored_raced
+    from ..ops.pallas_tpu import (_FAILED, render_byte_raced,
+                                  warp_scored_raced)
     from ..ops.warp import (render_rgba_ctrl, render_scenes_bands_ctrl,
                             render_scenes_ctrl, warp_scenes_ctrl_scored)
     from ..pipeline.executor import _bucket_pow2
@@ -305,6 +294,7 @@ def prewarm(configs: Dict,
     step = 16
     specs = layer_specs(configs)
     programs = failures = 0
+    failed0 = set(_FAILED)
 
     def run(fn, *args, **kw):
         nonlocal programs, failures
@@ -314,9 +304,9 @@ def prewarm(configs: Dict,
                 if hasattr(leaf, "block_until_ready"):
                     leaf.block_until_ready()
             programs += 1
-        except Exception as e:
+        except Exception:
             failures += 1
-            log.warning("prewarm %s: %s", getattr(fn, "__name__", fn), e)
+            log.exception("prewarm %s", getattr(fn, "__name__", fn))
 
     for method, n_exprs, auto, colour_scale in sorted(specs):
         for hw in sizes:
@@ -606,9 +596,9 @@ def prewarm(configs: Dict,
                     pool, specs, sizes, batches, slot_sweep,
                     wave_size_lattice(), step)
                 programs += mesh_programs
-            except Exception as e:
+            except Exception:
                 failures += 1
-                log.warning("prewarm mesh lattice: %s", e)
+                log.exception("prewarm mesh lattice")
 
     out = {"specs": len(specs), "programs": programs,
            "mesh_programs": mesh_programs,
@@ -616,23 +606,10 @@ def prewarm(configs: Dict,
            "failures": failures, "compiles": compile_count() - c0,
            "seconds": round(time.perf_counter() - t0, 3)}
     log.info("prewarm: %s", out)
+    newly_failed = {k: v for k, v in _FAILED.items()
+                    if k not in failed0}
+    if failures or newly_failed:
+        raise PrewarmError(
+            f"{failures} program(s) failed, kernels failed in the "
+            f"sweep {newly_failed}: {out}")
     return out
-
-
-def prewarm_from_watcher(watcher) -> Optional[Dict]:
-    """main.py hook: wire the persistent cache from the root namespace's
-    service_config, then compile the layer programs.  Never raises —
-    a failed prewarm must not stop the server from coming up."""
-    if not prewarm_enabled():
-        return None
-    try:
-        cache_dir = ""
-        for cfg in watcher.configs.values():
-            if cfg.service_config.jax_compilation_cache_dir:
-                cache_dir = cfg.service_config.jax_compilation_cache_dir
-                break
-        configure_compilation_cache(cache_dir)
-        return prewarm(watcher.configs)
-    except Exception as e:
-        log.warning("prewarm skipped: %s", e)
-        return None

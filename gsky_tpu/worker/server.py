@@ -665,11 +665,13 @@ def main(argv=None):
     a = ap.parse_args(argv)
     logging.basicConfig(level=logging.INFO)
 
+    # in the split topology this process holds the chip (the gateway
+    # is started with JAX_PLATFORMS=cpu); no TPU raises PlatformError
     from ..device import ensure_platform
     plat = ensure_platform()
-    if plat["fallback"]:
-        log.warning("accelerator unreachable after %d probe(s); "
-                    "computing on CPU", plat["probe_attempts"])
+    log.info("gsky-rpc: platform %s (%s x%d), compile cache %s",
+             plat["platform"], plat["device_kind"], plat["device_count"],
+             plat["cache_dir"])
 
     svc = WorkerService(pool_size=a.pool or None, task_timeout=a.timeout)
     if not svc.advertise_addr:

@@ -173,7 +173,7 @@ class TestThroughputKnee:
         assert b.stats()["tile_ms"] == {8: 10.0}
 
     def test_knee_ratchets_down_past_regression(self):
-        """BENCH_r05 shape: x8 batches at 9.29 ms/tile vs 4.10 single
+        """The regression shape: x8 batches at 9.29 ms/tile vs 4.10 single
         -> the ratchet caps the flush threshold at 4."""
         b = RenderBatcher(max_batch=16)
         assert b.knee == 16

@@ -17,7 +17,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 def run_cli(module, *args, timeout=120):
     env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"   # ensure_platform pins CPU from this
+    env["JAX_PLATFORMS"] = "cpu"   # the one way to ask for the CPU
     return subprocess.run(
         [sys.executable, "-m", module, *args],
         capture_output=True, text=True, timeout=timeout, cwd=REPO,

@@ -26,7 +26,7 @@ def _tmp_ledger(tmp_path, monkeypatch):
 
 def _clean(*keys):
     for name, token in keys:
-        pt._FAILED.discard(name)
+        pt._FAILED.pop(name, None)
         pt._SLOW.discard((name, token))
         pt._PROVEN.pop((name, token), None)
 

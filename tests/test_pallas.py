@@ -97,8 +97,32 @@ class TestStatsKernel:
                                    atol=1e-6)
 
 
+class TestSelectionGates:
+    def test_warp_family_is_interpret_only(self, monkeypatch):
+        """On a TPU the streaming kernels Mosaic compiles are selected;
+        the gather-form warp family it refuses — and with it pages and
+        waves — is not, by what the code knows.  Interpret mode (these
+        parity tests) keeps the whole family reachable."""
+        from gsky_tpu.ops import pallas_tpu as pt
+        from gsky_tpu.ops.paged import paged_enabled
+        from gsky_tpu.pipeline.waves import waves_enabled
+
+        monkeypatch.setattr(pt, "tpu_like_backend", lambda: True)
+        monkeypatch.delenv("GSKY_PALLAS", raising=False)
+        assert pt.use_pallas()
+        assert not pt.warp_pallas_enabled()
+        assert not pt.warp_pallas_ok(384, 384, 1)
+        assert not paged_enabled() and not waves_enabled()
+        monkeypatch.setenv("GSKY_PALLAS", "interpret")
+        assert pt.warp_pallas_enabled() and pt.warp_pallas_ok(384, 384, 1)
+        assert paged_enabled() and waves_enabled()
+        monkeypatch.setenv("GSKY_PALLAS", "0")
+        assert not pt.use_pallas() and not pt.warp_pallas_enabled()
+        assert not paged_enabled()
+
+
 class TestRunWithFallback:
-    def test_falls_back_and_blacklists(self):
+    def test_failure_is_loud_and_not_retried(self, caplog):
         from gsky_tpu.ops import pallas_tpu as pt
 
         calls = {"pallas": 0, "xla": 0}
@@ -112,18 +136,25 @@ class TestRunWithFallback:
             return "xla-result"
 
         orig = pt.use_pallas
-        pt._FAILED.discard("test_kernel")
+        pt._FAILED.pop("test_kernel", None)
         pt.use_pallas = lambda: True
         try:
-            with pytest.warns(UserWarning, match="test_kernel"):
+            with caplog.at_level("ERROR", logger="gsky.pallas"):
                 assert pt.run_with_fallback("test_kernel", bad,
                                             good) == "xla-result"
+            # logged as an error with the traceback, and readable by
+            # name (what /debug, prewarm and chip_smoke.py act on)
+            rec = [r for r in caplog.records if "test_kernel" in
+                   r.getMessage()]
+            assert rec and rec[0].levelname == "ERROR" \
+                and rec[0].exc_info
+            assert "VMEM OOM" in pt.kernel_state()["failed"]["test_kernel"]
             # second call must not retry the broken kernel
             assert pt.run_with_fallback("test_kernel", bad,
                                         good) == "xla-result"
         finally:
             pt.use_pallas = orig
-            pt._FAILED.discard("test_kernel")
+            pt._FAILED.pop("test_kernel", None)
         assert calls == {"pallas": 1, "xla": 2}
 
     def test_speed_race_demotes_slow_pallas(self):

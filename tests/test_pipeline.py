@@ -232,6 +232,8 @@ class TestDrill:
         dp = DrillPipeline(mas)
         dp.process(req)                        # primes the async upload
         assert default_drill_cache.wait_idle(60)
+        from gsky_tpu.pipeline.executor import default_executor
+        legs0 = dict(default_executor.bucket_stats)
         res_dev = dp.process(req)              # cached-stack path
         # guard against a vacuous pass: the fixture's stack must be
         # device-resident (earlier tests may have already cached it)
@@ -239,6 +241,14 @@ class TestDrill:
                    for k in default_drill_cache._order)
         monkeypatch.setenv("GSKY_DRILL_CACHE", "0")
         res_host = dp.process(req)             # host-read path
+        # the leg that answered is counted where /debug reads it, and a
+        # device-path failure cannot hide behind the host reads
+
+        def grew(leg):
+            return default_executor.bucket_stats.get(leg, 0) \
+                - legs0.get(leg, 0)
+        assert grew("drill_device") >= 1 and grew("drill_host") >= 1
+        assert grew("drill_device_error") == 0
         assert res_dev.dates == res_host.dates
         for ns in res_host.values:
             np.testing.assert_allclose(

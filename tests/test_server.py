@@ -640,6 +640,14 @@ class TestDebugSideDoor:
         assert set(gw) >= {"engaged", "declined", "batches_windowed",
                            "batches_full", "batch_knee", "tile_ms"}
         assert "jax" in doc and doc["jax"]["backend"] == "cpu"
+        # what chip_smoke.py and an operator read a fallback from:
+        # device, fresh compiles, prewarm, and the kernel selection
+        assert doc["jax"]["device_kind"] == "cpu"
+        assert doc["jax"]["compiles"] >= 0
+        assert "prewarm" in doc
+        assert set(doc["kernels"]) >= {
+            "failed", "demoted", "promoted", "lowered",
+            "warp_pallas_enabled", "ledger_path"}
 
     def test_debug_errors_counted(self, env):
         import json as _json

@@ -464,6 +464,39 @@ class TestPrewarm:
         assert again["compiles"] == 0
         assert again["failures"] == 0
 
+    def test_prewarm_failure_is_fatal(self, env, monkeypatch):
+        """A program that fails in the sweep, or a pallas kernel that
+        lands in `_FAILED` during it, stops start-up: the server does
+        not come up on a path it knows is broken."""
+        import importlib
+
+        from gsky_tpu.ops import pallas_tpu as pt
+        from gsky_tpu.server.prewarm import PrewarmError, prewarm
+        warp = importlib.import_module("gsky_tpu.ops.warp")
+
+        def broken(*a, **kw):
+            raise RuntimeError("no such program")
+
+        with monkeypatch.context() as m:
+            m.setattr(warp, "render_rgba_ctrl", broken)
+            with pytest.raises(PrewarmError, match="1 program"):
+                prewarm(env["watcher"].configs, sizes=[128], bucket=512,
+                        max_scenes=2)
+
+        real = pt.render_byte_raced
+
+        def fails_a_kernel(*a, **kw):
+            pt._FAILED["warp_render"] = "NotImplementedError: simulated"
+            return real(*a, **kw)
+
+        monkeypatch.setattr(pt, "render_byte_raced", fails_a_kernel)
+        try:
+            with pytest.raises(PrewarmError, match="warp_render"):
+                prewarm(env["watcher"].configs, sizes=[128], bucket=512,
+                        max_scenes=2)
+        finally:
+            pt._FAILED.pop("warp_render", None)
+
 
 class TestCancellation:
     """End-to-end cooperative cancellation at the pipeline stages: a

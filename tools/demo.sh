@@ -6,6 +6,12 @@
 #   gsky-rpc   (TPU compute worker, gRPC)    on :11429
 #   gsky-ows   (OGC WMS/WCS/WPS/DAP4 server) on :8080
 # and smoke-checks a GetMap tile.  Ctrl-C tears everything down.
+#
+# One process holds the chip: in this split topology it is gsky-rpc.
+# gsky-ows is told JAX_PLATFORMS=cpu (its own JAX work runs on the CPU on
+# purpose), gsky-mas and the crawler never initialise JAX, and the
+# archive generator below is told the CPU too.  Run the whole script
+# under JAX_PLATFORMS=cpu to keep gsky-rpc off the chip as well.
 set -euo pipefail
 
 ROOT="$(cd "$(dirname "$0")/.." && pwd)"
@@ -17,7 +23,7 @@ echo "[demo] building native codec"
 make -C gsky_tpu/native >/dev/null
 
 echo "[demo] generating sample archive under $DEMO"
-$PY - "$DEMO" <<'EOF'
+JAX_PLATFORMS=cpu $PY - "$DEMO" <<'EOF'
 import json, os, sys
 sys.path.insert(0, os.getcwd())
 import bench
@@ -60,12 +66,12 @@ echo "[demo] starting gsky-mas :8888"
 $PY -m gsky_tpu.index.api -port 8888 -ingest "$DEMO/crawl.tsv" &
 sleep 1
 
-echo "[demo] starting gsky-rpc :11429"
+echo "[demo] starting gsky-rpc :11429 (holds the chip)"
 $PY -m gsky_tpu.worker.server -p 11429 &
 sleep 2
 
-echo "[demo] starting gsky-ows :8080 (conf $DEMO/conf)"
-$PY -m gsky_tpu.server.main -port 8080 -conf "$DEMO/conf" -static "$ROOT/static" &
+echo "[demo] starting gsky-ows :8080 on the CPU (conf $DEMO/conf)"
+JAX_PLATFORMS=cpu $PY -m gsky_tpu.server.main -port 8080 -conf "$DEMO/conf" -static "$ROOT/static" &
 sleep 3
 
 echo "[demo] waiting for gsky-ows to come up"

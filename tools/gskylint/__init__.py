@@ -16,7 +16,7 @@ but that code review alone had been enforcing (docs/ANALYSIS.md):
                 both with and without their lock held.
   GSKY-EXC      no unannotated ``except Exception: pass`` swallows;
                 device errors stay inside the
-                ``DeviceGuardError ⊂ BackendUnavailable`` taxonomy.
+                ``DeviceGuardError ⊂ BackendUnavailable`` hierarchy.
 
 Run locally::
 

@@ -1,4 +1,4 @@
-"""GSKY-EXC: silent swallows and the device-error taxonomy.
+"""GSKY-EXC: silent swallows and the device-error hierarchy.
 
 Two rules:
 
@@ -15,7 +15,7 @@ X1  an ``except Exception:`` / ``except BaseException:`` / bare
 
 X2  exception classes defined under ``gsky_tpu/device_guard/`` must
     stay inside the ``DeviceGuardError ⊂ BackendUnavailable``
-    taxonomy (subclass one of the two, directly) — a device error
+    hierarchy (subclass one of the two, directly) — a device error
     outside it would dodge the gateway's 503+Retry-After mapping and
     surface as a bare 500.
 """
@@ -29,7 +29,7 @@ from .engine import Finding, RepoContext
 
 CODE = "GSKY-EXC"
 _BROAD = {"Exception", "BaseException"}
-_TAXONOMY_BASES = {"DeviceGuardError", "BackendUnavailable"}
+_HIERARCHY_BASES = {"DeviceGuardError", "BackendUnavailable"}
 
 
 def _handler_types(handler: ast.ExceptHandler) -> List[str]:
@@ -94,12 +94,12 @@ def check(ctx: RepoContext) -> List[Finding]:
                         names.add(b.attr)
                 looks_exc = node.name.endswith(("Error", "Fault")) or \
                     any(n.endswith(("Error", "Exception")) or
-                        n in _TAXONOMY_BASES for n in names)
-                if looks_exc and not (names & _TAXONOMY_BASES):
+                        n in _HIERARCHY_BASES for n in names)
+                if looks_exc and not (names & _HIERARCHY_BASES):
                     out.append(Finding(
                         CODE, sf.path, node.lineno,
                         f"device exception {node.name} is outside the "
                         f"DeviceGuardError ⊂ BackendUnavailable "
-                        f"taxonomy (X2) — it would bypass the "
+                        f"hierarchy (X2) — it would bypass the "
                         f"gateway's 503 mapping"))
     return out

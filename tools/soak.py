@@ -379,7 +379,7 @@ def _run(argv=None):
             ).strip()
 
     from gsky_tpu.device import ensure_platform
-    ensure_platform(retries=1, timeout_s=45.0)
+    ensure_platform()
 
     import asyncio
     import tempfile
