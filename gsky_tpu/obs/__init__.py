@@ -17,6 +17,9 @@ Three concerns, one seam:
   two endpoints cannot drift; the rest is collected at scrape time from
   the live stats objects.
 
+While a ``jax.profiler`` session runs, every span is also an event of
+its name on the profile's host plane, on the device trace's clock.
+
 ``GSKY_TRACE=0`` disables tracing entirely (spans become no-ops on a
 pre-checked fast path); ``GSKY_TRACE_FILE`` + ``GSKY_TRACE_SAMPLE``
 enable sampled JSONL file export.  See docs/OBSERVABILITY.md.

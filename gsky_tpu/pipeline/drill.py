@@ -18,6 +18,7 @@ Reference dataflow: DrillIndexer -> GeoDrillGRPC -> DrillMerger
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import math
 import xml.etree.ElementTree as ET
@@ -34,6 +35,7 @@ from ..index.client import Dataset, MASClient
 from ..index.store import fmt_time
 from ..io.geotiff import GeoTIFF
 from ..io.netcdf import NetCDF
+from ..obs import set_attr, span as obs_span
 from ..ops import drill as D
 from ..ops.raster import nodata_mask
 from .types import DrillResult, GeoDrillRequest
@@ -117,8 +119,11 @@ class DrillPipeline:
                       year_step: int = 0) -> DrillResult:
         """TimeSplitter-wired entry: split the request into year-stepped
         windows, drill each, and merge (`processor/date_splitter.go`)."""
-        return merge_results([self.process(w)
-                              for w in split_by_years(req, year_step)])
+        parts = [self.process(w) for w in split_by_years(req, year_step)]
+        with obs_span("drill.merge") as sp:
+            res = merge_results(parts)
+            sp.set(dates=len(res.dates), namespaces=len(res.values))
+        return res
 
     def index(self, req: GeoDrillRequest) -> List[Dataset]:
         namespaces = list(req.band_exprs.var_list) \
@@ -130,7 +135,11 @@ class DrillPipeline:
             kw["time"] = fmt_time(req.start_time)
         if req.end_time is not None:
             kw["until"] = fmt_time(req.end_time)
-        return self.mas.intersects(req.collection, **kw)
+        with obs_span("drill.index") as sp:
+            datasets = self.mas.intersects(req.collection, **kw)
+            sp.set(datasets=len(datasets),
+                   timestamps=sum(len(d.timestamps) for d in datasets))
+        return datasets
 
     def process(self, req: GeoDrillRequest) -> DrillResult:
         # large-polygon tiling (`drill_indexer.go:115-137`): each tiled
@@ -154,10 +163,13 @@ class DrillPipeline:
                                           index_tile_x_size=0.0,
                                           index_tile_y_size=0.0)
                 self._drill_into(sub, acc, approx_seen)
-            return _merge(acc, req)
-        acc = defaultdict(list)
-        self._drill_into(req, acc)
-        return _merge(acc, req)
+        else:
+            acc = defaultdict(list)
+            self._drill_into(req, acc)
+        with obs_span("drill.merge") as sp:
+            res = _merge(acc, req)
+            sp.set(dates=len(res.dates), namespaces=len(res.raw_namespaces))
+        return res
 
     def _drill_into(self, req: GeoDrillRequest, acc,
                     approx_seen: Optional[set] = None) -> None:
@@ -218,23 +230,27 @@ class DrillPipeline:
                     if k in approx_seen:
                         continue
                     approx_seen.add(k)
-                for ti in sel:
-                    date = ds.timestamps[ti] if ds.timestamps else 0.0
-                    acc[(ds.namespace, date)].append(
-                        (float(ds.means[min(ti, len(ds.means) - 1)]),
-                         int(ds.sample_counts[min(ti, len(ds.sample_counts) - 1)])))
+                with obs_span("drill.merge", dates=len(sel), namespaces=1):
+                    for ti in sel:
+                        date = ds.timestamps[ti] if ds.timestamps else 0.0
+                        acc[(ds.namespace, date)].append(
+                            (float(ds.means[min(ti, len(ds.means) - 1)]),
+                             int(ds.sample_counts[
+                                 min(ti, len(ds.sample_counts) - 1)])))
                 continue
             stats = _drill_file(ds, sel, g4326, req, vrt_xml=vrt_xml)
             if stats is None:
                 continue
             values, counts, deciles = stats
-            for k, ti in enumerate(sel):
-                date = ds.timestamps[ti] if ds.timestamps else 0.0
-                acc[(ds.namespace, date)].append(
-                    (float(values[k]), int(counts[k])))
-                for d in range(req.deciles):
-                    acc[(f"{ds.namespace}_d{d + 1}", date)].append(
-                        (float(deciles[k, d]), 1))
+            with obs_span("drill.merge", dates=len(sel),
+                          namespaces=1 + req.deciles):
+                for k, ti in enumerate(sel):
+                    date = ds.timestamps[ti] if ds.timestamps else 0.0
+                    acc[(ds.namespace, date)].append(
+                        (float(values[k]), int(counts[k])))
+                    for d in range(req.deciles):
+                        acc[(f"{ds.namespace}_d{d + 1}", date)].append(
+                            (float(deciles[k, d]), 1))
 
 
 def _geoloc_drill_mask(ds: Dataset, g4326: geom.Geometry, H: int,
@@ -393,6 +409,47 @@ def _selected_times(ds: Dataset, req: GeoDrillRequest) -> List[int]:
     return out
 
 
+def _drill_window(ds: Dataset, g4326: geom.Geometry, h, H: int, W: int,
+                  is_vrt: bool):
+    """The window of an open file that a geometry covers and its mask
+    there: (mask (uint8, window-shaped), (c0, r0, c1, r1) in raster
+    pixels), or None where the geometry misses the file or the file's
+    SRS cannot be used."""
+    try:
+        if is_vrt and h.crs is not None:
+            src_crs = h.crs
+        else:
+            src_crs = parse_crs(ds.srs) if ds.srs else EPSG4326
+        gt = h.gt if is_vrt else \
+            GeoTransform.from_gdal(ds.geo_transform)
+        g = g4326 if src_crs == EPSG4326 else g4326.transform(
+            lambda x, y: EPSG4326.transform_to(src_crs, x, y))
+    except ValueError:  # unparseable SRS / out-of-domain projection
+        return None
+
+    if getattr(ds, "geo_loc", None) and not is_vrt:
+        return _geoloc_drill_mask(ds, g4326, H, W)
+    # envelope intersect + ALL_TOUCHED mask burn
+    b = g.bbox()
+    c0, r0 = gt.geo_to_pixel(b.xmin, b.ymax)
+    c1, r1 = gt.geo_to_pixel(b.xmax, b.ymin)
+    c0, c1 = sorted((c0, c1))
+    r0, r1 = sorted((r0, r1))
+    c0 = max(int(math.floor(c0)), 0)
+    r0 = max(int(math.floor(r0)), 0)
+    c1 = min(int(math.ceil(c1)), W)
+    r1 = min(int(math.ceil(r1)), H)
+    if c0 >= c1 or r0 >= r1:
+        return None
+    wgt = gt.window(c0, r0)
+    mask = geom.rasterize(g, c1 - c0, r1 - r0,
+                          lambda x, y: wgt.geo_to_pixel(x, y),
+                          all_touched=True)
+    if not mask.any():
+        return None
+    return mask, (c0, r0, c1, r1)
+
+
 def _drill_file(ds: Dataset, sel: List[int], g4326: geom.Geometry,
                 req: GeoDrillRequest, vrt_xml: Optional[str] = None):
     """Masked reductions for the selected bands of one file (or of a
@@ -401,75 +458,47 @@ def _drill_file(ds: Dataset, sel: List[int], g4326: geom.Geometry,
     is_nc = not is_vrt and not ds.ds_name.upper().startswith("GMT:") \
         and (ds.file_path.lower().endswith((".nc", ".nc4"))
              or ds.ds_name.upper().startswith("NETCDF:"))
-    try:
-        if is_vrt:
-            from ..io.vrt import VRTRaster
-            h = VRTRaster(vrt_xml)
-            H, W = h.height, h.width
-        elif is_nc:
-            h = NetCDF(ds.file_path)
-            var = ds.ds_name.split(":")[-1].strip('"')
-            v = h.variables[var]
-            H, W = v.shape[-2], v.shape[-1]
-        else:
-            from ..io.registry import open_raster
-            h = open_raster(ds.file_path)
-            H, W = h.height, h.width
-    except (OSError, ValueError, KeyError, ET.ParseError):
-        return None
+    with contextlib.ExitStack() as opened:
+        with obs_span("drill.prepare", kind="vrt" if is_vrt else
+                      "nc" if is_nc else "tiff") as psp:
+            try:
+                if is_vrt:
+                    from ..io.vrt import VRTRaster
+                    h = VRTRaster(vrt_xml)
+                    H, W = h.height, h.width
+                elif is_nc:
+                    h = NetCDF(ds.file_path)
+                    var = ds.ds_name.split(":")[-1].strip('"')
+                    v = h.variables[var]
+                    H, W = v.shape[-2], v.shape[-1]
+                else:
+                    from ..io.registry import open_raster
+                    h = open_raster(ds.file_path)
+                    H, W = h.height, h.width
+            except (OSError, ValueError, KeyError, ET.ParseError):
+                return None
+            opened.callback(h.close)
 
-    try:
-        try:
-            if is_vrt and h.crs is not None:
-                src_crs = h.crs
-            else:
-                src_crs = parse_crs(ds.srs) if ds.srs else EPSG4326
-            gt = h.gt if is_vrt else \
-                GeoTransform.from_gdal(ds.geo_transform)
-            g = g4326 if src_crs == EPSG4326 else g4326.transform(
-                lambda x, y: EPSG4326.transform_to(src_crs, x, y))
-        except ValueError:  # unparseable SRS / out-of-domain projection
-            return None
-
-        if getattr(ds, "geo_loc", None) and not is_vrt:
-            made = _geoloc_drill_mask(ds, g4326, H, W)
+            made = _drill_window(ds, g4326, h, H, W, is_vrt)
             if made is None:
                 return None
             mask, (c0, r0, c1, r1) = made
-        else:
-            # envelope intersect + ALL_TOUCHED mask burn
-            b = g.bbox()
-            c0, r0 = gt.geo_to_pixel(b.xmin, b.ymax)
-            c1, r1 = gt.geo_to_pixel(b.xmax, b.ymin)
-            c0, c1 = sorted((c0, c1))
-            r0, r1 = sorted((r0, r1))
-            c0 = max(int(math.floor(c0)), 0)
-            r0 = max(int(math.floor(r0)), 0)
-            c1 = min(int(math.ceil(c1)), W)
-            r1 = min(int(math.ceil(r1)), H)
-            if c0 >= c1 or r0 >= r1:
-                return None
-            wgt = gt.window(c0, r0)
-            mask = geom.rasterize(g, c1 - c0, r1 - r0,
-                                  lambda x, y: wgt.geo_to_pixel(x, y),
-                                  all_touched=True)
-            if not mask.any():
-                return None
+            psp.set(window=(r1 - r0, c1 - c0))
 
-        # strided band reads with interpolation (`drill.go:119-214`)
-        stride = max(req.band_strides, 1)
-        read_idx: List[int] = []
-        for s in range(0, len(sel), stride):
-            e = min(s + stride, len(sel))
-            read_idx.append(s)
-            if e - 1 != s:
-                read_idx.append(e - 1)
-        read_idx = sorted(set(read_idx))
+            # strided band reads with interpolation (`drill.go:119-214`)
+            stride = max(req.band_strides, 1)
+            read_idx: List[int] = []
+            for s in range(0, len(sel), stride):
+                e = min(s + stride, len(sel))
+                read_idx.append(s)
+                if e - 1 != s:
+                    read_idx.append(e - 1)
+            read_idx = sorted(set(read_idx))
 
-        band0 = 1
-        if not is_nc and ":" in ds.ds_name \
-                and ds.ds_name.rsplit(":", 1)[-1].isdigit():
-            band0 = int(ds.ds_name.rsplit(":", 1)[-1])
+            band0 = 1
+            if not is_nc and ":" in ds.ds_name \
+                    and ds.ds_name.rsplit(":", 1)[-1].isdigit():
+                band0 = int(ds.ds_name.rsplit(":", 1)[-1])
 
         # device-resident stack fast path: the whole variable stack
         # lives in HBM (uploaded once per file), the window slice +
@@ -487,12 +516,16 @@ def _drill_file(ds: Dataset, sel: List[int], g4326: geom.Geometry,
                     # reads while the stack uploads in the background
                     getter = DC.default_drill_cache.get if DC.sync_mode() \
                         else DC.default_drill_cache.get_async
-                    st = getter(
-                        ds.file_path, is_nc, var if is_nc else "", band0,
-                        ds.nodata)
-                    dev = _drill_device(st, sel, read_idx, mask,
-                                        (c0, r0, c1, r1), req) \
-                        if st is not None else None
+                    with obs_span("drill.device") as dsp:
+                        st = getter(
+                            ds.file_path, is_nc, var if is_nc else "",
+                            band0, ds.nodata)
+                        if st is None:
+                            dsp.set(resident=False)
+                            dev = None
+                        else:
+                            dev = _drill_device(st, sel, read_idx, mask,
+                                                (c0, r0, c1, r1), req)
                 except Exception:
                     # any device-path failure (upload OOM, compile)
                     # degrades to host reads, not a failed request —
@@ -509,34 +542,36 @@ def _drill_file(ds: Dataset, sel: List[int], g4326: geom.Geometry,
                                          sel, stride, req)
         default_executor._count("drill_host")
 
-        bands_data = []
-        for k in read_idx:
-            ti = sel[k]
-            if is_vrt:
-                data = h.read(1, (c0, r0, c1 - c0, r1 - r0),
-                              time_index=ti)
-                nodata = h.nodata
-            elif is_nc:
-                data = h.read_slice(var, ti if len(v.shape) > 2 else None,
-                                    (c0, r0, c1 - c0, r1 - r0))
-                nodata = ds.nodata if ds.nodata is not None else v.nodata
-            else:
-                # GeoTIFF granules carry one timestamp per file; the band
-                # index comes from the crawler's ds_name suffix
-                data = h.read(band0, (c0, r0, c1 - c0, r1 - r0))
-                nodata = ds.nodata if ds.nodata is not None else h.nodata
-            bands_data.append((data.astype(np.float32),
-                               nodata_mask(data, nodata)))
+        with obs_span("drill.host_read", bands=len(read_idx)):
+            bands_data = []
+            for k in read_idx:
+                ti = sel[k]
+                if is_vrt:
+                    data = h.read(1, (c0, r0, c1 - c0, r1 - r0),
+                                  time_index=ti)
+                    nodata = h.nodata
+                elif is_nc:
+                    data = h.read_slice(
+                        var, ti if len(v.shape) > 2 else None,
+                        (c0, r0, c1 - c0, r1 - r0))
+                    nodata = ds.nodata if ds.nodata is not None \
+                        else v.nodata
+                else:
+                    # GeoTIFF granules carry one timestamp per file; the
+                    # band index comes from the crawler's ds_name suffix
+                    data = h.read(band0, (c0, r0, c1 - c0, r1 - r0))
+                    nodata = ds.nodata if ds.nodata is not None \
+                        else h.nodata
+                bands_data.append((data.astype(np.float32),
+                                   nodata_mask(data, nodata)))
 
-        data = np.stack([d for d, _ in bands_data])
-        valid = np.stack([m for _, m in bands_data]) & (mask[None] > 0)
-        B = data.shape[0]
-        vals, counts, dec = _stats_tail(data.reshape(B, -1),
-                                        valid.reshape(B, -1), req)
+            data = np.stack([d for d, _ in bands_data])
+            valid = np.stack([m for _, m in bands_data]) & (mask[None] > 0)
+            B = data.shape[0]
+            vals, counts, dec = _stats_tail(data.reshape(B, -1),
+                                            valid.reshape(B, -1), req)
         return _maybe_interp(vals, counts, dec, read_idx, sel, stride,
                              req)
-    finally:
-        h.close()
 
 
 def _stats_host(dataf: np.ndarray, validf: np.ndarray,
@@ -671,6 +706,7 @@ def _drill_device(st, sel: List[int], read_idx: List[int],
     B = len(tsel)
     Bp = _bucket_pow2(B)
     tsel_p = np.pad(tsel, (0, Bp - B), mode="edge")
+    set_attr(bucket=(bh, bw), bands=B, bands_padded=Bp)
     # nodata compares in the stack's NATIVE dtype (parity with
     # ops.raster.nodata_mask); a nodata not representable there matches
     # nothing, exactly like the host path's dtype-promoting !=

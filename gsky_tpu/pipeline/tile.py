@@ -20,6 +20,7 @@ import numpy as np
 from ..geo.crs import EPSG4326
 from ..index.client import MASClient
 from ..index.store import fmt_time
+from ..obs import span as obs_span
 from ..ops import mosaic as M
 from ..ops.expr import BandExpressions
 from ..resilience import check_partial
@@ -215,16 +216,17 @@ class TilePipeline:
 
     def _timed_index(self, req: GeoTileRequest,
                      spans: Optional[Dict[str, float]] = None):
-        """`index()` with the MAS-query seconds recorded into ``spans``
-        (the staged tile path's per-request "index" stage span)."""
-        if spans is None:
-            return self.index(req)
+        """`index()` under a `tile.index` span, with the MAS-query
+        seconds recorded into ``spans`` (the staged tile path's
+        per-request "index" stage)."""
         t0 = time.perf_counter()
         try:
-            return self.index(req)
+            with obs_span("tile.index"):
+                return self.index(req)
         finally:
-            spans["index_s"] = spans.get("index_s", 0.0) \
-                + time.perf_counter() - t0
+            if spans is not None:
+                spans["index_s"] = spans.get("index_s", 0.0) \
+                    + time.perf_counter() - t0
 
     def composite_prep(self, req: GeoTileRequest,
                        stats: Optional[Dict[str, int]] = None,

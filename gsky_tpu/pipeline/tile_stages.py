@@ -40,7 +40,7 @@ from typing import Dict, Optional
 
 import numpy as np
 
-from ..obs import record_span, span as obs_span
+from ..obs import span as obs_span
 from ..resilience import check_cancel
 
 
@@ -270,10 +270,6 @@ def render_staged(pipe, req, n_exprs: int,
     # "plan" is the prep minus the index query it contains
     spans["plan_s"] = spans.get("plan_s", 0.0) \
         + max(0.0, time.perf_counter() - t0 - spans.get("index_s", 0.0))
-    if spans.get("index_s"):
-        # the MAS query ran inside the prep (see _timed_index); surface
-        # it as its own span, anchored to where the prep ended
-        record_span("tile.index", spans["index_s"])
     if made is None:
         return None
 

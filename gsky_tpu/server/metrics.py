@@ -191,6 +191,8 @@ class MetricsLogger:
         self._export: Dict = {}
         # cumulative staged-tile (pipeline/tile_stages.py) aggregates
         self._tiles: Dict = {}
+        # cumulative WPS Execute stage aggregates, folded from spans
+        self._drills: Dict = {}
 
     def collector(self) -> MetricsCollector:
         return MetricsCollector(self)
@@ -291,6 +293,42 @@ class MetricsLogger:
         except Exception:   # observability must never fail a request
             pass
 
+    # /debug drill_stages key <- the span it sums (docs/OBSERVABILITY.md);
+    # the stages of one Execute run one after another, so wall_s minus
+    # their sum is what no span covers yet
+    _DRILL_STAGES = (("parse_s", "wps.parse"),
+                     ("admission_s", "gateway.admission"),
+                     ("index_s", "drill.index"),
+                     ("prepare_s", "drill.prepare"),
+                     ("device_s", "drill.device"),
+                     ("host_read_s", "drill.host_read"),
+                     ("merge_s", "drill.merge"),
+                     ("format_s", "wps.format"))
+
+    def record_drill(self, stages: Dict[str, float], wall_s: float,
+                     files: int) -> None:
+        """Fold one answered WPS Execute into the /debug `drill_stages`
+        aggregates.  ``stages`` is the request's trace folded by span
+        name (`Trace.seconds_by_name`), ``wall_s`` the root span's age,
+        ``files`` the files drilled."""
+        try:
+            last = {k: round(stages.get(name, 0.0), 6)
+                    for k, name in self._DRILL_STAGES}
+            last["wall_s"] = round(wall_s, 6)
+            last["files"] = files
+            with self._summary_lock:
+                e = self._drills
+                e["requests"] = e.get("requests", 0) + 1
+                for k, v in last.items():
+                    e[k] = round(e.get(k, 0) + v, 6)
+                e["last"] = last
+            from ..obs.metrics import STAGE_SECONDS
+            for k, v in last.items():
+                if k.endswith("_s"):
+                    STAGE_SECONDS.labels(stage="drill_" + k[:-2]).observe(v)
+        except Exception:   # observability must never fail a request
+            pass
+
     def summary(self) -> Dict:
         """The /debug document body: uptime, per-verb counts + latency
         percentiles over the rolling window, cumulative device/pipeline
@@ -313,6 +351,8 @@ class MetricsLogger:
                     "pipeline_ms_total": round(s["rpc_ms"], 1)}
             if self._export.get("exports"):
                 out["export_pipeline"] = dict(self._export)
+            if self._drills.get("requests"):
+                out["drill_stages"] = dict(self._drills)
             if self._tiles.get("tiles"):
                 out["tile_stages"] = dict(self._tiles)
                 try:

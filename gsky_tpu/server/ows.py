@@ -1897,7 +1897,8 @@ class OWSServer:
 
     async def serve_wps(self, request, cfg: Config, q, collector):
         body = await request.read() if request.method == "POST" else None
-        p = parse_wps(q, body if body else None)
+        with obs.span("wps.parse", body_bytes=len(body or b"")):
+            p = parse_wps(q, body if body else None)
         req_name = (p.request or "").lower()
         host = _host_of(request, cfg)
         if req_name == "getcapabilities" or not req_name:
@@ -1911,7 +1912,11 @@ class OWSServer:
         if req_name != "execute":
             raise OWSError(f"WPS request {p.request!r} not supported",
                            "OperationNotSupported")
+        t0, pc0 = time.time(), time.perf_counter()
         async with self._admit("WPS", _tenant_of(request)):
+            obs.record_span("gateway.admission",
+                            time.perf_counter() - pc0, t0=t0,
+                            service="WPS")
             return await self._wps_execute(cfg, p)
 
     async def _wps_execute(self, cfg: Config, p) -> web.Response:
@@ -1922,19 +1927,23 @@ class OWSServer:
         if not p.geometry_json:
             raise OWSError("geometry input required",
                            "MissingParameterValue")
-        try:
-            g = geom.from_geojson(p.geometry_json)
-        except (ValueError, KeyError) as e:
-            raise OWSError(f"invalid GeoJSON geometry: {e}")
-        if g.kind not in ("Point", "Polygon", "MultiPolygon"):
-            raise OWSError(
-                f"geometry type {g.kind} not supported; use Point/Polygon/"
-                f"MultiPolygon")
-        if proc.max_area > 0 and g.area() > proc.max_area:
-            raise OWSError(
-                f"geometry area exceeds process limit {proc.max_area}")
+        with obs.span("wps.parse") as psp:
+            try:
+                g = geom.from_geojson(p.geometry_json)
+            except (ValueError, KeyError) as e:
+                raise OWSError(f"invalid GeoJSON geometry: {e}")
+            if g.kind not in ("Point", "Polygon", "MultiPolygon"):
+                raise OWSError(
+                    f"geometry type {g.kind} not supported; use Point/"
+                    f"Polygon/MultiPolygon")
+            if proc.max_area > 0 and g.area() > proc.max_area:
+                raise OWSError(
+                    f"geometry area exceeds process limit {proc.max_area}")
+            wkt = g.to_wkt()
+            psp.set(vertices=sum(len(r) for poly in g.polys for r in poly)
+                    + (len(g.points) if g.points is not None else 0))
 
-        csv_blocks = []
+        results = []
         for src in proc.data_sources:
             vrt_xml = ""
             if src.vrt_url:
@@ -1950,7 +1959,7 @@ class OWSServer:
                                    f"unreadable: {e}")
             dreq = GeoDrillRequest(
                 collection=src.data_source, bands=src.rgb_products,
-                geometry_wkt=g.to_wkt(),
+                geometry_wkt=wkt,
                 start_time=p.start_time, end_time=p.end_time,
                 deciles=proc.deciles, approx=proc.approx,
                 band_strides=src.band_strides,
@@ -1967,10 +1976,22 @@ class OWSServer:
                     asyncio.to_thread(dp.process_split, dreq,
                                       proc.year_step),
                     timeout=ddl.remaining())
-            from ..pipeline.drill import drill_csv
-            names = list(res.values)
-            csv_blocks.append(drill_csv(res, names))
-        return _xml(T.wps_execute_response(p.identifier, csv_blocks))
+            results.append(res)
+        from ..pipeline.drill import drill_csv
+        with obs.span("wps.format") as fsp:
+            csv_blocks = [drill_csv(res, list(res.values))
+                          for res in results]
+            resp = _xml(T.wps_execute_response(p.identifier, csv_blocks))
+            fsp.set(rows=sum(len(res.dates) for res in results),
+                    bytes=len(resp.body))
+        trace = obs.current_trace()
+        if trace is not None:
+            # the drill's counters are a fold of its spans: one
+            # measurement, read by /debug, /metrics and the trace alike
+            self.metrics.record_drill(trace.seconds_by_name(),
+                                      trace.age_s(),
+                                      trace.count("drill.prepare"))
+        return resp
 
 
 # ---------------------------------------------------------------------------

@@ -496,6 +496,89 @@ def test_record_span_closed_interval():
     assert sp["attrs"]["queued"] == 3
 
 
+def test_seconds_by_name_sums_same_named_spans_and_skips_open_ones():
+    with obs.start_trace("req") as tr:
+        obs.record_span("drill.prepare", 0.25)
+        obs.record_span("drill.prepare", 0.5)
+        obs.record_span("drill.index", 0.125)
+        with obs.span("wps.format"):
+            with obs.span("inner"):
+                pass
+            # wps.format is still open here: in nobody's sum yet
+            mid = tr.seconds_by_name()
+            assert tr.count("wps.format") == 0
+    assert mid["drill.prepare"] == 0.75 and mid["drill.index"] == 0.125
+    assert "wps.format" not in mid and "req" not in mid
+    assert mid["inner"] >= 0
+    done = tr.seconds_by_name()
+    assert done["wps.format"] >= done["inner"]
+    assert tr.count("drill.prepare") == 2 and tr.count("wps.format") == 1
+    assert tr.age_s() >= done["wps.format"]
+
+
+class _Annotation:
+    """Stands in for jax.profiler.TraceAnnotation."""
+    log = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        self.log.append(("enter", self.name))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name))
+        return False
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    from gsky_tpu.obs import trace as trace_mod
+    monkeypatch.setattr(_Annotation, "log", [])
+    monkeypatch.setattr(trace_mod, "_ANNOTATION", _Annotation)
+    return _Annotation.log
+
+
+def test_span_holds_one_annotation_of_its_name(annotations):
+    with obs.start_trace("req"):
+        with obs.span("drill.device", bands=3):
+            assert annotations == [("enter", "drill.device")]
+    assert annotations == [("enter", "drill.device"),
+                           ("exit", "drill.device")]
+
+
+def test_span_leaves_its_annotation_on_an_exception(annotations):
+    with obs.start_trace("req") as tr:
+        with pytest.raises(RuntimeError):
+            with obs.span("boom"):
+                raise RuntimeError("nope")
+    assert annotations == [("enter", "boom"), ("exit", "boom")]
+    sp = [s for s in tr.span_dicts() if s["name"] == "boom"][0]
+    assert sp["attrs"]["error"] == "RuntimeError"
+
+
+def test_untraced_span_opens_no_annotation(annotations, monkeypatch):
+    with obs.span("orphan"):
+        pass
+    monkeypatch.setenv("GSKY_TRACE", "0")
+    with obs.start_trace("req"):
+        with obs.span("child"):
+            pass
+    assert annotations == []
+
+
+def test_the_annotation_is_the_profilers(monkeypatch):
+    """Resolved by the first traced span, not at import."""
+    import jax.profiler
+    from gsky_tpu.obs import trace as trace_mod
+    monkeypatch.setattr(trace_mod, "_ANNOTATION", None)
+    with obs.start_trace("req"):
+        with obs.span("child"):
+            pass
+    assert trace_mod._ANNOTATION is jax.profiler.TraceAnnotation
+
+
 def test_trace_disabled_is_noop(monkeypatch):
     monkeypatch.setenv("GSKY_TRACE", "0")
     rec = obs.default_recorder()

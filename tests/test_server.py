@@ -419,6 +419,84 @@ class TestWPS:
         assert status == 400
 
 
+class TestDrillStages:
+    """A WPS Execute is legible from inside: stage spans where the work
+    happens, folded into /debug `drill_stages`."""
+
+    STAGES = ("parse_s", "admission_s", "index_s", "prepare_s",
+              "device_s", "host_read_s", "merge_s", "format_s")
+
+    def _execute(self, env):
+        import urllib.parse
+        return _get(
+            env, "/ows?service=WPS&request=Execute&identifier=geometryDrill"
+                 f"&datainputs=geometry={urllib.parse.quote(TestWPS.GEOM)}")
+
+    @pytest.fixture
+    def fresh(self, env):
+        """The server with a metrics logger and a recorder of its own."""
+        from gsky_tpu import obs
+        obs.reset_recorder()
+        before = env["server"].metrics
+        env["server"].metrics = MetricsLogger()
+        yield env["server"].metrics
+        env["server"].metrics = before
+        obs.reset_recorder()
+
+    def test_execute_leaves_stage_spans_and_drill_stages(self, env, fresh):
+        from gsky_tpu import obs
+        sent = 3
+        for _ in range(sent):
+            status, _, body = self._execute(env)
+            assert status == 200, body[:400]
+        traces = [t for t in obs.default_recorder().traces()
+                  if t["attrs"].get("verb") == "WPS.Execute"]
+        assert len(traces) == sent
+        for tr in traces:
+            spans = {}
+            for sp in tr["spans"]:
+                spans.setdefault(sp["name"], []).append(sp)
+            assert {"wps.parse", "gateway.admission", "drill.index",
+                    "drill.prepare", "drill.merge", "wps.format"} \
+                <= set(spans), sorted(spans)
+            # each file is answered by the device or by host reads
+            assert "drill.device" in spans or "drill.host_read" in spans
+            assert spans["gateway.admission"][0]["attrs"]["service"] == "WPS"
+            assert spans["drill.index"][0]["attrs"]["datasets"] >= 1
+            assert spans["drill.index"][0]["attrs"]["timestamps"] >= 1
+            assert spans["drill.prepare"][0]["attrs"]["kind"] in (
+                "nc", "tiff", "vrt")
+            assert len(spans["drill.prepare"][0]["attrs"]["window"]) == 2
+            assert sum(sp["attrs"].get("vertices", 0)
+                       for sp in spans["wps.parse"]) == 5
+            fmt = spans["wps.format"][0]["attrs"]
+            assert fmt["rows"] >= 1 and fmt["bytes"] > 0
+        ds = fresh.summary()["drill_stages"]
+        assert ds["requests"] == sent
+        assert ds["files"] >= sent
+        for doc in (ds, ds["last"]):
+            assert all(doc[k] >= 0 for k in self.STAGES), doc
+            # the stages run one after another: no request's named
+            # stages sum to more than its wall time
+            assert sum(doc[k] for k in self.STAGES) <= doc["wall_s"], doc
+        assert ds["index_s"] > 0 and ds["prepare_s"] > 0
+        # /metrics is fed at the same point
+        text = obs.render_metrics()
+        assert 'gsky_stage_seconds_count{stage="drill_index"}' in text
+        assert 'gsky_stage_seconds_count{stage="drill_wall"}' in text
+
+    def test_trace_off_leaves_no_drill_stages_and_the_same_bytes(
+            self, env, fresh, monkeypatch):
+        status, _, traced = self._execute(env)
+        assert status == 200 and "drill_stages" in fresh.summary()
+        env["server"].metrics = MetricsLogger()
+        monkeypatch.setenv("GSKY_TRACE", "0")
+        status, _, untraced = self._execute(env)
+        assert status == 200
+        assert untraced == traced
+        assert "drill_stages" not in env["server"].metrics.summary()
+
+
 class TestConfigSystem:
     def test_tree_namespaces(self, tmp_path):
         (tmp_path / "config.json").write_text(json.dumps(

@@ -255,6 +255,37 @@ class TestStageTelemetry:
         gw = doc["executor"]["gather_window"]
         assert "batch_knee" in gw and "tile_ms" in gw
 
+    def test_tile_index_is_a_span_where_the_query_runs(self, env,
+                                                       monkeypatch):
+        """`tile.index` is measured round the MAS query itself, so it
+        lies inside `tile.plan`; `tile_stages.index_s` is the same
+        clock pair it always was."""
+        from gsky_tpu import obs
+        obs.reset_recorder()
+        m = MetricsLogger()
+        monkeypatch.setattr(env["server"], "metrics", m)
+        monkeypatch.setenv("GSKY_TILE_PIPELINE", "1")
+        try:
+            status, _, _, _ = _get(env, _getmap("mosaic"))
+            traces = obs.default_recorder().traces()
+        finally:
+            obs.reset_recorder()
+        assert status == 200
+        spans = [sp for tr in traces for sp in tr["spans"]]
+        plan = [sp for sp in spans if sp["name"] == "tile.plan"]
+        index = [sp for sp in spans if sp["name"] == "tile.index"]
+        assert len(plan) == 1 and len(index) == 1
+        plan, index = plan[0], index[0]
+        assert index["parent_id"] == plan["span_id"]
+        assert plan["t0"] <= index["t0"]
+        assert index["t0"] + index["dur_s"] \
+            <= plan["t0"] + plan["dur_s"] + 1e-3
+        last = m.summary()["tile_stages"]["last"]
+        # index_s clocks the span from outside: the same to a fraction
+        # of a millisecond, never less
+        assert index["dur_s"] <= last["index_s"] < index["dur_s"] + 1e-3
+        assert last["plan_s"] >= 0
+
     def test_serial_path_records_no_tile_stages(self, env):
         """The escape hatch must not half-engage: with the pipeline off
         no staged spans are recorded for the request."""
