@@ -175,6 +175,12 @@ class Trace:
         with self._lock:
             return sum(1 for sp in self._spans if sp.name == name)
 
+    def total(self, attr: str) -> float:
+        """Sum of a numeric attribute over the closed child spans that
+        carry it."""
+        with self._lock:
+            return sum(sp.attrs.get(attr, 0) for sp in self._spans)
+
     def age_s(self) -> float:
         """Seconds since the root span opened."""
         return time.perf_counter() - self.root._pc0

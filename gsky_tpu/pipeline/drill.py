@@ -23,8 +23,9 @@ import logging
 import math
 import xml.etree.ElementTree as ET
 from collections import defaultdict
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -35,7 +36,7 @@ from ..index.client import Dataset, MASClient
 from ..index.store import fmt_time
 from ..io.geotiff import GeoTIFF
 from ..io.netcdf import NetCDF
-from ..obs import set_attr, span as obs_span
+from ..obs import span as obs_span
 from ..ops import drill as D
 from ..ops.raster import nodata_mask
 from .types import DrillResult, GeoDrillRequest
@@ -180,6 +181,13 @@ class DrillPipeline:
                    if d.namespace in set(req.mask_namespaces)]
         data_ds = [d for d in datasets if d not in mask_ds]
 
+        # the files of one answer share what depends only on the polygon
+        # and the grid, and are all enqueued before the one readback; what
+        # each adds to `acc` is kept in data_ds order, the order in which
+        # weighted means add up
+        group = _DrillGroup(g4326, req)
+        drilled: List[Tuple[Dataset, List[int],
+                            Optional["_FileDrill"]]] = []
         for ds in data_ds:
             sel = _selected_times(ds, req)
             if not sel:
@@ -230,6 +238,13 @@ class DrillPipeline:
                     if k in approx_seen:
                         continue
                     approx_seen.add(k)
+                drilled.append((ds, sel, None))
+                continue
+            drilled.append((ds, sel, group.start(ds, sel, vrt_xml)))
+        group.collect()
+
+        for ds, sel, f in drilled:
+            if f is None:       # the crawler's statistics answer
                 with obs_span("drill.merge", dates=len(sel), namespaces=1):
                     for ti in sel:
                         date = ds.timestamps[ti] if ds.timestamps else 0.0
@@ -238,11 +253,12 @@ class DrillPipeline:
                              int(ds.sample_counts[
                                  min(ti, len(ds.sample_counts) - 1)])))
                 continue
-            stats = _drill_file(ds, sel, g4326, req, vrt_xml=vrt_xml)
-            if stats is None:
+            if f.stats is None:
                 continue
-            values, counts, deciles = stats
-            with obs_span("drill.merge", dates=len(sel),
+            values, counts, deciles = f.stats
+            # `files`: one file answered, by the device or by host reads
+            # (/debug drill_stages.files sums it)
+            with obs_span("drill.merge", files=1, dates=len(sel),
                           namespaces=1 + req.deciles):
                 for k, ti in enumerate(sel):
                     date = ds.timestamps[ti] if ds.timestamps else 0.0
@@ -450,128 +466,327 @@ def _drill_window(ds: Dataset, g4326: geom.Geometry, h, H: int, W: int,
     return mask, (c0, r0, c1, r1)
 
 
+def _read_positions(n: int, stride: int) -> List[int]:
+    """Positions among ``n`` selected timesteps that a strided drill
+    reads: both ends of every ``stride``-long run; the rest are
+    interpolated (`drill.go:119-214`)."""
+    read_idx: List[int] = []
+    for s in range(0, n, stride):
+        e = min(s + stride, n)
+        read_idx.append(s)
+        if e - 1 != s:
+            read_idx.append(e - 1)
+    return sorted(set(read_idx))
+
+
+_UNOPENABLE = (OSError, ValueError, KeyError, ET.ParseError)
+
+
+class _FileDrill:
+    """One file of a drill: what its name says of it, the window and
+    mask it is read through, and its answer."""
+
+    __slots__ = ("ds", "sel", "vrt_xml", "is_nc", "var", "band0", "stride",
+                 "read_idx", "mask", "win", "stats")
+
+    def __init__(self, ds: Dataset, sel: List[int], vrt_xml: Optional[str],
+                 stride: int, read_idx: List[int]):
+        self.ds, self.sel, self.vrt_xml = ds, sel, vrt_xml
+        self.is_nc = not vrt_xml \
+            and not ds.ds_name.upper().startswith("GMT:") \
+            and (ds.file_path.lower().endswith((".nc", ".nc4"))
+                 or ds.ds_name.upper().startswith("NETCDF:"))
+        self.var = ds.ds_name.split(":")[-1].strip('"') if self.is_nc else ""
+        self.band0 = 1
+        if not self.is_nc and ":" in ds.ds_name \
+                and ds.ds_name.rsplit(":", 1)[-1].isdigit():
+            self.band0 = int(ds.ds_name.rsplit(":", 1)[-1])
+        # strided band reads with interpolation (`drill.go:119-214`)
+        self.stride, self.read_idx = stride, read_idx
+        self.mask = self.win = None
+        # (values, counts, deciles) per selected timestep; None where the
+        # geometry misses the file or the file cannot be opened
+        self.stats = None
+
+    @property
+    def kind(self) -> str:
+        return "vrt" if self.vrt_xml else "nc" if self.is_nc else "tiff"
+
+    def open(self):
+        """(handle, H, W) of the file, or of the VRT rendered round it."""
+        if self.vrt_xml:
+            from ..io.vrt import VRTRaster
+            h = VRTRaster(self.vrt_xml)
+            return h, h.height, h.width
+        if self.is_nc:
+            h = NetCDF(self.ds.file_path)
+            try:
+                v = h.variables[self.var]
+            except KeyError:
+                h.close()
+                raise
+            return h, v.shape[-2], v.shape[-1]
+        from ..io.registry import open_raster
+        h = open_raster(self.ds.file_path)
+        return h, h.height, h.width
+
+
+class _Upload(NamedTuple):
+    """What a drill ships to the device for one window and one choice
+    of timesteps, whichever stack of that grid it is then read from."""
+    mask: jax.Array         # (bh, bw) bool, shifted to the clamped origin
+    tsel: jax.Array         # (Bp,) int32, the last index repeated
+    hw: Tuple[int, int]     # the window's bucket (bh, bw)
+    r0c: jax.Array          # () int32: its origin, clamped so that the
+    c0c: jax.Array          # bucket fits
+
+    @property
+    def gather_bytes(self) -> int:
+        """Size of the f32 (Bp, bh * bw) block `window_gather` writes."""
+        return 4 * int(self.tsel.shape[0]) * self.hw[0] * self.hw[1]
+
+
+# Gathered bytes one request keeps enqueued before it reads back.  The
+# runtime holds an enqueued program's buffers until it has run, so three
+# 1 GiB windows enqueued at once by each of four requests would not fit
+# beside the resident stacks; past this much (the largest stack the
+# cache keeps) a group reads back before it enqueues more, which for
+# the largest windows is file by file.
+_GATHER_BYTES_IN_FLIGHT = 1 << 30
+
+
+class _DrillGroup:
+    """The files one drill request reads, drilled together.  What
+    depends only on the polygon and the grid (the window, its mask, what
+    is uploaded for them) is made once per grid; every device-resident
+    file's work is enqueued before any result is read back, and one
+    readback brings them all.  Lives for one `_drill_into` call: nothing
+    here outlasts the request that made it."""
+
+    def __init__(self, g4326: geom.Geometry, req: GeoDrillRequest):
+        self.g4326, self.req = g4326, req
+        self.stride = max(req.band_strides, 1)
+        self._read_idx: Dict[int, List[int]] = {}
+        # (srs, geo_transform, H, W) -> `_drill_window`'s answer, None too
+        self._windows: Dict[tuple, Optional[tuple]] = {}
+        # (grid, timesteps) -> _Upload, or None where no bucket fits
+        self._uploads: Dict[tuple, Optional[_Upload]] = {}
+        # (file, what `_stats_enqueue` left on the device), in file order
+        self._enqueued: List[Tuple[_FileDrill, tuple]] = []
+        self._in_flight = 0     # gathered bytes of those
+
+    # -- one file ------------------------------------------------------
+    def start(self, ds: Dataset, sel: List[int],
+              vrt_xml: Optional[str] = None) -> _FileDrill:
+        """Begin one file (or the rendered VRT wrapping it,
+        `drill.go:363-423`).  A device-resident stack has its window
+        gather and reductions enqueued and is answered by `collect`;
+        any other file is answered here, from host reads."""
+        n = len(sel)
+        if n not in self._read_idx:
+            self._read_idx[n] = _read_positions(n, self.stride)
+        f = _FileDrill(ds, sel, vrt_xml, self.stride, self._read_idx[n])
+        # the stack first: a resident one carries the raster's size, so
+        # no file is opened for it
+        st = self._stack(f)
+        H, W = (None, None) if st is None else st.shape[-2:]
+        grid = None if st is None else self._grid(f, H, W)
+        with contextlib.ExitStack() as opened:
+            h = None
+            if grid in self._windows:
+                made = self._windows[grid]
+            else:
+                # opened only where a window is computed or a header
+                # read: /debug drill_stages.windows counts these spans
+                with obs_span("drill.prepare", kind=f.kind) as psp:
+                    if st is None:
+                        try:
+                            h, H, W = f.open()
+                        except _UNOPENABLE:
+                            return f
+                        opened.callback(h.close)
+                        grid = self._grid(f, H, W)
+                    if grid in self._windows:
+                        made = self._windows[grid]
+                    else:
+                        made = _drill_window(ds, self.g4326, h, H, W,
+                                             bool(vrt_xml))
+                        if grid is not None:
+                            self._windows[grid] = made
+                    if made is not None:
+                        c0, r0, c1, r1 = made[1]
+                        psp.set(window=(r1 - r0, c1 - c0))
+            if made is None:
+                return f
+            f.mask, f.win = made
+            if st is None or not self._enqueue(f, st, grid):
+                f.stats = _drill_host(f, self.req, h)
+        if self._in_flight >= _GATHER_BYTES_IN_FLIGHT:
+            self.collect()
+        return f
+
+    @staticmethod
+    def _grid(f: _FileDrill, H: int, W: int) -> Optional[tuple]:
+        """What files must have in common to share a window; None for a
+        file that shares with nobody: a VRT rendering has a grid of its
+        own, a swath's mask comes from its geolocation arrays."""
+        ds = f.ds
+        if f.vrt_xml or getattr(ds, "geo_loc", None) \
+                or not ds.geo_transform:
+            return None
+        return (ds.srs, tuple(ds.geo_transform), H, W)
+
+    @staticmethod
+    def _device_failed(f: _FileDrill) -> None:
+        # any device-path failure (upload OOM, compile) degrades that
+        # file to host reads, not a failed request — but loudly, and
+        # counted
+        from .executor import default_executor
+        log.exception("drill device path failed for %s; answering from "
+                      "host reads", f.ds.file_path)
+        default_executor._count("drill_device_error")
+
+    def _stack(self, f: _FileDrill):
+        """The file's device-resident stack (the whole variable in HBM,
+        uploaded once per file), or None: a VRT, the cache off, not
+        resident yet."""
+        from . import drill_cache as DC
+        if f.vrt_xml or not DC.enabled():
+            return None
+        # async by default: a cold request answers from host reads while
+        # the stack uploads in the background
+        getter = DC.default_drill_cache.get if DC.sync_mode() \
+            else DC.default_drill_cache.get_async
+        try:
+            with obs_span("drill.device") as dsp:
+                st = getter(f.ds.file_path, f.is_nc, f.var, f.band0,
+                            f.ds.nodata)
+                if st is None:
+                    dsp.set(resident=False)
+                return st
+        except Exception:
+            self._device_failed(f)
+            return None
+
+    def _enqueue(self, f: _FileDrill, st, grid: Optional[tuple]) -> bool:
+        """Put the file's window gather and reductions on the device's
+        queue: the request ships only the polygon mask + timestep
+        indices (KBs, once per grid), never the (B, window) raster.
+        False where host reads have to answer instead."""
+        try:
+            with obs_span("drill.device") as dsp:
+                key = None if grid is None else (grid, tuple(f.sel))
+                if key in self._uploads:
+                    up = self._uploads[key]
+                else:
+                    up = _device_upload(st.shape, f.sel, f.read_idx, f.mask,
+                                        f.win)
+                    if key is not None:
+                        self._uploads[key] = up
+                if up is None:
+                    return False
+                dsp.set(bucket=up.hw, bands=len(f.read_idx),
+                        bands_padded=int(up.tsel.shape[0]))
+                self._enqueued.append((f, _device_enqueue(st, up, self.req)))
+                self._in_flight += up.gather_bytes
+                return True
+        except Exception:
+            self._device_failed(f)
+            return False
+
+    # -- all of them -----------------------------------------------------
+    def collect(self) -> None:
+        """The readback: every enqueued file's statistics come to
+        the host together, then each file's answer is finished in the
+        order the files were begun.  A file whose result cannot be read
+        is answered from host reads; the others keep theirs."""
+        if not self._enqueued:
+            return
+        from .executor import default_executor
+        queued, self._enqueued, self._in_flight = self._enqueued, [], 0
+        # the span ends where the values are host arrays, sums divided
+        with obs_span("drill.device", queued=len(queued)):
+            try:
+                got = jax.device_get([dev for _, dev in queued])
+            except Exception:
+                # file by file, to find the one at fault
+                got = []
+                for f, dev in queued:
+                    try:
+                        got.append(jax.device_get(dev))
+                    except Exception:
+                        self._device_failed(f)
+                        got.append(None)
+            got = [host and _stats_finish(*host) for host in got]
+        # which leg answered lands in /debug executor.dispatches, beside
+        # the render legs
+        for (f, _), host in zip(queued, got):
+            if host is None:
+                f.stats = _drill_host(f, self.req)
+                continue
+            default_executor._count("drill_device")
+            B = len(f.read_idx)
+            vals, counts, dec = host
+            f.stats = _maybe_interp(vals[:B], counts[:B], dec[:B],
+                                    f.read_idx, f.sel, f.stride, self.req)
+
+
 def _drill_file(ds: Dataset, sel: List[int], g4326: geom.Geometry,
                 req: GeoDrillRequest, vrt_xml: Optional[str] = None):
     """Masked reductions for the selected bands of one file (or of a
-    rendered VRT wrapping it, `drill.go:363-423`)."""
-    is_vrt = bool(vrt_xml)
-    is_nc = not is_vrt and not ds.ds_name.upper().startswith("GMT:") \
-        and (ds.file_path.lower().endswith((".nc", ".nc4"))
-             or ds.ds_name.upper().startswith("NETCDF:"))
-    with contextlib.ExitStack() as opened:
-        with obs_span("drill.prepare", kind="vrt" if is_vrt else
-                      "nc" if is_nc else "tiff") as psp:
+    rendered VRT wrapping it, `drill.go:363-423`): the group of one."""
+    group = _DrillGroup(g4326, req)
+    f = group.start(ds, sel, vrt_xml)
+    group.collect()
+    return f.stats
+
+
+def _drill_host(f: _FileDrill, req: GeoDrillRequest, h=None):
+    """Answer one file from host reads of its window.  ``h`` is its open
+    handle where the caller has one; else the file is opened here, and
+    closed."""
+    from .executor import default_executor
+    default_executor._count("drill_host")
+    ds = f.ds
+    c0, r0, c1, r1 = f.win
+    with contextlib.ExitStack() as opened, \
+            obs_span("drill.host_read", bands=len(f.read_idx)):
+        if h is None:
             try:
-                if is_vrt:
-                    from ..io.vrt import VRTRaster
-                    h = VRTRaster(vrt_xml)
-                    H, W = h.height, h.width
-                elif is_nc:
-                    h = NetCDF(ds.file_path)
-                    var = ds.ds_name.split(":")[-1].strip('"')
-                    v = h.variables[var]
-                    H, W = v.shape[-2], v.shape[-1]
-                else:
-                    from ..io.registry import open_raster
-                    h = open_raster(ds.file_path)
-                    H, W = h.height, h.width
-            except (OSError, ValueError, KeyError, ET.ParseError):
+                h = f.open()[0]
+            except _UNOPENABLE:
                 return None
             opened.callback(h.close)
+        v = h.variables[f.var] if f.is_nc else None
+        bands_data = []
+        for k in f.read_idx:
+            ti = f.sel[k]
+            if f.vrt_xml:
+                data = h.read(1, (c0, r0, c1 - c0, r1 - r0),
+                              time_index=ti)
+                nodata = h.nodata
+            elif f.is_nc:
+                data = h.read_slice(
+                    f.var, ti if len(v.shape) > 2 else None,
+                    (c0, r0, c1 - c0, r1 - r0))
+                nodata = ds.nodata if ds.nodata is not None \
+                    else v.nodata
+            else:
+                # GeoTIFF granules carry one timestamp per file; the
+                # band index comes from the crawler's ds_name suffix
+                data = h.read(f.band0, (c0, r0, c1 - c0, r1 - r0))
+                nodata = ds.nodata if ds.nodata is not None \
+                    else h.nodata
+            bands_data.append((data.astype(np.float32),
+                               nodata_mask(data, nodata)))
 
-            made = _drill_window(ds, g4326, h, H, W, is_vrt)
-            if made is None:
-                return None
-            mask, (c0, r0, c1, r1) = made
-            psp.set(window=(r1 - r0, c1 - c0))
-
-            # strided band reads with interpolation (`drill.go:119-214`)
-            stride = max(req.band_strides, 1)
-            read_idx: List[int] = []
-            for s in range(0, len(sel), stride):
-                e = min(s + stride, len(sel))
-                read_idx.append(s)
-                if e - 1 != s:
-                    read_idx.append(e - 1)
-            read_idx = sorted(set(read_idx))
-
-            band0 = 1
-            if not is_nc and ":" in ds.ds_name \
-                    and ds.ds_name.rsplit(":", 1)[-1].isdigit():
-                band0 = int(ds.ds_name.rsplit(":", 1)[-1])
-
-        # device-resident stack fast path: the whole variable stack
-        # lives in HBM (uploaded once per file), the window slice +
-        # reductions run on device, and this request ships only the
-        # polygon mask + timestep indices — KBs instead of the
-        # (B, window) raster through the host link
-        # which leg answered lands in /debug executor.dispatches,
-        # beside the render legs
-        from .executor import default_executor
-        if not is_vrt:
-            from . import drill_cache as DC
-            if DC.enabled():
-                try:
-                    # async by default: a cold request answers from host
-                    # reads while the stack uploads in the background
-                    getter = DC.default_drill_cache.get if DC.sync_mode() \
-                        else DC.default_drill_cache.get_async
-                    with obs_span("drill.device") as dsp:
-                        st = getter(
-                            ds.file_path, is_nc, var if is_nc else "",
-                            band0, ds.nodata)
-                        if st is None:
-                            dsp.set(resident=False)
-                            dev = None
-                        else:
-                            dev = _drill_device(st, sel, read_idx, mask,
-                                                (c0, r0, c1, r1), req)
-                except Exception:
-                    # any device-path failure (upload OOM, compile)
-                    # degrades to host reads, not a failed request —
-                    # but loudly, and counted
-                    log.exception("drill device path failed for %s; "
-                                  "answering from host reads",
-                                  ds.file_path)
-                    default_executor._count("drill_device_error")
-                    dev = None
-                if dev is not None:
-                    default_executor._count("drill_device")
-                    vals, counts, dec = dev
-                    return _maybe_interp(vals, counts, dec, read_idx,
-                                         sel, stride, req)
-        default_executor._count("drill_host")
-
-        with obs_span("drill.host_read", bands=len(read_idx)):
-            bands_data = []
-            for k in read_idx:
-                ti = sel[k]
-                if is_vrt:
-                    data = h.read(1, (c0, r0, c1 - c0, r1 - r0),
-                                  time_index=ti)
-                    nodata = h.nodata
-                elif is_nc:
-                    data = h.read_slice(
-                        var, ti if len(v.shape) > 2 else None,
-                        (c0, r0, c1 - c0, r1 - r0))
-                    nodata = ds.nodata if ds.nodata is not None \
-                        else v.nodata
-                else:
-                    # GeoTIFF granules carry one timestamp per file; the
-                    # band index comes from the crawler's ds_name suffix
-                    data = h.read(band0, (c0, r0, c1 - c0, r1 - r0))
-                    nodata = ds.nodata if ds.nodata is not None \
-                        else h.nodata
-                bands_data.append((data.astype(np.float32),
-                                   nodata_mask(data, nodata)))
-
-            data = np.stack([d for d, _ in bands_data])
-            valid = np.stack([m for _, m in bands_data]) & (mask[None] > 0)
-            B = data.shape[0]
-            vals, counts, dec = _stats_tail(data.reshape(B, -1),
-                                            valid.reshape(B, -1), req)
-        return _maybe_interp(vals, counts, dec, read_idx, sel, stride,
-                             req)
+        data = np.stack([d for d, _ in bands_data])
+        valid = np.stack([m for _, m in bands_data]) & (f.mask[None] > 0)
+        B = data.shape[0]
+        vals, counts, dec = _stats_host(data.reshape(B, -1),
+                                        valid.reshape(B, -1), req)
+    return _maybe_interp(vals, counts, dec, f.read_idx, f.sel, f.stride,
+                         req)
 
 
 def _stats_host(dataf: np.ndarray, validf: np.ndarray,
@@ -581,7 +796,7 @@ def _stats_host(dataf: np.ndarray, validf: np.ndarray,
     (B, window) block through the device link just to reduce it — the
     reference's reductions are host-side too (`drill.go:128-220`).
     Steady-state requests still reduce on device from the resident
-    stack (`_drill_device`).  Same implementation bodies as the device
+    stack (`_device_enqueue`).  Same implementation bodies as the device
     path (`ops.drill.*_impl` parameterised on the array namespace), so
     cold and warm responses cannot drift."""
     vals, counts = D.masked_mean_impl(
@@ -596,11 +811,32 @@ def _stats_host(dataf: np.ndarray, validf: np.ndarray,
 
 
 def _stats_tail(dataf, validf, req: GeoDrillRequest):
-    """Masked mean + deciles over (B, N) data/valid — device or host
-    arrays (jnp.asarray is a no-op for resident device buffers; numpy
-    inputs reduce in numpy, see `_stats_host`)."""
+    """Masked mean + deciles over (B, N) data/valid, as host arrays —
+    from device or host arrays (numpy inputs reduce in numpy, see
+    `_stats_host`; device inputs are enqueued and read back at once)."""
     if isinstance(dataf, np.ndarray):
         return _stats_host(dataf, validf, req)
+    return _stats_finish(*jax.device_get(_stats_enqueue(dataf, validf, req)))
+
+
+def _stats_finish(kind: str, a, counts, dec):
+    """`_stats_enqueue`'s answer, once it is on the host, as (values,
+    counts, deciles): a leg that left sums divides them here."""
+    counts = np.asarray(counts)
+    if kind == "sum":
+        a = np.where(counts > 0, np.asarray(a) / np.maximum(counts, 1),
+                     0.0).astype(np.float32)
+    return np.asarray(a), counts, np.asarray(dec)
+
+
+def _stats_enqueue(dataf, validf, req: GeoDrillRequest):
+    """Enqueue masked mean + deciles over device-resident (B, N)
+    data/valid (jnp.asarray is a no-op for resident device buffers) and
+    return without waiting: (kind, a, counts, deciles), where ``a`` holds
+    means (kind "mean") or sums still to be divided by the counts
+    ("sum": the Pallas leg), for `_stats_finish` after the readback.
+    The mesh and wave legs block by nature and hand back host arrays."""
+    no_dec = np.zeros((dataf.shape[0], 0), np.float32)
     from ..mesh.dispatch import compat_spmd
     spmd = compat_spmd()
     if spmd is not None and not req.deciles:
@@ -609,28 +845,26 @@ def _stats_tail(dataf, validf, req: GeoDrillRequest):
         # sort — those requests stay single-device)
         v, c = spmd.masked_stats(dataf, validf, req.clip_lower,
                                  req.clip_upper, req.pixel_count)
-        return (np.asarray(v), np.asarray(c),
-                np.zeros((dataf.shape[0], 0), np.float32))
+        return "mean", np.asarray(v), np.asarray(c), no_dec
     from ..ops.pallas_tpu import (masked_stats_pallas, pallas_interpret,
                                   run_with_fallback)
 
+    # the two legs leave different things on the device, a sum and a
+    # mean: each says which, so the race may still hand back either
     def _via_pallas():
         # VMEM-streamed reduction kernel on TPU backends
         s, c = masked_stats_pallas(
             jnp.asarray(dataf), jnp.asarray(validf),
             req.clip_lower, req.clip_upper,
             interpret=pallas_interpret())
-        c = np.asarray(c)
-        v = np.where(c > 0, np.asarray(s) / np.maximum(c, 1),
-                     0.0).astype(np.float32)
-        return v, c
+        return "sum", s, c
 
     def _via_xla():
         v, c = D.masked_mean(
             jnp.asarray(dataf), jnp.asarray(validf),
             clip_lower=req.clip_lower, clip_upper=req.clip_upper,
             pixel_count=req.pixel_count)
-        return np.asarray(v), np.asarray(c)
+        return "mean", v, c
 
     from .waves import default_waves, waves_enabled
     if waves_enabled():
@@ -639,30 +873,29 @@ def _stats_tail(dataf, validf, req: GeoDrillRequest):
         # (the reduction is per-row independent, so the stacked result
         # is bit-identical to per-call); the per-call XLA leg is the
         # incident failover
+        kind = "mean"
         vals, counts = default_waves().drill_stats(
             dataf, validf, float(req.clip_lower),
-            float(req.clip_upper), bool(req.pixel_count), _via_xla)
+            float(req.clip_upper), bool(req.pixel_count),
+            lambda: tuple(np.asarray(x) for x in _via_xla()[1:]))
     elif not req.pixel_count:
         # sync_token engages the fallback guard's first-call speed race
         # too: at deep-stack shapes (1000, 16k) the pallas reduction is
         # the prime suspect for the r5 on-chip warm-drill outlier, and
         # the race demotes it automatically wherever XLA measures
-        # faster.  The shape is BUCKETED (`_drill_device` pads the band
+        # faster.  The shape is BUCKETED (`_device_upload` pads the band
         # axis to pow2 and the window to shape buckets), so the token
         # cardinality — and with it the number of races — is bounded
         # plain-int token: the durable ledger round-trips tokens through
         # repr/literal_eval, so numpy ints must not leak in
-        vals, counts = run_with_fallback(
+        kind, vals, counts = run_with_fallback(
             "masked_stats", _via_pallas, _via_xla,
             sync_token=tuple(int(d) for d in dataf.shape))
     else:
-        vals, counts = _via_xla()
-    if req.deciles:
-        dec = np.asarray(D.deciles(jnp.asarray(dataf),
-                                   jnp.asarray(validf), req.deciles))
-    else:
-        dec = np.zeros((dataf.shape[0], 0), np.float32)
-    return vals, counts, dec
+        kind, vals, counts = _via_xla()
+    dec = D.deciles(jnp.asarray(dataf), jnp.asarray(validf), req.deciles) \
+        if req.deciles else no_dec
+    return kind, vals, counts, dec
 
 
 def _maybe_interp(vals, counts, dec, read_idx, sel, stride,
@@ -679,18 +912,15 @@ def _maybe_interp(vals, counts, dec, read_idx, sel, stride,
     return vals, counts, dec
 
 
-def _drill_device(st, sel: List[int], read_idx: List[int],
-                  mask: np.ndarray, win, req: GeoDrillRequest):
-    """Drill one file from its DEVICE-RESIDENT stack: upload the
-    rasterized polygon mask + timestep indices (KBs), slice the window
-    on device (`ops.drill.window_gather`), reduce in place.  Returns
-    (values, counts, deciles) for the read_idx bands, or None when the
-    window doesn't fit a padded bucket (caller falls back to host
-    reads)."""
+def _device_upload(shape, sel: List[int], read_idx: List[int],
+                   mask: np.ndarray, win) -> Optional[_Upload]:
+    """Pad the rasterized polygon mask and the timestep indices to their
+    buckets and put them on the device (KBs).  None when the window
+    doesn't fit a padded bucket (the caller falls back to host reads)."""
     from .executor import _bucket, _bucket_pow2
 
     c0, r0, c1, r1 = win
-    T, H, W = st.shape
+    H, W = shape[-2:]
     wh, ww = r1 - r0, c1 - c0
     bh = min(_bucket(wh), H)
     bw = min(_bucket(ww), W)
@@ -704,9 +934,19 @@ def _drill_device(st, sel: List[int], read_idx: List[int],
     mask_p[r0 - r0c:r0 - r0c + wh, c0 - c0c:c0 - c0c + ww] = mask > 0
     tsel = np.asarray([sel[k] for k in read_idx], np.int32)
     B = len(tsel)
-    Bp = _bucket_pow2(B)
-    tsel_p = np.pad(tsel, (0, Bp - B), mode="edge")
-    set_attr(bucket=(bh, bw), bands=B, bands_padded=Bp)
+    tsel_p = np.pad(tsel, (0, _bucket_pow2(B) - B), mode="edge")
+    # 0-d arrays, not numpy scalars: jnp.asarray runs a program to
+    # convert a scalar and only uploads an array
+    return _Upload(jnp.asarray(mask_p), jnp.asarray(tsel_p), (bh, bw),
+                   jnp.asarray(np.asarray(r0c, np.int32)),
+                   jnp.asarray(np.asarray(c0c, np.int32)))
+
+
+def _device_enqueue(st, up: _Upload, req: GeoDrillRequest):
+    """Drill one file from its DEVICE-RESIDENT stack: slice the window
+    on device (`ops.drill.window_gather`) and reduce it in place, both
+    enqueued and neither waited for.  Returns `_stats_enqueue`'s answer
+    for the padded bands, still on the device."""
     # nodata compares in the stack's NATIVE dtype (parity with
     # ops.raster.nodata_mask); a nodata not representable there matches
     # nothing, exactly like the host path's dtype-promoting !=
@@ -724,10 +964,9 @@ def _drill_device(st, sel: List[int], read_idx: List[int],
         nd_native = np.asarray(nd).astype(dtype)
         use_nd = bool(np.asarray(float(nd_native) == float(nd)))
     dataf, validf = D.window_gather(
-        st.dev, jnp.asarray(tsel_p), np.int32(r0c), np.int32(c0c),
-        jnp.asarray(mask_p), nd_native, np.bool_(use_nd), (bh, bw))
-    vals, counts, dec = _stats_tail(dataf, validf, req)
-    return vals[:B], counts[:B], dec[:B]
+        st.dev, up.tsel, up.r0c, up.c0c, up.mask, nd_native,
+        np.bool_(use_nd), up.hw)
+    return _stats_enqueue(dataf, validf, req)
 
 
 def _merge(acc, req: GeoDrillRequest) -> DrillResult:

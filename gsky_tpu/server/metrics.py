@@ -306,16 +306,19 @@ class MetricsLogger:
                      ("format_s", "wps.format"))
 
     def record_drill(self, stages: Dict[str, float], wall_s: float,
-                     files: int) -> None:
+                     files: int, windows: int) -> None:
         """Fold one answered WPS Execute into the /debug `drill_stages`
         aggregates.  ``stages`` is the request's trace folded by span
         name (`Trace.seconds_by_name`), ``wall_s`` the root span's age,
-        ``files`` the files drilled."""
+        ``files`` the files drilled (answered by the device or by host
+        reads), ``windows`` the windows and masks made for them (the
+        `drill.prepare` spans: files on one grid share one)."""
         try:
             last = {k: round(stages.get(name, 0.0), 6)
                     for k, name in self._DRILL_STAGES}
             last["wall_s"] = round(wall_s, 6)
             last["files"] = files
+            last["windows"] = windows
             with self._summary_lock:
                 e = self._drills
                 e["requests"] = e.get("requests", 0) + 1

@@ -1990,7 +1990,8 @@ class OWSServer:
             # measurement, read by /debug, /metrics and the trace alike
             self.metrics.record_drill(trace.seconds_by_name(),
                                       trace.age_s(),
-                                      trace.count("drill.prepare"))
+                                      files=trace.total("files"),
+                                      windows=trace.count("drill.prepare"))
         return resp
 
 

@@ -330,6 +330,272 @@ class TestDrill:
         c = small.get(nc, True, "phot_veg", 1, None)
         assert c is not None and c.serial != a.serial  # was evicted
 
+    # -- one request's co-gridded files, drilled as one group ----------
+
+    GROUP_WKT = ("POLYGON((148.3 -35.9,149.0 -35.7,149.1 -35.2,148.6 -35.0,"
+                 "148.2 -35.3,148.3 -35.9))")
+    BANDS = ["phot_veg", "nphot_veg", "bare_soil"]
+
+    @pytest.fixture(scope="class")
+    def stacks(self, tmp_path_factory):
+        """Three one-variable NetCDF stacks on one 96 x 96 grid and a
+        fourth on a grid of its own, five steps each, crawled into a
+        store of their own."""
+        from gsky_tpu.index.crawler import extract
+        from gsky_tpu.index.store import MASStore
+        from gsky_tpu.io.netcdf import write_netcdf3
+
+        root = str(tmp_path_factory.mktemp("stacks"))
+        rng = np.random.default_rng(7)
+        times = np.array([t(d) for d in (10, 11, 12, 13, 14)])
+        store = MASStore()
+        grids = {"phot_veg": 96, "nphot_veg": 96, "bare_soil": 96,
+                 "other": 64}
+        for name, n in grids.items():
+            data = rng.uniform(0, 100, (5, n, n)).astype(np.float32)
+            data[:, : n // 8, : n // 8] = -1.0
+            path = os.path.join(root, f"{name}.nc")
+            write_netcdf3(path, {name: data},
+                          np.linspace(148.0, 149.5, n),
+                          np.linspace(-34.8, -36.2, n), EPSG4326,
+                          times=times, nodata=-1.0)
+            rec = extract(path)
+            assert not rec.get("error"), rec
+            store.ingest(rec)
+        return {"root": root, "mas": MASClient(store)}
+
+    def _group_req(self, stacks, bands=None, **kw):
+        kw.setdefault("start_time", t(10))
+        kw.setdefault("end_time", t(14))
+        return GeoDrillRequest(collection=stacks["root"],
+                               bands=bands or self.BANDS,
+                               geometry_wkt=self.GROUP_WKT, approx=False,
+                               **kw)
+
+    @staticmethod
+    def _file_by_file(dp, req):
+        """The answer as it was made before files were grouped: one
+        `_drill_file` per dataset, in the index's order."""
+        from collections import defaultdict
+
+        from gsky_tpu.geo import geometry as geom
+        from gsky_tpu.pipeline import drill as DR
+        acc = defaultdict(list)
+        g4326 = geom.from_wkt(req.geometry_wkt)
+        for ds in dp.index(req):
+            sel = DR._selected_times(ds, req)
+            stats = DR._drill_file(ds, sel, g4326, req)
+            if stats is None:
+                continue
+            values, counts, deciles = stats
+            for k, ti in enumerate(sel):
+                date = ds.timestamps[ti]
+                acc[(ds.namespace, date)].append(
+                    (float(values[k]), int(counts[k])))
+                for d in range(req.deciles):
+                    acc[(f"{ds.namespace}_d{d + 1}", date)].append(
+                        (float(deciles[k, d]), 1))
+        return DR._merge(acc, req)
+
+    @staticmethod
+    def _same_bits(got, want):
+        assert got.dates == want.dates and got.dates
+        assert got.raw_namespaces == want.raw_namespaces
+        assert sorted(got.values) == sorted(want.values)
+        for ns in want.values:
+            a = np.asarray(got.values[ns], np.float64)
+            b = np.asarray(want.values[ns], np.float64)
+            assert a.tobytes() == b.tobytes(), ns
+            assert got.counts[ns] == want.counts[ns], ns
+
+    @staticmethod
+    def _legs():
+        from gsky_tpu.pipeline.executor import default_executor
+        return dict(default_executor.bucket_stats)
+
+    @staticmethod
+    def _grew(before, leg):
+        from gsky_tpu.pipeline.executor import default_executor
+        return default_executor.bucket_stats.get(leg, 0) - before.get(leg, 0)
+
+    def test_group_one_grid_one_window_no_file_opened(self, stacks,
+                                                      monkeypatch):
+        """(a) three resident stacks on one grid: one rasterised mask,
+        no header opened, `windows` 1 and `files` 3, and the answer is
+        bit for bit the file-by-file one."""
+        from gsky_tpu import obs
+        from gsky_tpu.geo import geometry as geom
+        from gsky_tpu.pipeline import drill as DR
+        from gsky_tpu.server.metrics import MetricsLogger
+
+        monkeypatch.setenv("GSKY_DRILL_CACHE", "sync")
+        dp = DrillPipeline(stacks["mas"])
+        req = self._group_req(stacks)
+        want = self._file_by_file(dp, req)      # uploads the stacks too
+        burns, opened = [], []
+        rasterize = geom.rasterize
+        monkeypatch.setattr(
+            geom, "rasterize",
+            lambda *a, **k: burns.append(1) or rasterize(*a, **k))
+        monkeypatch.setattr(
+            DR, "NetCDF", lambda path: opened.append(path) or 1 / 0)
+        legs0 = self._legs()
+        with obs.start_trace("test") as trace:
+            got = dp.process(req)
+        assert len(burns) == 1 and opened == []
+        assert self._grew(legs0, "drill_device") == 3
+        assert self._grew(legs0, "drill_host") == 0
+        self._same_bits(got, want)
+        assert len(got.dates) == 5 and sorted(got.values) == sorted(self.BANDS)
+        # the counters /debug reads, folded as the server folds them
+        assert trace.count("drill.prepare") == 1
+        assert trace.total("files") == 3
+        m = MetricsLogger()
+        m.record_drill(trace.seconds_by_name(), trace.age_s(),
+                       files=trace.total("files"),
+                       windows=trace.count("drill.prepare"))
+        stages = m.summary()["drill_stages"]
+        assert stages["windows"] == 1 and stages["files"] == 3
+        assert stages["last"]["windows"] == 1
+        assert stages["device_s"] > 0 and stages["host_read_s"] == 0
+
+    def test_group_two_grids_two_windows(self, stacks, monkeypatch):
+        """(b) a second grid in the same request gets a window of its
+        own; both answers as file by file."""
+        from gsky_tpu import obs
+
+        monkeypatch.setenv("GSKY_DRILL_CACHE", "sync")
+        dp = DrillPipeline(stacks["mas"])
+        req = self._group_req(stacks, bands=self.BANDS + ["other"])
+        want = self._file_by_file(dp, req)
+        with obs.start_trace("test") as trace:
+            got = dp.process(req)
+        assert trace.count("drill.prepare") == 2
+        assert trace.total("files") == 4
+        self._same_bits(got, want)
+        assert "other" in got.values
+
+    def test_group_one_stack_not_resident(self, stacks, monkeypatch):
+        """(c) one of three stacks is not on the device: that file is
+        answered by host reads, the others by the device."""
+        from gsky_tpu import obs
+        from gsky_tpu.pipeline import drill_cache as DC
+
+        monkeypatch.setenv("GSKY_DRILL_CACHE", "sync")
+        dp = DrillPipeline(stacks["mas"])
+        req = self._group_req(stacks)
+        want = self._file_by_file(dp, req)
+        get = DC.default_drill_cache.get
+        monkeypatch.setattr(
+            DC.default_drill_cache, "get",
+            lambda path, *a: None if path.endswith("nphot_veg.nc")
+            else get(path, *a))
+        legs0 = self._legs()
+        with obs.start_trace("test") as trace:
+            got = dp.process(req)
+        assert self._grew(legs0, "drill_device") == 2
+        assert self._grew(legs0, "drill_host") == 1
+        assert self._grew(legs0, "drill_device_error") == 0
+        assert trace.count("drill.host_read") == 1
+        assert trace.total("files") == 3
+        # host reads and the device agree to rounding, not to the bit:
+        # the file-by-file answer takes the same legs file for file
+        ref = self._file_by_file(dp, req)
+        self._same_bits(got, ref)
+        for ns in want.values:
+            np.testing.assert_allclose(got.values[ns], want.values[ns],
+                                       rtol=1e-6, err_msg=ns)
+            assert got.counts[ns] == want.counts[ns]
+
+    def test_group_device_error_at_collect_stays_with_its_file(
+            self, stacks, monkeypatch):
+        """(d) one file's result cannot be read back: it is counted,
+        that file is answered from host reads, the others keep their
+        device values."""
+        from gsky_tpu.pipeline import drill as DR
+
+        monkeypatch.setenv("GSKY_DRILL_CACHE", "sync")
+        dp = DrillPipeline(stacks["mas"])
+        req = self._group_req(stacks)
+        want = self._file_by_file(dp, req)
+
+        class Unreadable:
+            def __array__(self, *a, **k):
+                raise RuntimeError("injected: device result lost")
+
+        enqueue, calls = DR._stats_enqueue, []
+
+        def second_is_lost(dataf, validf, r):
+            kind, a, c, dec = enqueue(dataf, validf, r)
+            calls.append(1)
+            return (kind, Unreadable(), c, dec) if len(calls) == 2 \
+                else (kind, a, c, dec)
+
+        monkeypatch.setattr(DR, "_stats_enqueue", second_is_lost)
+        legs0 = self._legs()
+        got = dp.process(req)
+        assert self._grew(legs0, "drill_device_error") == 1
+        assert self._grew(legs0, "drill_host") == 1
+        assert self._grew(legs0, "drill_device") == 2
+        assert got.dates == want.dates
+        lost = [d.namespace for d in dp.index(req)][1]
+        for ns in want.values:
+            if ns != lost:      # untouched, to the bit
+                assert np.asarray(got.values[ns]).tobytes() \
+                    == np.asarray(want.values[ns]).tobytes(), ns
+            np.testing.assert_allclose(got.values[ns], want.values[ns],
+                                       rtol=1e-6, err_msg=ns)
+            assert got.counts[ns] == want.counts[ns]
+
+    def test_group_reads_back_early_past_its_bytes_in_flight(
+            self, stacks, monkeypatch):
+        """Windows too large to keep three enqueued are read back as
+        they go; the answer does not change."""
+        from gsky_tpu import obs
+        from gsky_tpu.pipeline import drill as DR
+
+        monkeypatch.setenv("GSKY_DRILL_CACHE", "sync")
+        dp = DrillPipeline(stacks["mas"])
+        req = self._group_req(stacks)
+        with obs.start_trace("test") as trace:
+            want = dp.process(req)
+        readbacks = [sp["attrs"]["queued"] for sp in trace.span_dicts()
+                     if "queued" in sp.get("attrs", {})]
+        assert readbacks == [3]
+        monkeypatch.setattr(DR, "_GATHER_BYTES_IN_FLIGHT", 2 * 8 * 64 * 64 * 4)
+        with obs.start_trace("test") as trace:
+            got = dp.process(req)
+        readbacks = [sp["attrs"]["queued"] for sp in trace.span_dicts()
+                     if "queued" in sp.get("attrs", {})]
+        assert readbacks == [2, 1]
+        assert trace.count("drill.prepare") == 1
+        self._same_bits(got, want)
+
+    @pytest.mark.parametrize("kw, env", [
+        (dict(deciles=3), {}), (dict(band_strides=2), {}),
+        (dict(band_strides=3, deciles=2), {}), (dict(pixel_count=True), {}),
+        # the Pallas leg leaves sums on the device, divided after the
+        # readback; the wave leg hands back host arrays
+        (dict(), {"GSKY_PALLAS": "interpret", "GSKY_WAVES": "0"}),
+        (dict(deciles=2), {"GSKY_PALLAS": "interpret"})])
+    def test_group_deciles_and_strides_as_file_by_file(self, stacks,
+                                                       monkeypatch, kw, env):
+        """(e) deciles, strided reads with interpolation and pixel
+        counts go through the group as they go file by file, whichever
+        leg reduces."""
+        for k, v in env.items():
+            monkeypatch.setenv(k, v)
+        monkeypatch.setenv("GSKY_DRILL_CACHE", "sync")
+        dp = DrillPipeline(stacks["mas"])
+        req = self._group_req(stacks, **kw)
+        want = self._file_by_file(dp, req)
+        legs0 = self._legs()
+        got = dp.process(req)
+        assert self._grew(legs0, "drill_device") == 3
+        self._same_bits(got, want)
+        if kw.get("deciles"):
+            assert "bare_soil_d1" in got.values
+
     def test_drill_expression(self, mas, archive):
         req = GeoDrillRequest(
             collection=archive["root"],

@@ -475,6 +475,9 @@ class TestDrillStages:
         assert ds["requests"] == sent
         assert ds["files"] >= sent
         for doc in (ds, ds["last"]):
+            # files on one grid share a window: never more windows than
+            # files, and no file drilled without one
+            assert 1 <= doc["windows"] <= doc["files"], doc
             assert all(doc[k] >= 0 for k in self.STAGES), doc
             # the stages run one after another: no request's named
             # stages sum to more than its wall time
