@@ -2036,17 +2036,18 @@ def bench_kernels():
         "chip_tiles_per_s": round(NB * 1e3 / pipe_ms, 1)}
 
     # --- channel-packed RGB render at the cfg2 shape (bilinear)
-    rgb = jnp.asarray(
-        rng.uniform(200, 3000, (S, S, 3)).astype(np.int16))
+    rgb = (tuple(jnp.asarray(b) for b in
+                 rng.uniform(200, 3000, (3, S, S)).astype(np.int16)),)
+    prio1 = jnp.ones((1, 3), jnp.float32)
     param1 = jnp.asarray(np.array(
         [0.0, 1.0, 0.0, 0.0, 0.0, 1.0, S, S, np.nan, 0, 0], np.float32))
 
     def render_rgb():
-        return render_rgba_ctrl(rgb, ctrl, param1, sp, "bilinear",
-                                (h, w), 16, True, 0)
+        return render_rgba_ctrl(rgb, ctrl, param1[None], prio1, sp,
+                                "bilinear", (h, w), 16, True, 0)
 
     sync_ms, pipe_ms = timeit(render_rgb)
-    traffic = h * w * 4 * 3 * rgb.dtype.itemsize + h * w * 4
+    traffic = h * w * 4 * 3 * rgb[0][0].dtype.itemsize + h * w * 4
     out["render_rgba_256"] = {
         "sync_ms": sync_ms, "pipelined_ms": pipe_ms,
         "chip_tiles_per_s": round(1e3 / pipe_ms, 1),
@@ -2059,9 +2060,9 @@ def bench_kernels():
         win0r_dev = jnp.asarray(win0r)
 
         def render_rgb_win():
-            return render_rgba_ctrl(rgb, ctrl, param1, sp, "bilinear",
-                                    (h, w), 16, True, 0,
-                                    win=winr, win0=win0r_dev)
+            return render_rgba_ctrl(rgb, ctrl, param1[None], prio1, sp,
+                                    "bilinear", (h, w), 16, True, 0,
+                                    win=winr, win0=win0r_dev[None])
 
         sync_ms, pipe_ms = timeit(render_rgb_win)
         out["render_rgba_256_win"] = {

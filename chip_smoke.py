@@ -50,9 +50,8 @@ SIZES = {
     # assumed: one Landsat-8 OLI scene, 7,601 x 7,761 px int16 at 30 m
     # in UTM (118 MB on disk, 244 MB as the 256-px-bucketed f32 the
     # scene cache keeps in HBM).  No embedded overviews, so every zoom
-    # level reads level 1: the 2 GiB scene cache (a constant in
-    # pipeline/scene_cache.py) holds the 7 band-scenes below and
-    # nothing more.
+    # level reads level 1; the 7 band-scenes below are 1.7 GB of the
+    # scene cache's byte budget (device.residency_budget).
     "scene_hw": (7601, 7761),
     # >= 4 overlapping scenes on consecutive days, each shifted a third
     # of a scene east and a fifth south (bench.py's layout at size)
@@ -406,8 +405,10 @@ class Reference:
             if made is None:
                 return None
             granules, ns_index, out_sel = made
+            ns_ids, prio = self.pipe._ns_prios(granules, ns_index)
             out = self.ex.render_rgba_byte(
-                granules, out_sel, req.dst_gt(), req.crs, 256, 256,
+                granules, ns_ids, prio, out_sel, req.dst_gt(), req.crs,
+                256, 256,
                 req.resample, *self.SCALE, cache=self.cache)
         else:
             req = self._req(arch, layer, bb, (256, 256), "near")

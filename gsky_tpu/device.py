@@ -30,6 +30,16 @@ DEFAULT_CACHE_DIR = os.path.join(
     ".jax_cache")
 
 
+# What the drill stack cache may hold (`pipeline/drill_cache.py`), and
+# so what the scene cache may not: the two share one device.
+DRILL_STACK_BYTES = 4 << 30
+# Without `memory_stats()` (the CPU backend: tests) the scene cache's
+# budget is the 2 GiB it had while it was a constant.
+_FALLBACK_RESIDENT_BYTES = 2 << 30
+
+_budget: Optional[dict] = None
+
+
 class PlatformError(RuntimeError):
     """This process was not told to use the CPU and found no TPU."""
 
@@ -85,3 +95,31 @@ def ensure_platform() -> dict:
                  "device_count": len(devs),
                  "cache_dir": _place_compilation_cache(platform)}
     return _resolved
+
+
+def residency_budget() -> dict:
+    """How many bytes of decoded scenes, and of the executor's stacks of
+    them, may stay on the device between requests: what the device
+    reports as its memory, less what the drill stack cache may take,
+    less a quarter of it as headroom for what programs hold while they
+    run (the most measured is a drill's gathers in flight: 8.5 GB at
+    the peak with 3.2 GB resident, PERF.md PR 26).  Resolved once, at
+    the first scene load, and shown whole in /debug `device.residency`.
+    `pipeline/scene_cache.py` keeps scenes and stacks inside `budget`."""
+    global _budget
+    if _budget is None:
+        import jax
+        stats = jax.devices()[0].memory_stats() or {}
+        limit = int(stats.get("bytes_limit") or 0)
+        if limit:
+            headroom = limit // 4
+            _budget = {"source": "memory_stats", "bytes_limit": limit,
+                       "drill_stacks": DRILL_STACK_BYTES,
+                       "headroom": headroom,
+                       "budget": max(limit - DRILL_STACK_BYTES - headroom,
+                                     _FALLBACK_RESIDENT_BYTES)}
+        else:
+            _budget = {"source": "fallback", "bytes_limit": None,
+                       "drill_stacks": DRILL_STACK_BYTES, "headroom": None,
+                       "budget": _FALLBACK_RESIDENT_BYTES}
+    return _budget

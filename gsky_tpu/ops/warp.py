@@ -105,7 +105,8 @@ def _window_slice(arr, win, win0, axis: int):
     the static window ``win`` = (WR, WC) at traced (2,) int32 origin
     ``win0``.  Returns (sliced, r0f, c0f): the f32 origins callers
     subtract from their coordinate grids — exact, because subtracting
-    an integer ≤ 4096 from an f32 coordinate < 2^12 never rounds.
+    an integer from an f32 coordinate of the same magnitude (both
+    < 2^14 at granule size) never rounds.
     Nearest results are bit-identical to the full-scene kernel;
     interpolated methods can differ by 1 ulp where XLA contracts the
     tap-weight arithmetic differently between the two programs."""
@@ -488,39 +489,60 @@ def _resample_c(src, nodata, rows, cols, method: str):
 @functools.partial(jax.jit,
                    static_argnames=("method", "out_hw", "step", "auto",
                                     "colour_scale", "win"))
-def render_rgba_ctrl(scene, ctrl, param, scale_params,
+def render_rgba_ctrl(granules, ctrl, params, prios, scale_params,
                      method: str = "near",
                      out_hw: Tuple[int, int] = (256, 256),
                      step: int = 16, auto: bool = True,
                      colour_scale: int = 0,
                      win: Optional[Tuple[int, int]] = None, win0=None):
-    """Single-granule RGB fast path: one dispatch from a channel-packed
-    scene (sh, sw, 3) to the PNG-ready (h, w, 4) RGBA tile.  Compared
-    with `render_scenes_bands_ctrl` this computes warp indices and tap
-    weights ONCE for all three bands (the per-band variant's dominant
-    cost), and the host pulls one contiguous buffer that feeds the PNG
-    encoder without an interleave pass.  Alpha is 0 exactly where all
-    three scaled bytes are 255 — the transparency rule of the RGB PNG
-    encoder (`utils/ogc_encoders.go:82-142` parity).
+    """RGB fast path: one dispatch from the band scenes of G granules,
+    each a 3-tuple of (sh, sw) arrays on one grid as the scene cache
+    holds them, to the PNG-ready (h, w, 4) RGBA tile.  Only the gather
+    window of each band is packed channel-last (without a window the
+    packed scene is a temporary of this program), so no second copy of
+    a raster stays on the device.  Compared with
+    `render_scenes_bands_ctrl` this computes warp indices and tap
+    weights ONCE a granule for all three bands and gathers 3-vectors:
+    a TPU gather costs by the element gathered, so a tile over two
+    granules takes 8 gathers where the per-band kernel takes 32
+    (22.7 ms against 5.6 ms for one granule, PERF.md PR 27).  The host
+    pulls one contiguous buffer that feeds the PNG encoder without an
+    interleave pass.  Alpha is 0 exactly where all three scaled bytes
+    are 255 — the transparency rule of the RGB PNG encoder
+    (`utils/ogc_encoders.go:82-142` parity).
 
-    param: the (11,) granule params of `warp_scenes_batch` (priority and
-    namespace id unused here).  scale_params (3,) as elsewhere.
+    params: (G, 11), the granule params of `warp_scenes_batch` (priority
+    and namespace id unused here).  prios: (G, 3) f32, the mosaic
+    priority of each granule's band in each channel (the per-band
+    kernel's newest-wins, channel by channel); a row of -inf is a
+    padding granule.  win0: (G, 2), an origin a granule.
+    scale_params (3,) as elsewhere.
     """
     from .scale import auto_byte_scale, scale_to_byte
     h, w = out_hw
     sx = _bilerp_grid(ctrl[0], h, w, step)
     sy = _bilerp_grid(ctrl[1], h, w, step)
-    p = param
-    cols = (p[0] + p[1] * sx + p[2] * sy) - 0.5
-    rows = (p[3] + p[4] * sx + p[5] * sy) - 0.5
-    oob = (rows < -0.5) | (rows > p[6] - 0.5) \
-        | (cols < -0.5) | (cols > p[7] - 0.5)
-    rows = jnp.where(oob, jnp.nan, rows)
-    if win is not None:
-        scene, r0f, c0f = _window_slice(scene, win, win0, axis=0)
-        rows = rows - r0f
-        cols = cols - c0f
-    data, ok = _resample_c(scene, p[8], rows, cols, method)
+    data = jnp.zeros((h, w, 3), jnp.float32)
+    best = jnp.full((h, w, 3), -jnp.inf, jnp.float32)
+    for k, bands in enumerate(granules):
+        p = params[k]
+        cols = (p[0] + p[1] * sx + p[2] * sy) - 0.5
+        rows = (p[3] + p[4] * sx + p[5] * sy) - 0.5
+        oob = (rows < -0.5) | (rows > p[6] - 0.5) \
+            | (cols < -0.5) | (cols > p[7] - 0.5)
+        rows = jnp.where(oob, jnp.nan, rows)
+        if win is not None:
+            cut = [_window_slice(b, win, win0[k], axis=0) for b in bands]
+            bands = [c[0] for c in cut]
+            rows = rows - cut[0][1]
+            cols = cols - cut[0][2]
+        d, o = _resample_c(jnp.stack(bands, axis=-1), p[8], rows, cols,
+                           method)
+        score = jnp.where(o, prios[k], -jnp.inf)
+        take = score > best
+        data = jnp.where(take, d, data)
+        best = jnp.where(take, score, best)
+    ok = best > -jnp.inf
     if auto:
         if colour_scale == 1:
             logged = jnp.log10(data)
@@ -656,26 +678,46 @@ def _warp_scenes_scored(stack, sx, sy, params, method: str, n_ns: int,
     shared dynamic slice of the stack instead of the full scenes.  The
     caller guarantees every granule's finite gather footprint (incl.
     the 2-px cubic tap margin) lies inside the window; the origin
-    subtraction is an exact f32 op (integer ≤ 4096 off a coordinate
-    < 2^12), so the windowed kernel reads exactly the taps the
-    unwindowed one does (nearest: bit-identical; interpolated: 1-ulp
-    XLA-contraction differences between the two programs).
+    subtraction is an exact f32 op (an integer off a coordinate of the
+    same magnitude, both < 2^14), so the windowed kernel reads exactly
+    the taps the unwindowed one does (nearest: bit-identical;
+    interpolated: 1-ulp XLA-contraction differences between the two
+    programs).  ``stack`` may also be a tuple of (H, W) scenes, with
+    win0 (B, 2): see below.
     """
-    if win is not None:
-        stack, r0f, c0f = _window_slice(stack, win, win0, axis=1)
-
-    def per(scene, p):
+    def per(scene, p, r0=None, c0=None):
         cols = (p[0] + p[1] * sx + p[2] * sy) - 0.5
         rows = (p[3] + p[4] * sx + p[5] * sy) - 0.5
         oob = (rows < -0.5) | (rows > p[6] - 0.5) \
             | (cols < -0.5) | (cols > p[7] - 0.5)
         rows = jnp.where(oob, jnp.nan, rows)
-        if win is not None:
-            rows = rows - r0f
-            cols = cols - c0f
+        if r0 is not None:
+            rows = rows - r0
+            cols = cols - c0
         return _resample_native(scene, p[8], rows, cols, method)
 
-    out, ok = jax.vmap(per)(stack, params)
+    if isinstance(stack, (tuple, list)):
+        # the scenes as the scene cache holds them, one (H, W) array
+        # each, and win0 (B, 2), an origin a scene.  Each is sliced and
+        # resampled by itself, so no copy of a raster is made, and the
+        # taps are gathers from one flat array: XLA's TPU gather over a
+        # batch of windows took 8 ms a tap for 8 scenes where these take
+        # ~0.1 ms a scene (PERF.md, PR 27)
+        def one(k, scene):
+            if win is None:
+                return per(scene, params[k])
+            cut, r0, c0 = _window_slice(scene, win, win0[k], axis=0)
+            return per(cut, params[k], r0, c0)
+
+        made = [one(k, scene) for k, scene in enumerate(stack)]
+        out = jnp.stack([m[0] for m in made])
+        ok = jnp.stack([m[1] for m in made])
+    elif win is not None:
+        stack, r0f, c0f = _window_slice(stack, win, win0, axis=1)
+        out, ok = jax.vmap(lambda scene, p: per(scene, p, r0f, c0f))(
+            stack, params)
+    else:
+        out, ok = jax.vmap(per)(stack, params)
     prio = params[:, 9]
     ns_id = params[:, 10].astype(jnp.int32)
     score = jnp.where(ok, prio[:, None, None], -jnp.inf)

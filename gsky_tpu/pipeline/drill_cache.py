@@ -28,6 +28,8 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from ..device import DRILL_STACK_BYTES
+
 _stack_serial = itertools.count(1)
 
 
@@ -47,7 +49,7 @@ class DeviceStack:
 
 
 class DrillStackCache:
-    def __init__(self, max_bytes: int = 4 << 30,
+    def __init__(self, max_bytes: int = DRILL_STACK_BYTES,
                  max_item_bytes: int = 1 << 30,
                  max_negative: int = 4096,
                  max_background_loads: int = 2):

@@ -164,6 +164,35 @@ def _gather_window(params64: np.ndarray, cx: np.ndarray, cy: np.ndarray,
     return win, win0, (r_lo, r_hi, c_lo, c_hi)
 
 
+def _gather_windows(params64: np.ndarray, cx: np.ndarray, cy: np.ndarray,
+                    bucket_h: int, bucket_w: int):
+    """`_gather_window` for a kernel that slices each scene by itself:
+    (win, win0 (B, 2), None), one window size, as large as the largest
+    granule footprint, and one origin per granule, where the tile
+    touches that granule.  Neighbouring granules of one grid lie a
+    granule's pitch apart in each other's pixel coordinates, so one
+    origin for all would span a window over everything between them
+    (11,008 x 512 px where 512 x 512 do)."""
+    bounds = [None if p[10] < 0 else _granule_bounds(p, cx, cy)
+              for p in params64]
+    real = [b for b in bounds if b is not None]
+    if not real:
+        return None
+    rows = max(b[1] - b[0] for b in real)
+    cols = max(b[3] - b[2] for b in real)
+
+    def finish(r_lo, c_lo):
+        return finish_window(r_lo, r_lo + rows, c_lo, c_lo + cols,
+                             bucket_h, bucket_w)
+
+    made = finish(0, 0)         # the size alone decides whether it helps
+    if made is None:
+        return None
+    win0 = np.stack([np.zeros(2, np.int32) if b is None
+                     else finish(b[0], b[2])[1] for b in bounds])
+    return made[0], win0, None
+
+
 def finish_window(r_lo: int, r_hi: int, c_lo: int, c_hi: int,
                   bucket_h: int, bucket_w: int):
     """Bucket raw footprint bounds into (win, win0), or None when the
@@ -201,7 +230,6 @@ class WarpExecutor:
     # the oldest entries, not dump the whole working set (a clear causes
     # a recompute/re-upload storm exactly when traffic is heaviest)
     _GEO_CACHE_MAX = 256
-    _STACK_CACHE_MAX = 32
     # per-granule scalar strides get their own (much larger) map: one
     # tiny entry per granule geotransform must not flush the multi-MB
     # projection grids out of the 256-slot LRU above
@@ -209,7 +237,6 @@ class WarpExecutor:
 
     def __init__(self):
         self._geo_cache: OrderedDict = OrderedDict()
-        self._stack_cache: OrderedDict = OrderedDict()
         self._stride_cache: OrderedDict = OrderedDict()
         self._lock = threading.Lock()
         # dispatch counters by (path, shape bucket) — the /debug
@@ -836,49 +863,59 @@ class WarpExecutor:
                           cache=None):
         """Multi-band fused fast path (RGB styles): one dispatch from
         cached scenes to per-band uint8 planes
-        (`ops.warp.render_scenes_bands_ctrl`).  Returns a device uint8
-        (n_out, H, W) array or None (fallback)."""
+        (`ops.warp.render_scenes_bands_ctrl`).  The kernel takes the
+        scenes as the scene cache holds them and stacks only their
+        gather windows, so no copy of a raster is made or kept.
+        Returns a device uint8 (n_out, H, W) array or None (fallback)."""
         made = self._scene_inputs(granules, ns_ids, prios, dst_gt,
-                                  dst_crs, height, width, cache)
+                                  dst_crs, height, width, cache,
+                                  stacked=False)
         if made is None:
             return None
-        stack, _, params, step, _, ctrl_dev, win, win0, *_ = made
-        self._count("render_bands", (stack.shape, win))
+        devs, _, params, step, _, ctrl_dev, win, win0, *_ = made
+        self._count("render_bands", ((len(devs),) + devs[0].shape, win))
         self._note_win(win)
         sp = jnp.asarray(np.array([offset, scale, clip], np.float32))
         sel = jnp.asarray(np.asarray(out_sel, np.int32))
         return _prefetch(render_scenes_bands_ctrl(
-            stack, ctrl_dev, jnp.asarray(params), sp, sel,
+            devs, ctrl_dev, jnp.asarray(params), sp, sel,
             method, _bucket_pow2(n_ns), (height, width), step, auto,
             colour_scale, win=win, win0=_dev_win0(win0)))
 
-    def render_rgba_byte(self, granules, out_sel: Sequence[int],
+    def render_rgba_byte(self, granules, ns_ids: Sequence[int],
+                         prios: Sequence[float], out_sel: Sequence[int],
                          dst_gt: GeoTransform, dst_crs: CRS,
                          height: int, width: int, method: str = "near",
                          offset: float = 0.0, scale: float = 0.0,
                          clip: float = 0.0, colour_scale: int = 0,
                          auto: bool = True, cache=None):
-        """Channel-packed RGB fast path: when the request is one RGB
-        scene (one temporal granule per output band, all bands sharing
-        grid/dtype/nodata — the Sentinel-2 true-colour shape), the three
-        band scenes pack into a (sh, sw, 3) device array (cached) and
-        `ops.warp.render_rgba_ctrl` renders the PNG-ready (H, W, 4)
-        RGBA tile in one dispatch, computing warp indices once for all
+        """Channel-packed RGB fast path: when the request's granules
+        fall into sets of three, one per output band on one grid (the
+        Sentinel-2 true-colour shape: a granule's band rasters; several
+        such sets where the tile lies on granules' overlap),
+        `ops.warp.render_rgba_ctrl` takes the band scenes as the scene
+        cache holds them and renders the PNG-ready (H, W, 4) RGBA tile
+        in one dispatch, computing warp indices once a set for all
         three bands.  Returns a device uint8 (H, W, 4) or None (caller
         falls back to the per-band path)."""
-        if len(granules) != 3 or len(out_sel) != 3 \
-                or sorted(out_sel) != [0, 1, 2]:
+        if len(out_sel) != 3 or sorted(out_sel) != [0, 1, 2]:
             return None
-        g0 = granules[0]
-        if g0.geo_loc:
-            return None
-        for g in granules[1:]:
-            if g.geo_loc or g.srs != g0.srs \
-                    or g.geo_transform != g0.geo_transform:
+        # grid -> {namespace id: granule index}: every set one granule
+        # per band (two dates on one grid are the per-band kernel's)
+        sets: Dict[tuple, Dict[int, int]] = {}
+        for i, g in enumerate(granules):
+            if g.geo_loc or g.srs != granules[0].srs:
                 return None
+            members = sets.setdefault(tuple(g.geo_transform), {})
+            if ns_ids[i] in members:
+                return None
+            members[ns_ids[i]] = i
+        if not sets or any(sorted(m) != [0, 1, 2] for m in sets.values()):
+            return None
         from ..geo.crs import parse_crs
         from .scene_cache import default_scene_cache
         cache = cache or default_scene_cache
+        g0 = granules[0]
         try:
             src_crs = parse_crs(g0.srs) if g0.srs else None
         except ValueError:
@@ -886,19 +923,20 @@ class WarpExecutor:
         if src_crs is None:
             return None
         stride = self._granule_stride(g0, dst_gt, dst_crs, height, width)
-        # out_sel maps expression order -> ns index == granule index here
-        # (one granule per namespace); channel k comes from the granule
-        # whose ns id equals out_sel[k]
-        chans = []
         rgba_bbox = dst_gt.bbox(width, height)
-        for ns in out_sel:
-            s = cache.get(granules[ns], stride,
-                          dst_bbox=rgba_bbox, dst_crs=dst_crs)
-            if s is None:
+        # out_sel maps expression order -> ns id: channel k of a set
+        # comes from its granule whose ns id equals out_sel[k]
+        chans, chan_prios = [], []
+        for members in sets.values():
+            picked = [members[ns] for ns in out_sel]
+            got = [cache.get(granules[i], stride, dst_bbox=rgba_bbox,
+                             dst_crs=dst_crs) for i in picked]
+            if any(s is None for s in got):
                 return None
-            chans.append(s)
-        s0 = chans[0]
-        for s in chans[1:]:
+            chans.append(got)
+            chan_prios.append([prios[i] for i in picked])
+        s0 = chans[0][0]
+        for s in (s for got in chans for s in got):
             if s.bucket != s0.bucket or s.dtype != s0.dtype \
                     or s.crs != s0.crs \
                     or not (np.isnan(s.nodata) and np.isnan(s0.nodata)
@@ -915,38 +953,32 @@ class WarpExecutor:
             ctrl_dev = jnp.asarray(
                 np.stack([sx - ox, sy - oy]).astype(np.float32))
             self._geo_cache_put(dkey, ctrl_dev)
-        skey = ("rgb",) + tuple(s.serial for s in chans)
-        with self._lock:
-            packed = self._stack_cache.get(skey)
-            if packed is not None:
-                self._stack_cache.move_to_end(skey)
-        if packed is None:
-            packed = jnp.stack([s.dev for s in chans], axis=-1)
-            with self._lock:
-                self._stack_cache[skey] = packed
-                self._stack_cache.move_to_end(skey)
-                while len(self._stack_cache) > self._STACK_CACHE_MAX:
-                    self._stack_cache.popitem(last=False)
-        inv = _inv_gt_params(s0.gt, ox, oy)
-        param = np.array(inv + (s0.height, s0.width, s0.nodata, 0.0, 0.0),
-                         np.float32)
+        # G is a power of two (bounded jit variants): the filling
+        # repeats the first set with a priority that never wins
+        G = _bucket_pow2(len(chans))
+        bands = tuple(tuple(s.dev for s in got) for got in chans)
+        bands += (bands[0],) * (G - len(chans))
+        params = np.zeros((G, 11), np.float64)
+        params[:, 10] = -1.0
+        for k, got in enumerate(chans):
+            params[k, :6] = _inv_gt_params(got[0].gt, ox, oy)
+            params[k, 6:9] = (s0.height, s0.width, s0.nodata)
+            params[k, 10] = 0.0
+        prio = np.full((G, 3), -np.inf, np.float32)
+        prio[:len(chans)] = chan_prios
         win = win0 = None
         if _window_mode():
-            # window bound from the SAME param row the kernel consumes
-            # (prio/ns slots are 0, so _gather_window reads it as one
-            # non-padding granule)
-            made_w = _gather_window(param.astype(np.float64)[None, :],
-                                    sx - ox, sy - oy,
-                                    int(packed.shape[0]),
-                                    int(packed.shape[1]))
+            # window bounds from the SAME param rows the kernel consumes
+            made_w = _gather_windows(params, sx - ox, sy - oy, *s0.bucket)
             if made_w is not None:
                 win, win0, _ = made_w
         from ..ops.warp import render_rgba_ctrl
-        self._count("render_rgba", (packed.shape, win))
+        self._count("render_rgba", ((G,) + s0.bucket + (3,), win))
         self._note_win(win)
         sp = np.array([offset, scale, clip], np.float32)
         return _prefetch(render_rgba_ctrl(
-            packed, ctrl_dev, jnp.asarray(param), jnp.asarray(sp),
+            bands, ctrl_dev, jnp.asarray(params.astype(np.float32)),
+            jnp.asarray(prio), jnp.asarray(sp),
             method, (height, width), step, auto, colour_scale,
             win=win, win0=_dev_win0(win0)))
 
@@ -1056,11 +1088,11 @@ class WarpExecutor:
         return pool, tables, params16, real_pages
 
     def _scene_inputs(self, granules, ns_ids, prios, dst_gt, dst_crs,
-                      height, width, cache=None):
+                      height, width, cache=None, stacked=True):
         """Single-group scene inputs; None when the granule set is not
         uniform (the byte fast paths then fall back)."""
         groups = self._scene_groups(granules, ns_ids, prios, dst_gt,
-                                    dst_crs, height, width, cache)
+                                    dst_crs, height, width, cache, stacked)
         if groups is None or len(groups) != 1:
             return None
         return groups[0]
@@ -1123,13 +1155,22 @@ class WarpExecutor:
         return out
 
     def _scene_groups(self, granules, ns_ids, prios, dst_gt, dst_crs,
-                      height, width, cache=None):
+                      height, width, cache=None, stacked=True):
         """Device inputs for the fused scene kernels, grouped by
         (source CRS, bucket shape, dtype) — curvilinear granules group
         by their geolocation arrays instead: each group gets its own
         (stack, ctrl, params, step); multi-group sets (granules spanning
         UTM zones, or mixing regular and curvilinear grids) combine via
-        the scored kernels.  None when any scene is uncacheable."""
+        the scored kernels.  None when any scene is uncacheable.
+
+        The group's first member is one (B, bh, bw) array, a copy of its
+        scenes that the scene cache keeps and charges to its budget
+        (`SceneCache.stack`), or with ``stacked=False`` the tuple of the
+        B scene arrays themselves, for a kernel that stacks only their
+        gather windows (its `win0` is then (B, 2), an origin a scene:
+        `_gather_windows`).  B is a power of two (bounded jit variants);
+        the filling repeats the first scene, which costs the tuple
+        nothing."""
         from .scene_cache import default_scene_cache
         cache = cache or default_scene_cache
         scenes = []
@@ -1200,25 +1241,14 @@ class WarpExecutor:
                 params[k, 10] = ns_ids[i]
 
             skey = tuple(s.serial for s in gs) + (B,)
-            with self._lock:
-                stack = self._stack_cache.get(skey)
-                if stack is not None:
-                    self._stack_cache.move_to_end(skey)
-            if stack is None:
-                devs = [s.dev for s in gs]
-                devs += [devs[0]] * (B - len(devs))
-                stack = jnp.stack(devs)
-                with self._lock:
-                    self._stack_cache[skey] = stack
-                    self._stack_cache.move_to_end(skey)
-                    while len(self._stack_cache) > self._STACK_CACHE_MAX:
-                        self._stack_cache.popitem(last=False)
+            devs = tuple(s.dev for s in gs) + (s0.dev,) * (B - len(gs))
+            stack = cache.stack(skey, lambda devs=devs: jnp.stack(devs)) \
+                if stacked else devs
             win = win0 = win_raw = None
             if _window_mode():
-                made_w = _gather_window(
+                made_w = (_gather_window if stacked else _gather_windows)(
                     params, np.asarray(ctrl[0], np.float64),
-                    np.asarray(ctrl[1], np.float64),
-                    int(stack.shape[1]), int(stack.shape[2]))
+                    np.asarray(ctrl[1], np.float64), *s0.bucket)
                 if made_w is not None:
                     win, win0, win_raw = made_w
             # trailing members (scenes + f64 params) feed the paged

@@ -10,16 +10,21 @@ gathered tap — and every subsequent tile request warps from the cached
 device array (`ops.warp.warp_scenes_batch`) with only a ~2 KB
 control-grid upload.
 
-Eviction is LRU by device bytes.  Scenes above ``max_scene_px`` are not
-cached (a one-off window read is cheaper than shipping the whole raster).
+One byte budget (`device.residency_budget`, from the device's memory)
+covers the scenes and the executor's stacks of them (`stack`): a stack
+is a copy of scenes that are resident, so stacks go first, least
+recently used first, then scenes.  A scene too large for the budget is
+not cached (a one-off window read is cheaper than shipping the raster).
 """
 
 from __future__ import annotations
 
 import itertools
+import logging
 import threading
+from collections import OrderedDict
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -31,6 +36,7 @@ from .types import Granule
 
 
 _scene_serial = itertools.count(1)
+_log = logging.getLogger("gsky.scene_cache")
 
 
 @dataclass
@@ -74,18 +80,29 @@ def _put_scene(data, serial: int):
     return jax.device_put(data, dev)
 
 
+def _nbytes(dev) -> int:
+    """The committed device allocation: bucket dims x itemsize."""
+    return int(np.prod(dev.shape)) * dev.dtype.itemsize
+
+
 class SceneCache:
-    def __init__(self, max_bytes: int = 2 << 30,
-                 max_scene_px: int = 64 << 20):
+    def __init__(self, max_bytes: Optional[int] = None):
+        """``max_bytes``: the budget scenes and stacks share; None takes
+        the device's (`device.residency_budget`) at the first load."""
         self._lock = threading.Lock()
         self._scenes: Dict[tuple, DeviceScene] = {}
         self._order: List[tuple] = []
         self._bytes = 0
+        self._stacks: OrderedDict = OrderedDict()   # key -> (array, bytes)
+        self._stack_bytes = 0
         self._max_bytes = max_bytes
-        self._max_scene_px = max_scene_px
         self._inflight: Dict[tuple, threading.Event] = {}
         self.hits = 0
         self.misses = 0
+        self.upload_bytes = 0
+        self.evictions = 0
+        self.stack_evictions = 0
+        self._told: set = set()         # (path, why) already warned of
         # ranged-window routing: decline counts per key (promote-to-
         # residency once a "cold" scene turns out to be hot), plus the
         # running total of requests served through the window path
@@ -95,6 +112,69 @@ class SceneCache:
 
     def _key(self, g: Granule) -> tuple:
         return (g.path, g.band, g.var_name, g.time_index)
+
+    @property
+    def max_bytes(self) -> int:
+        if self._max_bytes is None:
+            from ..device import residency_budget
+            self._max_bytes = residency_budget()["budget"]
+        return self._max_bytes
+
+    @property
+    def max_scene_px(self) -> int:
+        """A scene is cacheable if eight of its size fit the budget: its
+        sibling bands and the neighbours a tile on its edge touches stay
+        resident beside it."""
+        return self.max_bytes // (8 * 4)
+
+    def stats(self) -> Dict:
+        """The /debug `cache.scene` row: cumulative counters, and every
+        resident byte of scenes and stacks beside the budget."""
+        with self._lock:
+            return {"hits": self.hits, "misses": self.misses,
+                    "upload_bytes": self.upload_bytes,
+                    "evictions": self.evictions,
+                    "resident_bytes": self._bytes,
+                    "stacks": len(self._stacks),
+                    "stack_bytes": self._stack_bytes,
+                    "stack_evictions": self.stack_evictions,
+                    "budget_bytes": self.max_bytes}
+
+    def _make_room(self) -> None:  # gskylint: holds-lock
+        """Evict until scenes + stacks fit the budget: stacks first (a
+        copy of resident scenes is rebuilt by one device copy), then
+        scenes, each least recently used first; the newest scene stays."""
+        while self._bytes + self._stack_bytes > self.max_bytes:
+            if self._stacks:
+                _, (_, n) = self._stacks.popitem(last=False)
+                self._stack_bytes -= n
+                self.stack_evictions += 1
+            elif len(self._order) > 1:
+                old = self._scenes.pop(self._order.pop(0))
+                self._bytes -= _nbytes(old.dev)
+                self.evictions += 1
+            else:
+                break
+
+    def stack(self, key: tuple, make: Callable[[], jax.Array]) -> jax.Array:
+        """The executor's stacked copy of resident scenes under ``key``
+        (their serials, so a reloaded scene never meets a stale stack),
+        built by ``make()`` on a miss and charged its bytes to the
+        budget.  One that does not fit beside the resident scenes, once
+        every older stack has gone, serves its request and is not kept."""
+        with self._lock:
+            hit = self._stacks.get(key)
+            if hit is not None:
+                self._stacks.move_to_end(key)
+                return hit[0]
+        arr = make()
+        n = _nbytes(arr)
+        with self._lock:
+            if key not in self._stacks and self._bytes + n <= self.max_bytes:
+                self._stacks[key] = (arr, n)
+                self._stack_bytes += n
+                self._make_room()
+        return arr
 
     def _pick_level(self, g: Granule, stride: float) -> int:
         """Decimation level to cache for a request stepping ``stride``
@@ -170,14 +250,15 @@ class SceneCache:
     def get(self, g: Granule, stride: float = 1.0,
             dst_bbox=None, dst_crs=None) -> Optional[DeviceScene]:
         """Cached scene for a granule, decoding + uploading on first use.
-        Returns None when the scene is uncacheable (too big / unreadable).
+        Returns None when the scene is uncacheable (over budget /
+        unreadable / no CRS; `_uncacheable` logs which).
         Concurrent requests for the same scene decode once (per-key
         latch), not once per tile.
 
         ``stride`` (source px per dst px) selects the cached resolution:
         zoomed-out requests get the overview/decimated level — which also
-        makes scenes above ``max_scene_px`` cacheable once the level
-        fits (`worker/gdalprocess/warp.go:156-198`).
+        makes scenes over the budget cacheable once the level fits
+        (`worker/gdalprocess/warp.go:156-198`).
 
         ``dst_bbox``/``dst_crs`` (optional) describe the request
         footprint; with ingest on, a non-resident scene barely touched
@@ -208,17 +289,13 @@ class SceneCache:
         try:
             scene = self._load(g, level)
             if scene is not None:
-                nbytes = int(np.prod(scene.bucket)) * scene.dtype.itemsize
+                nbytes = _nbytes(scene.dev)
                 with self._lock:
                     self._scenes[key] = scene
                     self._order.append(key)
                     self._bytes += nbytes
-                    while self._bytes > self._max_bytes and \
-                            len(self._order) > 1:
-                        old = self._order.pop(0)
-                        ev_s = self._scenes.pop(old)
-                        self._bytes -= int(np.prod(ev_s.bucket)) \
-                            * ev_s.dtype.itemsize
+                    self.upload_bytes += nbytes
+                    self._make_room()
         finally:
             with self._lock:
                 self._inflight.pop(key).set()
@@ -232,6 +309,8 @@ class SceneCache:
             self._scenes.clear()
             self._order.clear()
             self._bytes = 0
+            self._stacks.clear()
+            self._stack_bytes = 0
 
     def _staging_read(self, h, band: int, W: int, H: int, ovr,
                       nodata):
@@ -269,9 +348,30 @@ class SceneCache:
         except Exception:
             return None, None
 
+    def _uncacheable(self, g: Granule, why: str) -> None:
+        """Say why a scene is served by window decode instead (over
+        budget, unreadable, no CRS): once per file and reason, since a
+        deployment that falls there does so on every tile."""
+        with self._lock:
+            new = (g.path, why) not in self._told
+            if new and len(self._told) < 4096:
+                self._told.add((g.path, why))
+        if new:
+            _log.warning("scene uncacheable, window-path fallback: %s (%s)",
+                         g.path, why)
+        return None
+
+    def _over_budget(self, g: Granule, H: int, W: int) -> None:
+        return self._uncacheable(
+            g, f"over budget: {H} x {W} px, and a scene may hold "
+               f"{self.max_scene_px} px of a {self.max_bytes}-byte budget")
+
     def _load(self, g: Granule, level: int = 1) -> Optional[DeviceScene]:
         from .decode import _handles
         gt = GeoTransform.from_gdal(g.geo_transform)
+        crs = parse_crs(g.srs) if g.srs else None
+        if crs is None:
+            return self._uncacheable(g, "no CRS")
         sbuf = spool = None
         try:
             from ..resilience import faults
@@ -280,12 +380,13 @@ class SceneCache:
             if g.is_netcdf:
                 v = h.variables.get(g.var_name)
                 if v is None:
-                    return None
+                    return self._uncacheable(
+                        g, f"unreadable: no variable {g.var_name!r}")
                 H, W = v.shape[-2], v.shape[-1]
                 st = level if level > 1 and H // level >= 2 \
                     and W // level >= 2 else 1
-                if (H // st) * (W // st) > self._max_scene_px:
-                    return None
+                if (H // st) * (W // st) > self.max_scene_px:
+                    return self._over_budget(g, H // st, W // st)
                 Ho, Wo = H // st, W // st
                 data = h.read_slice(g.var_name, g.time_index,
                                     (0, 0, Wo * st, Ho * st), step=st)
@@ -300,8 +401,8 @@ class SceneCache:
                 if ovr is not None:
                     gt = gt.scaled(fx, fy)
                     W, H = ovr.width, ovr.height
-                if H * W > self._max_scene_px:
-                    return None
+                if H * W > self.max_scene_px:
+                    return self._over_budget(g, H, W)
                 nodata = g.nodata if g.nodata is not None else h.nodata
                 sbuf, spool = self._staging_read(h, g.band, W, H, ovr,
                                                  nodata)
@@ -320,16 +421,8 @@ class SceneCache:
             # "uncacheable" must stay a degradation, never a crash — but
             # it must also be VISIBLE: a signature drift in a handle's
             # read() once hid here as a silent slow path for the format
-            import logging
-            logging.getLogger("gsky.scene_cache").warning(
-                "scene uncacheable, window-path fallback: %s (%s: %s)",
-                g.path, type(e).__name__, e)
-            return None
-        crs = parse_crs(g.srs) if g.srs else None
-        if crs is None:
-            if sbuf is not None:
-                spool.release(sbuf)
-            return None
+            return self._uncacheable(
+                g, f"unreadable: {type(e).__name__}: {e}")
         nd = float(nodata) if nodata is not None else float("nan")
         from ..ingest import stats as _istats
         if sbuf is not None:

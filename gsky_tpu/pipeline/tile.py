@@ -432,14 +432,20 @@ class TilePipeline:
             out_sel.append(ns_index[cands[0]])
         return granules, ns_index, out_sel
 
-    def _bands_dispatch(self, req: GeoTileRequest, granules, ns_index,
-                        out_sel, offset, scale, clip, colour_scale,
-                        auto):
-        ns_ids = [ns_index[g.namespace] for g in granules]
+    @staticmethod
+    def _ns_prios(granules, ns_index):
+        """(namespace id, mosaic priority) of every granule: newest
+        first, among equal timestamps the later arrival."""
         order = M.priority_order([g.timestamp for g in granules])
         prio = [0.0] * len(granules)
         for rank, i in enumerate(order):
             prio[i] = float(len(granules) - rank)
+        return [ns_index[g.namespace] for g in granules], prio
+
+    def _bands_dispatch(self, req: GeoTileRequest, granules, ns_index,
+                        out_sel, offset, scale, clip, colour_scale,
+                        auto):
+        ns_ids, prio = self._ns_prios(granules, ns_index)
         return self.executor.render_bands_byte(
             granules, ns_ids, prio, req.dst_gt(), req.crs,
             req.height, req.width, len(ns_index), out_sel, req.resample,
@@ -467,15 +473,16 @@ class TilePipeline:
     def _rgba_try(self, req: GeoTileRequest, granules, ns_index, out_sel,
                   offset, scale, clip, colour_scale, auto):
         """The channel-packed RGBA dispatch over an ALREADY-indexed
-        granule set, or None when the set doesn't fit the single-scene
-        true-colour shape."""
-        if len(granules) != 3 or len(ns_index) != 3 \
+        granule set, or None when the set doesn't fit the true-colour
+        shape (sets of three granules, one per band on one grid)."""
+        if len(ns_index) != 3 or len(granules) % 3 \
                 or sorted(out_sel) != [0, 1, 2]:
             return None
+        ns_ids, prio = self._ns_prios(granules, ns_index)
         return self.executor.render_rgba_byte(
-            granules, out_sel, req.dst_gt(), req.crs, req.height,
-            req.width, req.resample, offset, scale, clip, colour_scale,
-            auto)
+            granules, ns_ids, prio, out_sel, req.dst_gt(), req.crs,
+            req.height, req.width, req.resample, offset, scale, clip,
+            colour_scale, auto)
 
     def render_rgba_byte(self, req: GeoTileRequest,
                          offset: float = 0.0, scale: float = 0.0,

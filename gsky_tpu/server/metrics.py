@@ -45,9 +45,7 @@ def _resolve_cache_handles():
     handles = []
     try:
         from ..pipeline import scene_cache as m
-        handles.append(("scene", lambda m=m: {
-            "hits": m.default_scene_cache.hits,
-            "misses": m.default_scene_cache.misses}))
+        handles.append(("scene", lambda m=m: m.default_scene_cache.stats()))
     except Exception:  # tier absent in this build - skip its counters
         pass
     try:
@@ -191,6 +189,9 @@ class MetricsLogger:
         self._export: Dict = {}
         # cumulative staged-tile (pipeline/tile_stages.py) aggregates
         self._tiles: Dict = {}
+        # which program served each three-band GetMap (record_rgb_route)
+        self._rgb_routes: Dict[str, int] = {"rgba": 0, "planes": 0,
+                                            "fallback": 0, "empty": 0}
         # cumulative WPS Execute stage aggregates, folded from spans
         self._drills: Dict = {}
 
@@ -293,6 +294,15 @@ class MetricsLogger:
         except Exception:   # observability must never fail a request
             pass
 
+    def record_rgb_route(self, route: str) -> None:
+        """Count one three-band GetMap by the program that served it:
+        `rgba` (one granule, `render_rgba_ctrl`), `planes` (several,
+        `render_scenes_bands_ctrl`), `fallback` (neither qualified: the
+        modular window-decode render) or `empty` (the index found no
+        granule under the tile: no program ran).  /debug `rgb_routes`."""
+        with self._summary_lock:
+            self._rgb_routes[route] += 1
+
     # /debug drill_stages key <- the span it sums (docs/OBSERVABILITY.md);
     # the stages of one Execute run one after another, so wall_s minus
     # their sum is what no span covers yet
@@ -365,6 +375,7 @@ class MetricsLogger:
                     out["tile_stages"]["encode_pool"] = encode_pool_stats()
                 except Exception:  # stage gates absent when the tile pipeline is off
                     pass
+            out["rgb_routes"] = dict(self._rgb_routes)
         out["cache"] = _cache_stats()
         try:
             from ..resilience import registry as _resilience
@@ -383,6 +394,10 @@ class MetricsLogger:
             from .. import device_guard
             dev = device_guard.default_supervisor().stats()
             dev["journal"] = device_guard.journal.stats()
+            # the byte budget of scenes + stacks and what it was derived
+            # from (cache.scene holds what is charged against it)
+            from ..device import residency_budget
+            dev["residency"] = residency_budget()
             out["device"] = dev
         except Exception:   # observability must never fail a request
             pass

@@ -413,7 +413,7 @@ class OWSServer:
             from ..pipeline.scene_cache import default_scene_cache as sc
             doc["executor"] = {
                 "geo_cache": len(ex._geo_cache),
-                "stack_cache": len(ex._stack_cache),
+                "stack_cache": len(sc._stacks),
                 "stride_cache": len(ex._stride_cache),
                 "dispatches": dict(ex.bucket_stats),
                 # gather-window engagement (GSKY_WARP_WINDOW): groups
@@ -1111,6 +1111,8 @@ class OWSServer:
                 if made is not None:
                     spans = made_spans
                     kind, arr = made
+                    if n_exprs == 3:
+                        self.metrics.record_rgb_route(kind)
                     rgba = None
                     if kind == "rgba":
                         rgba = arr              # (H, W, 4)
@@ -1160,6 +1162,8 @@ class OWSServer:
                         asyncio.to_thread(self._render_rgb, pipe, req,
                                           style, auto, stats),
                         timeout=dl.remaining())
+                    if sb is not None:
+                        self.metrics.record_rgb_route(sb[0])
                 else:
                     sb = await asyncio.wait_for(
                         asyncio.to_thread(pipe.render_bands_byte, req,
@@ -1211,6 +1215,9 @@ class OWSServer:
                 collector.info["indexer"]["num_granules"] = \
                     res.granule_count
                 collector.info["indexer"]["num_files"] = res.file_count
+                if n_exprs == 3:
+                    self.metrics.record_rgb_route(
+                        "fallback" if res.granule_count else "empty")
 
                 bands = [res.data[n] for n in res.namespaces
                          if n in res.data]

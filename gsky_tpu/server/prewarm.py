@@ -469,21 +469,21 @@ def prewarm(configs: Dict,
                         n_pad, (hw, hw), step, win=None, win0_dev=None)
             else:
                 # one granule per namespace: the executor pads the
-                # stack batch to pow2 (`_scene_groups`), so an RGB set
-                # dispatches at B=4 with one duplicated padding row
+                # scene batch to pow2 (`_scene_groups`), so an RGB set
+                # dispatches B=4 scene arrays, the first one twice
                 n_pad = _bucket_pow2(n_exprs)
                 B = _bucket_pow2(n_exprs)
                 sel = jnp.asarray(np.arange(n_exprs, dtype=np.int32))
-                stack = jnp.full((B, bh, bw), jnp.nan, jnp.float32)
+                scene = jnp.full((bh, bw), jnp.nan, jnp.float32)
                 params = jnp.asarray(_params(n_exprs, bh, bw, pad=B,
                                              per_ns=True))
-                run(render_scenes_bands_ctrl, stack, ctrl, params, sp,
-                    sel, method, n_pad, (hw, hw), step, auto,
+                run(render_scenes_bands_ctrl, (scene,) * B, ctrl, params,
+                    sp, sel, method, n_pad, (hw, hw), step, auto,
                     colour_scale, win=None, win0=None)
                 if n_exprs == 3:
-                    packed = jnp.full((bh, bw, 3), jnp.nan, jnp.float32)
-                    run(render_rgba_ctrl, packed, ctrl,
-                        jnp.asarray(_params(1, bh, bw)[0]), sp, method,
+                    run(render_rgba_ctrl, ((scene,) * 3,), ctrl,
+                        jnp.asarray(_params(1, bh, bw)),
+                        jnp.ones((1, 3), jnp.float32), sp, method,
                         (hw, hw), step, auto, colour_scale,
                         win=None, win0=None)
 

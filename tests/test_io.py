@@ -616,7 +616,8 @@ class TestOverviews:
         assert cache.get(g, stride=1.0).serial == full.serial
 
     def test_scene_cache_big_scene_cacheable_zoomed_out(self, tmp_path):
-        """Scenes over max_scene_px become cacheable at a coarse level."""
+        """A scene over the budget's share for one scene becomes
+        cacheable at a coarse level."""
         from gsky_tpu.pipeline.scene_cache import SceneCache
         from gsky_tpu.pipeline.types import Granule
 
@@ -626,7 +627,8 @@ class TestOverviews:
                     base_namespace="b1", band=1, time_index=None,
                     timestamp=0.0, geo_transform=list(gt.to_gdal()),
                     srs="EPSG:32755", nodata=-999.0)
-        cache = SceneCache(max_scene_px=300 * 300)
+        cache = SceneCache(max_bytes=8 * 300 * 300 * 4)
+        assert cache.max_scene_px == 300 * 300
         assert cache.get(g, stride=1.0) is None      # 512^2 too big
         ovr = cache.get(g, stride=4.0)               # 128^2 fits
         assert ovr is not None and ovr.width == 128

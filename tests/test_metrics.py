@@ -229,6 +229,7 @@ class TestCacheHandles:
         monkeypatch.setattr(M, "_CACHE_HANDLES", None)
         M.cache_stats()                      # resolve against the real module
         monkeypatch.setattr(sc, "default_scene_cache",
-                            types.SimpleNamespace(hits=41, misses=1))
+                            types.SimpleNamespace(
+                                stats=lambda: {"hits": 41, "misses": 1}))
         out = M.cache_stats()
         assert out["scene"] == {"hits": 41, "misses": 1}

@@ -117,17 +117,19 @@ def _():
 def _():
     from gsky_tpu.ops.warp import render_rgba_ctrl
     S = 512
-    scene = rng.uniform(200, 3000, (S, S, 3)).astype(np.int16)
+    bands = rng.uniform(200, 3000, (3, S, S)).astype(np.int16)
     _, ctrl, _ = _render_inputs()
     param = np.array([0, 1, 0, 0, 0, 1, S, S, 230.0, 0, 0], np.float32)
     sp = np.zeros(3, np.float32)
     kw = dict(method="bilinear", out_hw=(256, 256), step=16, auto=True,
               colour_scale=0)
     out_d = np.asarray(render_rgba_ctrl(
-        jnp.asarray(scene), jnp.asarray(ctrl), jnp.asarray(param),
+        (tuple(jnp.asarray(b) for b in bands),), jnp.asarray(ctrl),
+        jnp.asarray(param[None]), jnp.ones((1, 3), jnp.float32),
         jnp.asarray(sp), **kw))
-    out_c = on_cpu(lambda *a: render_rgba_ctrl(*a, **kw), scene, ctrl,
-                   param, sp)
+    out_c = on_cpu(lambda b, *a: render_rgba_ctrl((tuple(b),), *a, **kw),
+                   bands, ctrl, param[None], np.ones((1, 3), np.float32),
+                   sp)
     assert out_d.shape == (256, 256, 4)
     mism = np.mean(out_d != out_c)
     assert mism < 0.005, f"byte mismatch {mism:.2%}"
@@ -160,9 +162,9 @@ def _():
         (256, 256), 16, True, 0))
     param1 = np.array([0, 1, 0, 0, 0, 1, S, S, nodata, 0, 0], np.float32)
     packed = np.asarray(render_rgba_ctrl(
-        jnp.asarray(np.moveaxis(planes, 0, -1)), jnp.asarray(ctrl),
-        jnp.asarray(param1), jnp.asarray(sp), "near", (256, 256), 16,
-        True, 0))
+        (tuple(jnp.asarray(b) for b in planes),), jnp.asarray(ctrl),
+        jnp.asarray(param1[None]), jnp.ones((1, 3), jnp.float32),
+        jnp.asarray(sp), "near", (256, 256), 16, True, 0))
     for i in range(3):
         mism = np.mean(packed[..., i] != pl[i])
         assert mism < 0.001, f"band {i}: {mism:.2%}"
@@ -208,7 +210,8 @@ def _():
     from gsky_tpu.ops.warp import render_rgba_ctrl
     from gsky_tpu.pipeline.executor import _gather_window
     S = 1024
-    scene = rng.uniform(200, 3000, (S, S, 3)).astype(np.int16)
+    scene = (tuple(jnp.asarray(b) for b in
+                   rng.uniform(200, 3000, (3, S, S)).astype(np.int16)),)
     _, ctrl, _ = _render_inputs()
     param = np.array([0, 1, 0, 0, 0, 1, S, S, 230.0, 0, 0], np.float32)
     sp = np.zeros(3, np.float32)
@@ -219,12 +222,13 @@ def _():
     win, win0, _ = made
     kw = dict(method="bilinear", out_hw=(256, 256), step=16, auto=True,
               colour_scale=0)
+    prio = jnp.ones((1, 3), jnp.float32)
     full = np.asarray(render_rgba_ctrl(
-        jnp.asarray(scene), jnp.asarray(ctrl), jnp.asarray(param),
+        scene, jnp.asarray(ctrl), jnp.asarray(param[None]), prio,
         jnp.asarray(sp), **kw))
     wind = np.asarray(render_rgba_ctrl(
-        jnp.asarray(scene), jnp.asarray(ctrl), jnp.asarray(param),
-        jnp.asarray(sp), **kw, win=win, win0=jnp.asarray(win0)))
+        scene, jnp.asarray(ctrl), jnp.asarray(param[None]), prio,
+        jnp.asarray(sp), **kw, win=win, win0=jnp.asarray(win0[None])))
     diff = np.abs(full.astype(np.int16) - wind.astype(np.int16))
     assert diff.max() <= 1, f"byte delta {diff.max()}"
     mism = np.mean(diff != 0)

@@ -644,12 +644,16 @@ def _run(argv=None):
         "steady_state_rss_growth_mb": round(growth, 1),
         "caches": {k: exec_caches.get(k) for k in
                    ("geo_cache", "stack_cache", "stride_cache")},
-        "scene_cache_bytes": dbg.get("scene_cache_bytes"),
+        "scene_cache": dbg.get("cache", {}).get("scene"),
     }
     print(json.dumps(out))
+    sc = out["scene_cache"] or {}
     ok = (n_bad == 0 and growth <= args.max_rss_growth_mb
           and exec_caches.get("geo_cache", 0) <= 256
-          and exec_caches.get("stack_cache", 0) <= 32)
+          # scenes and the executor's stacks of them share one byte
+          # budget (pipeline/scene_cache.py)
+          and sc.get("resident_bytes", 0) + sc.get("stack_bytes", 0)
+          <= sc.get("budget_bytes", 0))
     print("SOAK PASSED" if ok else "SOAK FAILED", flush=True)
     return 0 if ok else 1
 
