@@ -57,6 +57,13 @@ def extent(p):
     return ("EPSG:4326", lon, lat - h * p["res"], lon + w * p["res"], lat)
 
 
+def nodata_below(p):
+    """(rows, columns) of the north-west block that holds no data, in
+    every timestep of every variable."""
+    h, w = p["hw"]
+    return int(h * p["nodata_corner"]), int(w * p["nodata_corner"])
+
+
 class Field:
     """The seeded parts of one variable; `window(ts, r0, r1, c0, c1)`
     gives timesteps ts of rows r0..r1-1, columns c0..c1-1."""
@@ -85,7 +92,7 @@ class Field:
         self.noise = rng.uniform(-0.03, 0.03, (NOISE_FIELDS, h, w)) \
             .astype(np.float32)
         self.nodata = np.float32(p["nodata"])
-        self.edge = (int(h * p["nodata_corner"]), int(w * p["nodata_corner"]))
+        self.edge = nodata_below(p)
 
     def window(self, ts, r0, r1, c0, c1, out=None, tmp=None):
         """`out` and `tmp`: float32 work buffers of at least this

@@ -18,9 +18,15 @@ touch every program the window can need, sent until the stacks are
 resident.  Nothing of a drill is cached, so the window's requests need
 no twins.
 
-The check's drills are rectangles whose edges run through pixel
-centres, so that the all-touched burn is unambiguous: exactly rows
-r0..r1 and columns c0..c1.
+The check holds every drill of the window to what its polygon's
+footprint holds on this archive (`reference.footprint_holds_data`
+against the archive's nodata block): every timestep a row, every band a
+field, finite where the footprint holds a valid pixel and empty where
+it holds none; within a pixel of the block's edge either passes and the
+run counts it.  The check's own drills are rectangles whose edges run
+through pixel centres, so that the all-touched burn is unambiguous
+(exactly rows r0..r1 and columns c0..c1), and their values are compared
+with the reference's means.
 """
 
 import json
@@ -96,14 +102,15 @@ class Generator:
         cx = rng.uniform(reach_x + 1, w - reach_x - 1)
         cy = rng.uniform(reach_y + 1, h - reach_y - 1)
         lon0, lat0 = self.p["origin"]
-        ring = [(lon0 + (cx + x) * res, lat0 - (cy + y) * res)
-                for x, y in zip(px, py)]
+        cols, rows = cx + px, cy + py
+        ring = [(lon0 + c * res, lat0 - r * res) for c, r in zip(cols, rows)]
         ring.append(ring[0])
         # the window the drill has to read: rows x columns x timesteps x
         # bands (roofline.py's bytes)
         wh = int(np.ceil(py.max() - py.min())) + 1
         ww = int(np.ceil(px.max() - px.min())) + 1
-        return self._req(ring, window_px=(wh, ww), **meta)
+        return self._req(ring, window_px=(wh, ww), corners_px=(cols, rows),
+                         **meta)
 
     def _flat(self, rng):
         while True:
@@ -138,26 +145,53 @@ class Generator:
             out.append(self._req(ring, rect=(r0, r1, c0, c1)))
         return out
 
-    def _shape(self, body):
-        """What every drill of the window is held to: one finite value
-        per timestep and band."""
-        rows = parse_rows(body)
+    def _held(self, body, holds_data):
+        """(state, fault) of one answered drill against what its
+        footprint holds (True, False, or None where the reference does
+        not decide): every timestep a row and every band a field, all
+        finite over a valid pixel, all empty over none.  The nodata block
+        is the same in every timestep, so one answer never mixes the
+        two."""
+        try:
+            rows = parse_rows(body)
+        except ValueError as e:
+            return "malformed", f"a field that is no number ({e})"
         bands = len(self.p["variables"])
         if len(rows) != self.p["steps"]:
-            return f"{len(rows)} rows, want {self.p['steps']}"
-        if any(len(v) != bands or not np.all(np.isfinite(v))
-               for v in rows.values()):
-            return f"a row without {bands} finite values"
-        return None
+            return "malformed", f"{len(rows)} rows, want {self.p['steps']}"
+        if any(len(v) != bands for v in rows.values()):
+            return "malformed", f"a row without {bands} fields"
+        finite = np.isfinite(np.array(list(rows.values())))
+        if finite.any() and not finite.all():
+            return "malformed", (f"{int((~finite).sum())} empty fields among "
+                                 f"{finite.size}")
+        got = bool(finite.all())
+        if holds_data is None:
+            return "undecided", None
+        if got != holds_data:
+            return "malformed", (
+                "empty rows over a footprint that holds data" if holds_data
+                else "finite rows over a footprint that holds no data")
+        return ("finite" if got else "empty_on_nodata"), None
 
     def verify(self, results, fetch):
-        """(problems, records): the window's answers by their shape, the
-        seeded rectangles against the reference's masked means."""
+        """(problems, records): every answer of the window by its rows,
+        the seeded rectangles also by their values against the
+        reference's masked means."""
         problems, records = [], []
-        for r in results:
-            bad = self._shape(r.body) if r.ok else None
-            if bad:
-                problems.append(f"window drill {r.req.meta}: {bad}")
+        block = self.archive.nodata_below(self.p)
+        for i, r in enumerate(results):
+            if not r.ok:
+                continue
+            cols, rows = r.req.meta["corners_px"]
+            holds = reference.footprint_holds_data(cols, rows, block)
+            state, fault = self._held(r.body, holds)
+            records.append({"drill": i, "holds_data": holds, "rows": state})
+            if fault:
+                problems.append(
+                    f"window drill {i} ({r.req.meta['side_px']:.0f} px "
+                    f"across, columns {cols.min():.1f}..{cols.max():.1f}, "
+                    f"rows {rows.min():.1f}..{rows.max():.1f}): {fault}")
         fields = self.archive.fields(self.p, self.seed)
         steps = self.p["steps"]
         bound = self.t["check"]["bound_abs"]
@@ -168,18 +202,24 @@ class Generator:
             rec = {"rect": [r0, r1, c0, c1]}
             records.append(rec)
             res = fetch(req)
-            bad = self._shape(res.body) if res.ok else f"status {res.status}"
-            if bad:
-                problems.append(f"drill {rec['rect']}: {bad}")
+            if not res.ok:
+                problems.append(f"drill {rec['rect']}: status {res.status}")
+                continue
+            mask = reference.burn_rectangle((r1 - r0 + 1, c1 - c0 + 1),
+                                            0, r1 - r0, 0, c1 - c0)
+            want, count = zip(*(reference.drill_means(
+                fields[n].window(np.arange(steps), r0, r1 + 1, c0, c1 + 1),
+                mask, float(self.p["nodata"])) for n in names))
+            rec["holds_data"] = bool(np.any(count))
+            rec["rows"], fault = self._held(res.body, rec["holds_data"])
+            if fault:
+                problems.append(f"drill {rec['rect']}: {fault}")
+                continue
+            if not rec["holds_data"]:
                 continue
             rows = parse_rows(res.body)
             got = np.array([rows[d] for d in sorted(rows)])     # (T, bands)
-            mask = reference.burn_rectangle((r1 - r0 + 1, c1 - c0 + 1),
-                                            0, r1 - r0, 0, c1 - c0)
-            want = np.stack([reference.drill_means(
-                fields[n].window(np.arange(steps), r0, r1 + 1, c0, c1 + 1),
-                mask, float(self.p["nodata"]))[0] for n in names], 1)
-            rec["max_abs_err"] = float(np.abs(got - want).max())
+            rec["max_abs_err"] = float(np.abs(got - np.stack(want, 1)).max())
             if rec["max_abs_err"] > bound:
                 problems.append(f"drill {rec['rect']}: a mean is off by "
                                 f"{rec['max_abs_err']:.3g} (bound {bound})")

@@ -96,7 +96,8 @@ class Generator(xyz_sessions.Generator):
             if not res.ok:
                 problems.append(f"tile {req.key}: status {res.status}")
                 continue
-            if res.digest != seen.digest:
+            rec["served_twice"] = res.digest != seen.digest
+            if rec["served_twice"]:
                 problems.append(f"tile {req.key}: served twice, two answers")
             img = Image.open(io.BytesIO(res.body))
             if img.mode != "RGBA":

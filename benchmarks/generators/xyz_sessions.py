@@ -34,6 +34,7 @@ from ..plan import Plan, Req, png_ok
 
 R = 6378137.0
 WORLD = 2 * math.pi * R
+TWIN_OFFSET_PX = 1.0 / 128      # see `Generator.twins`
 
 
 def tile_bbox(z, x, y):
@@ -188,19 +189,31 @@ class Generator:
 
     def twins(self, requests):
         """For each request one that runs the program it will run and
-        shares nothing else with it: the same tile half a pixel on (same
-        scenes, same gather window, another response, another control
-        grid).  A program's first use in a process stalls a dispatch
-        slot while it compiles or loads; the twin takes that stall
-        before the window."""
+        shares nothing else with it: the same tile `TWIN_OFFSET_PX` of a
+        pixel on (same scenes, same gather window, another response,
+        another index query, another control grid).  A program's first
+        use in a process stalls a dispatch slot while it compiles or
+        loads; the twin takes that stall before the window.
+
+        Which program a tile runs is decided by the scenes its bbox
+        touches and by its footprint in source pixels, floored and
+        padded to a bucket, so the nearer the twin the surer it runs its
+        request's program: half a pixel on (a twin until PR 31) is up to
+        2 source pixels on, and now and then another bucket or another
+        count of scenes, whose program then compiles inside the window
+        of a young checkout (PERF.md section 6 has the seed).  How near
+        it may be is set by the program's response cache, which keys a
+        bbox by 1/256ths of a pixel (`quantise_bbox`): nearer than that
+        the window's own tile is answered from the cache and the cell
+        measures nothing.  1/128 is two of those steps."""
         out = []
         for req in requests:
             m = req.meta
             b = m["bbox"]
-            half = (b[2] - b[0]) / 512.0
+            d = (b[2] - b[0]) / 256.0 * TWIN_OFFSET_PX
             out.append(self._req(
                 m["layer"], m["z"], req.key[2], req.key[3], m["time"],
-                bbox=(b[0] + half, b[1] + half, b[2] + half, b[3] + half),
+                bbox=(b[0] + d, b[1] + d, b[2] + d, b[3] + d),
                 key=req.key + ("twin",)))
         return out
 
@@ -251,7 +264,8 @@ class Generator:
             if not res.ok:
                 problems.append(f"tile {req.key}: status {res.status}")
                 continue
-            if res.digest != seen.digest:
+            rec["served_twice"] = res.digest != seen.digest
+            if rec["served_twice"]:
                 problems.append(f"tile {req.key}: served twice, two answers")
             img = Image.open(io.BytesIO(res.body))
             got = np.asarray(img)

@@ -17,7 +17,7 @@ the validity-weighted mean of the four around it (bilinear); where
 several scenes of one namespace are valid the newest wins; the value is
 clipped to [0, clip], scaled and floored to a byte 0..254, and 255 means
 no data.  A drill row is the mean over the polygon's valid pixels of one
-timestep.
+timestep, and an empty field where the polygon touches no valid pixel.
 """
 
 from dataclasses import dataclass
@@ -193,3 +193,22 @@ def drill_means(stack, mask, nodata):
     s = np.where(ok, stack, 0).reshape(len(stack), -1) \
         .sum(-1, dtype=np.float64)
     return s / np.maximum(n, 1), n
+
+
+def footprint_holds_data(cols, rows, nodata_below):
+    """Does the all-touched burn of the polygon with corners (cols,
+    rows), in corner-based pixel coordinates, hold a pixel outside the
+    raster's nodata block (rows < nodata_below[0] and columns <
+    nodata_below[1])?  False: every corner lies a pixel or more inside
+    the block, and so does all of a polygon.  True: a corner lies a
+    pixel or more outside it, and any burn takes the pixel under a
+    corner.  None: within a pixel of the block's edge it depends on how
+    a burn takes a pixel that the outline only grazes, which this
+    reference does not decide."""
+    er, ec = nodata_below
+    cols, rows = np.asarray(cols, float), np.asarray(rows, float)
+    if np.all((cols <= ec - 1) & (rows <= er - 1)):
+        return False
+    if np.any((cols >= ec + 1) | (rows >= er + 1)):
+        return True
+    return None

@@ -7,9 +7,11 @@ One process holds the chip.  It makes the cell's archive from the seed,
 boots the real `gsky-ows` with an in-process MAS, drives it over HTTP,
 warms up, measures for --seconds, checks a sample of answers against
 `reference.py` outside the window, and prints one JSON object as the
-last line of stdout: correct, attempted, failed, metrics, device (and
-breakdown with --trace 1).  Everything else worth reading goes to
-earlier lines and to <out>/<cell>.json.
+last line of stdout: correct, attempted, failed, metrics, device
+(breakdown with --trace 1), then problems (the first three reasons why
+it is not correct, empty where it is) and checks (every number compared,
+beside its limit; the last lines of stderr say the same).  Everything
+else worth reading goes to earlier lines and to <out>/<cell>.json.
 
 It exits 2 and prints no result when JAX finds no TPU, or fewer chips
 than the cell asks for.  `JAX_PLATFORMS=cpu` is accepted only with
@@ -38,11 +40,16 @@ sys.path.insert(0, ROOT)
 TRACE_LEAD_S = 2.0          # into the window before the profiler starts
 TRACE_MAX_S = 5.0           # the profiled slice
 # Loads from the compile cache that a window may hold and stay `correct`.
-# A twin now and then runs another program than its request (half a pixel
-# moves a footprint over a bucket's edge): one load in 11,600 requests of
+# A twin now and then runs another program than its request (the twin's
+# footprint falls over a bucket's edge, or touches another count of
+# scenes): with twins half a pixel on, one load in 11,600 requests of
 # three 40 s windows, none in 38,000 of twenty 20 s windows, and that
-# run's numbers lay inside the others' (PERF.md section 6).  Past the
-# twinned head 1-2 % of requests load a program; three already say so.
+# run's numbers lay inside the others' (PERF.md section 6).  A load is
+# what such a program costs a checkout that has run it before; one that
+# has not compiles it, which nothing here bears (seed 2147484103, PR 31),
+# so since then a twin lies 1/128 of a pixel on
+# (`generators/xyz_sessions.py::twins`).  Past the twinned head 1-2 % of
+# requests load a program; three already say so.
 STRAY_LOADS = 2
 
 
@@ -246,39 +253,88 @@ def reduce_trace(tracer, ctx, out_dir, spans_file):
                 idle, reduce.read_spans(spans_file), tracer.wall0)}
 
 
-def program_state_problems(ctx, traffic):
-    """What `correct` needs besides right answers: the path that served
-    is the one the cell is about, and the window measured serving and
-    nothing else (a program first used inside it stalls a dispatch slot,
-    and ten such stalls once took a cell from 97 to 36 requests/s)."""
+def program_state(ctx, traffic):
+    """What `correct` needs besides right answers, read once for the
+    problems and for the line's `checks`: programs first used inside the
+    window, the twinned head, kernels that failed, ran interpreted or
+    failed to prewarm, the device guard's incidents, and the counters
+    the traffic file wants still."""
     from benchmarks.ctx import dig
-    out = []
+    d1 = ctx.debug1
+    k = d1.get("kernels", {})
+    dev = d1.get("device", {})
     fresh, loads = ctx.compiles_in_window
-    if fresh or loads > STRAY_LOADS:
-        out.append(f"{fresh} program(s) compiled and {loads} loaded inside "
-                   f"the window (first used there: {ctx.first_used()}): "
-                   "warm-up did not reach them")
+    return {
+        "fresh": fresh, "loads": loads,
+        "failed": k.get("failed") or [],
+        "interpreted": [n for n, modes in (k.get("lowered") or {}).items()
+                        if "interpret" in modes],
+        "prewarm": d1.get("prewarm") if dig(d1, "prewarm.failures") else None,
+        "guard": {w: dev.get(w) for w in ("hangs", "crashes", "ooms",
+                                          "corruptions", "reinits")
+                  if dev.get(w)},
+        "moved": {path: ctx.delta(path)
+                  for path in traffic.get("demand_still", [])
+                  if ctx.delta(path)}}
+
+
+def program_state_problems(ctx, st):
+    """The path that served is the one the cell is about, and the window
+    measured serving and nothing else (a program first used inside it
+    stalls a dispatch slot, and ten such stalls once took a cell from 97
+    to 36 requests/s).  `st`: `program_state`."""
+    out = []
+    if st["fresh"] or st["loads"] > STRAY_LOADS:
+        out.append(f"{st['fresh']} program(s) compiled and {st['loads']} "
+                   f"loaded inside the window (first used there: "
+                   f"{ctx.first_used()}): warm-up did not reach them")
     if ctx.warmed is not None and len(ctx.results) > ctx.warmed:
         out.append(f"the window sent {len(ctx.results)} requests and only "
                    f"its first {ctx.warmed} had a twin in warm-up")
-    d1 = ctx.debug1
-    k = d1.get("kernels", {})
-    if k.get("failed"):
-        out.append(f"failed kernels: {k['failed']}")
-    interp = [n for n, modes in (k.get("lowered") or {}).items()
-              if "interpret" in modes]
-    if interp:
-        out.append(f"kernels ran interpreted: {interp}")
-    dev = d1.get("device", {})
-    hit = {w: dev.get(w) for w in ("hangs", "crashes", "ooms", "corruptions",
-                                   "reinits") if dev.get(w)}
-    if hit:
-        out.append(f"device guard incidents: {hit}")
-    for path in traffic.get("demand_still", []):
-        if ctx.delta(path):
-            out.append(f"{path} moved by {ctx.delta(path)} in the window")
-    if dig(d1, "prewarm.failures"):
-        out.append(f"prewarm: {d1['prewarm']}")
+    if st["failed"]:
+        out.append(f"failed kernels: {st['failed']}")
+    if st["interpreted"]:
+        out.append(f"kernels ran interpreted: {st['interpreted']}")
+    if st["guard"]:
+        out.append(f"device guard incidents: {st['guard']}")
+    for path, by in st["moved"].items():
+        out.append(f"{path} moved by {by} in the window")
+    if st["prewarm"]:
+        out.append(f"prewarm: {st['prewarm']}")
+    return out
+
+
+def checks_of(records, ctx, check, st):
+    """The line's `checks`: every number `correct` compares, beside its
+    limit where it has one of its own (the others' limit is 0), folded
+    from the generator's records, the traffic file's `check` and
+    `program_state`.  A drill is counted by its rows' state
+    (`generators/polygons.py::_held`)."""
+    rows = [r.get("rows") for r in records]
+    out = {"answers_checked": sum(
+        1 for r in records if {"mismatch", "max_abs_err", "rows"} & set(r))}
+    if "bound_mismatch" in check:
+        out["mismatch_max"] = max((r["mismatch"] for r in records
+                                   if "mismatch" in r), default=0.0)
+        out["mismatch_bound"] = check["bound_mismatch"]
+    if "bound_abs" in check:
+        out["abs_err_max"] = max((r["max_abs_err"] for r in records
+                                  if "max_abs_err" in r), default=0.0)
+        out["abs_err_bound"] = check["bound_abs"]
+    out.update(
+        served_twice_differs=sum(1 for r in records if r.get("served_twice")),
+        rows_malformed=rows.count("malformed"),
+        rows_empty_on_nodata=rows.count("empty_on_nodata"),
+        rows_undecided=rows.count("undecided"),
+        compiled_in_window=st["fresh"], loaded_in_window=st["loads"],
+        loaded_in_window_bound=STRAY_LOADS, sent=len(ctx.results))
+    if ctx.warmed is not None:
+        out["warmed"] = ctx.warmed
+    out.update(
+        demand_moved=sum(abs(v) for v in st["moved"].values()),
+        kernel_incidents=len(st["failed"]) + len(st["interpreted"])
+        + bool(st["prewarm"]),
+        guard_incidents=sum(st["guard"].values()))
     return out
 
 
@@ -437,7 +493,9 @@ def run(args, cell, seconds, cache, scratch, spans_file, native_dir):
         problems, records = gen.verify(results, client.fetch)
         if not records:
             problems.append("no answer could be checked")
-        problems += program_state_problems(ctx, cell.traffic)
+        st = program_state(ctx, cell.traffic)
+        problems += program_state_problems(ctx, st)
+        checks = checks_of(records, ctx, cell.traffic.get("check", {}), st)
         log(f"checks took {time.perf_counter() - t_check:.1f} s: "
             f"{len(records)} answers, {len(problems)} problem(s)")
         for p in problems[:10]:
@@ -459,11 +517,14 @@ def run(args, cell, seconds, cache, scratch, spans_file, native_dir):
             "failed": len(failed), "metrics": metrics, "device": device}
     if breakdown:
         line["breakdown"] = breakdown
+    # why, where it is not correct: the ledger keeps this line's numbers
+    line["problems"] = [p[:200] for p in problems[:3]]
+    line["checks"] = checks
 
     report = dict(line, workload=cell.name, seed=args.seed, seconds=seconds,
                   trace=args.trace, rehearsal=args.rehearsal,
                   setup_s=setup_s, warmup=passes, problems=problems,
-                  checks=records, legs=ctx.legs(),
+                  records=records, legs=ctx.legs(),
                   stages_ms_per_tile={
                       k: ctx.ratio([f"tile_stages.{k}"],
                                    ["tile_stages.tiles"], 1e3)
@@ -487,6 +548,10 @@ def run(args, cell, seconds, cache, scratch, spans_file, native_dir):
     log(f"whole run {report['total_s']:.1f} s; report in "
         f"{os.path.join(args.out, cell.name + '.json')}")
     print(json.dumps(line), flush=True)
+    for p in line["problems"]:
+        print("benchmark: PROBLEM: " + p, file=sys.stderr)
+    print("benchmark: checks " + json.dumps(checks), file=sys.stderr,
+          flush=True)
     return 0
 
 

@@ -80,7 +80,9 @@ def test_a_new_cell_is_new_files_only(tmp_path):
         return json.loads(out.stdout.strip().splitlines()[-1])
 
     line = run(0)
-    assert set(line) == {"correct", "attempted", "failed", "metrics", "device"}
+    assert list(line) == ["correct", "attempted", "failed", "metrics",
+                          "device", "problems", "checks"]
+    assert line["problems"] == []
     assert line["correct"] and line["attempted"] > 0 and line["failed"] == 0
     # the 95th percentile is reported from 200 requests up
     assert {"latency_p50_ms", "throughput_rps", "setup_s"} \

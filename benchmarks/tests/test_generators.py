@@ -68,15 +68,24 @@ def test_pan_cold_never_repeats():
     assert gen.prefill() == []
 
 
-def test_a_twin_is_the_same_tile_half_a_pixel_on():
+def test_a_twin_is_the_same_tile_a_sliver_of_a_pixel_on():
+    """Near enough to touch the same scenes and fall into the same
+    bucket; far enough to be another tile to the program's response
+    cache, which keys a bbox by 1/256ths of a pixel: two of those
+    steps, at every level."""
     _, gen = make("landsat8-mosaic.pan-cold", 5)
-    req = first(gen.window(), 1)[0]
-    twin, = gen.twins([req])
-    a, b = np.array(req.meta["bbox"]), np.array(twin.meta["bbox"])
-    pixel = (a[2] - a[0]) / 256
-    assert np.allclose(b - a, pixel / 2)
-    assert (twin.meta["layer"], twin.meta["time"]) == \
-        (req.meta["layer"], req.meta["time"])
+    reqs = first(gen.window(), 300)
+    assert len({r.meta["z"] for r in reqs}) >= 3
+    for req, twin in zip(reqs, gen.twins(reqs)):
+        a, b = np.array(req.meta["bbox"]), np.array(twin.meta["bbox"])
+        pixel = (a[2] - a[0]) / 256
+        assert np.allclose(b - a, pixel / 128, rtol=1e-6)
+        # the response cache's own rule (serving/response_cache.py)
+        quantum = pixel / 256
+        assert (np.round(b / quantum) != np.round(a / quantum)).all()
+        assert twin.path != req.path
+        assert (twin.meta["layer"], twin.meta["time"]) == \
+            (req.meta["layer"], req.meta["time"])
 
 
 def test_the_mix_holds_the_shares_the_file_states():
@@ -130,3 +139,31 @@ def test_polygons_lie_inside_the_stack_and_differ():
     sides = [r.meta["side_px"] for r in reqs]
     assert lo <= min(sides) and max(sides) <= hi
     assert max(sides) > 4 * min(sides)      # paddock to catchment
+
+
+# sha256 over path, NUL, body, newline of a seed's first 500 requests at
+# the size the chip runs, recorded from the tree before PR 31 (34f48ce):
+# a repair of `correct` or of the warm-up sends the window what it was sent
+DRAWN = {
+    "pan-cold":
+        "2e83520bccdcb649ff6ce84ff1fcaa6195eeacb9f56494a792ab25ef410980bb",
+    "polygons-warm":
+        "16689db2a116430cd2c296ab9cb385780c9e035ca49bb457102721b20e5f39a2",
+    "rgb-pan-cold":
+        "a34f09c9f5a93217ab43235b5a4a4f8cb2fec4bde045b808a115d929cd8d18b5",
+}
+
+
+@pytest.mark.parametrize("mix", sorted(DRAWN))
+def test_the_same_seed_still_draws_the_same_requests(mix):
+    import hashlib
+    bench = spec.load_json(os.path.join(spec.ROOT, "BENCHMARK.json"))
+    name = next(w["name"] for w in bench["workloads"] if w["traffic"] == mix)
+    cell = spec.load_cell(name)
+    archive = spec.load_kind("archives", cell.config["archive"]["kind"])
+    gen = spec.load_kind("generators", cell.traffic["generator"]).Generator(
+        cell.traffic, cell.config, archive, 2147483659)
+    h = hashlib.sha256()
+    for r in first(gen.window(), 500):
+        h.update(r.path.encode() + b"\0" + (r.body or b"") + b"\n")
+    assert h.hexdigest() == DRAWN[mix]

@@ -107,18 +107,78 @@ def ctx(**kw):
     return SimpleNamespace(**dict(base, **kw))
 
 
+def problems_of(c, traffic):
+    return run.program_state_problems(c, run.program_state(c, traffic))
+
+
+def checks_of(records, c, traffic):
+    return run.checks_of(records, c, traffic.get("check", {}),
+                         run.program_state(c, traffic))
+
+
 def test_a_window_that_outran_its_warm_up_is_not_correct():
-    assert run.program_state_problems(ctx(), {}) == []
-    assert run.program_state_problems(ctx(warmed=500), {}) == []
-    late, = run.program_state_problems(ctx(warmed=499), {})
+    assert problems_of(ctx(), {}) == []
+    assert problems_of(ctx(warmed=500), {}) == []
+    late, = problems_of(ctx(warmed=499), {})
     assert "500 requests" in late and "499" in late
 
 
 def test_a_stray_load_is_borne_and_a_compile_or_a_run_of_loads_is_not():
     stray = (0, run.STRAY_LOADS)
-    assert run.program_state_problems(ctx(compiles_in_window=stray), {}) == []
-    many, = run.program_state_problems(
+    assert problems_of(ctx(compiles_in_window=stray), {}) == []
+    many, = problems_of(
         ctx(compiles_in_window=(0, run.STRAY_LOADS + 1)), {})
     assert "3 loaded" in many and "leg" in many
-    fresh, = run.program_state_problems(ctx(compiles_in_window=(1, 0)), {})
+    fresh, = problems_of(ctx(compiles_in_window=(1, 0)), {})
     assert "1 program(s) compiled" in fresh
+
+
+COMMON = ["answers_checked", "served_twice_differs", "rows_malformed",
+          "rows_empty_on_nodata", "rows_undecided", "compiled_in_window",
+          "loaded_in_window", "loaded_in_window_bound", "sent",
+          "demand_moved", "kernel_incidents", "guard_incidents"]
+
+
+def test_checks_holds_every_number_compared_beside_its_limit():
+    """The line's `checks`, by the kind of cell: finite numbers under
+    fixed names, so that the ledger's `last_line_numbers` can say why a
+    run was not correct."""
+    import math
+    tiles = checks_of(
+        [{"mismatch": 0.0004, "served_twice": False},
+         {"mismatch": 0.0031, "served_twice": True}, {}],
+        ctx(compiles_in_window=(1, 3), warmed=499),
+        {"check": {"tiles": 8, "bound_mismatch": 0.002}})
+    assert sorted(tiles) == sorted(
+        COMMON + ["mismatch_max", "mismatch_bound", "warmed"])
+    assert (tiles["answers_checked"], tiles["mismatch_max"],
+            tiles["mismatch_bound"], tiles["served_twice_differs"]) \
+        == (2, 0.0031, 0.002, 1)
+    assert (tiles["compiled_in_window"], tiles["loaded_in_window"],
+            tiles["loaded_in_window_bound"], tiles["sent"],
+            tiles["warmed"]) == (1, 3, run.STRAY_LOADS, 500, 499)
+
+    moved = {"executor.dispatches.drill_host": 2}
+    debug1 = {"kernels": {"failed": ["k"], "lowered": {"m": ["interpret"]}},
+              "device": {"hangs": 1, "ooms": 2, "crashes": 0}}
+    drills = checks_of(
+        [{"rows": "finite"}, {"rows": "empty_on_nodata"},
+         {"rows": "undecided"}, {"rows": "malformed"},
+         {"rect": [0, 1, 0, 1], "rows": "finite", "max_abs_err": 5e-5}],
+        ctx(debug1=debug1, delta=lambda path: moved.get(path, 0)),
+        {"check": {"rect_px": [16], "bound_abs": 2e-4},
+         "demand_still": list(moved) + ["executor.dispatches.other"]})
+    assert sorted(drills) == sorted(COMMON + ["abs_err_max", "abs_err_bound"])
+    assert (drills["answers_checked"], drills["abs_err_max"],
+            drills["abs_err_bound"]) == (5, 5e-5, 2e-4)
+    assert (drills["rows_malformed"], drills["rows_empty_on_nodata"],
+            drills["rows_undecided"]) == (1, 1, 1)
+    assert (drills["demand_moved"], drills["kernel_incidents"],
+            drills["guard_incidents"]) == (2, 2, 3)
+    for checks in (tiles, drills):
+        assert all(isinstance(v, (int, float)) and not isinstance(v, bool)
+                   and math.isfinite(v) for v in checks.values()), checks
+    # the problems say the same as the numbers
+    assert len(problems_of(
+        ctx(debug1=debug1, delta=lambda path: moved.get(path, 0)),
+        {"demand_still": list(moved)})) == 4
