@@ -68,7 +68,15 @@ class Dataset:
 
     @classmethod
     def from_json(cls, j: Dict) -> "Dataset":
-        iso = list(j.get("timestamps") or [])
+        # an in-process store's record brings its stamps already parsed
+        # (`store.GdalRecord`): both lists are then the store's own,
+        # shared with every query that returns the row and never written
+        unix = getattr(j, "unix", None)
+        if unix is not None:
+            iso = j["timestamps"]
+        else:
+            iso = list(j.get("timestamps") or [])
+            unix = [parse_time(s) for s in iso]
         return cls(
             file_path=j.get("file_path", ""),
             ds_name=j.get("ds_name", ""),
@@ -76,7 +84,7 @@ class Dataset:
             array_type=j.get("array_type", "Float32"),
             srs=j.get("srs", ""),
             geo_transform=j.get("geo_transform"),
-            timestamps=[parse_time(s) for s in iso],
+            timestamps=unix,
             timestamps_iso=iso,
             polygon=j.get("polygon", ""),
             nodata=float(j.get("nodata") or 0.0),

@@ -63,6 +63,23 @@ def fmt_time(t: float) -> str:
     return dt.datetime.fromtimestamp(t, dt.timezone.utc).strftime(ISO)
 
 
+class GdalRecord(dict):
+    """One `gdal` record of an ``?intersects`` answer: to `json.dumps`
+    and every consumer the dict it always was, and beside its items
+    `unix`, the record's `timestamps` as unix seconds in the same order
+    (what `parse_time` gives for each), so the in-process client does
+    not parse them again.  A record's nested values and `unix` belong
+    to the store's decoded row and are shared by every query that
+    returns the row: read-only to all."""
+
+    __slots__ = ("unix",)
+
+    def copy(self) -> "GdalRecord":
+        r = GdalRecord(self)
+        r.unix = self.unix
+        return r
+
+
 _SCHEMA = """
 CREATE TABLE IF NOT EXISTS files(
     path TEXT PRIMARY KEY,
@@ -128,7 +145,14 @@ class MASStore:
     # across the many MASStore instances a sharded store fans out to
     total_query_hits = 0
     total_query_misses = 0
+    # dataset rows handed out by gdal queries: decoded earlier under
+    # this generation (hit) or decoded for this query (miss)
+    total_row_hits = 0
+    total_row_misses = 0
     _totals_lock = threading.Lock()
+    # decoded rows kept per store, oldest out first.  A row of 1,000
+    # stamps is ~0.15 MB decoded; a tile archive's rows hold one stamp
+    _ROW_CACHE_MAX = 2048
 
     def __init__(self, db_path: str = ":memory:"):
         self._db_path = db_path
@@ -137,6 +161,13 @@ class MASStore:
         self._cache_lock = threading.Lock()
         self.query_hits = 0
         self.query_misses = 0
+        # (generation, {row id: (sql row, GdalRecord)}): what a gdal
+        # query answers for a row, decoded once per generation.  Swapped
+        # whole when the generation moves; read without a lock
+        self._rows: Tuple[int, Dict[int, Tuple[tuple, GdalRecord]]] = \
+            (-1, {})
+        self.row_hits = 0
+        self.row_misses = 0
         self._local = threading.local()
         self._memory_conn: Optional[sqlite3.Connection] = None
         # a single :memory: connection is shared across threads, so every
@@ -153,6 +184,9 @@ class MASStore:
             self._conn().commit()
         self._columns = [d[0] for d in self._conn().execute(
             "SELECT * FROM datasets LIMIT 0").description]
+        self._i_id, self._i_path, self._i_srs, self._i_polygon = (
+            self._columns.index(c)
+            for c in ("id", "path", "srs", "polygon"))
         # bumped on every ingest; response caches key on it so cached
         # answers die with the data they were computed from.  Persisted
         # in sqlite (gsky_meta) so an ingest from ANOTHER process against
@@ -298,16 +332,18 @@ class MASStore:
         """`mas_intersects` (`mas/api/mas.sql:363-547`).  Returns
         {"files": [...]} or {"gdal": [...]} when metadata == "gdal".
 
-        Results cache per (args, generation) — the in-process stand-in
+        A row's record is decoded once per generation (`_records`);
+        results cache per (args, generation) — the in-process stand-in
         for the reference's memcached tier in front of MAS
         (`mas/api/api.go:43-52`): a tile server asks the same question
         for every zoom-level repeat, and the polygon refinement below is
         ~3 ms a call.  Any ingest bumps the generation (even from
         another process against the same file DB), so cached answers
         die with the data they were computed from."""
+        generation = self.generation
         ckey = (gpath, srs, wkt, nseg, time, until,
                 tuple(namespaces) if namespaces else None, metadata,
-                limit, self.generation)
+                limit, generation)
         with self._cache_lock:
             hit = self._query_cache.get(ckey)
             if hit is not None:
@@ -326,7 +362,7 @@ class MASStore:
             # every consumer — a deepcopy here would cost as much as
             # the query it saves for deep time-series responses
             if "gdal" in hit:
-                return {"gdal": [dict(r) for r in hit["gdal"]]}
+                return {"gdal": [r.copy() for r in hit["gdal"]]}
             return {"files": list(hit["files"])}
         q_geom = None
         if wkt:
@@ -373,17 +409,16 @@ class MASStore:
             sql += " AND namespace IN (%s)" % ",".join("?" * len(namespaces))
             args += list(namespaces)
         rows = self._fetchall(sql, args)
-        cols = self._columns
+        i_path, i_srs, i_polygon = self._i_path, self._i_srs, self._i_polygon
 
         # refine: exact polygon intersection in 4326
         out_rows = []
         for row in rows:
-            r = dict(zip(cols, row))
-            if q_geom is not None and r["polygon"]:
+            if q_geom is not None and row[i_polygon]:
                 try:
-                    p = geom.from_wkt(r["polygon"])
-                    if r["srs"]:
-                        crs = parse_crs(r["srs"])
+                    p = geom.from_wkt(row[i_polygon])
+                    if row[i_srs]:
+                        crs = parse_crs(row[i_srs])
                         if crs != EPSG4326:
                             p = p.transform(lambda x, y: crs.transform_to(
                                 EPSG4326, x, y))
@@ -393,33 +428,76 @@ class MASStore:
                         continue
                 except (ValueError, KeyError):
                     pass
-            out_rows.append(r)
+            out_rows.append(row)
             if limit and len(out_rows) >= limit:
                 break
 
         if metadata != "gdal":
             return self._cache_put(
-                ckey, {"files": sorted({r["path"] for r in out_rows})})
-        gdal = []
-        for r in out_rows:
-            gdal.append({
-                "file_path": r["path"],
-                "ds_name": r["ds_name"],
-                "namespace": r["namespace"],
-                "array_type": r["array_type"],
-                "srs": r["srs"],
-                "geo_transform": json.loads(r["geo_transform"] or "null"),
-                "timestamps": json.loads(r["timestamps"] or "[]"),
-                "polygon": r["polygon"],
-                "overviews": json.loads(r["overviews"]) if r["overviews"] else None,
-                "means": json.loads(r["means"]) if r["means"] else None,
-                "sample_counts": json.loads(r["sample_counts"])
-                if r["sample_counts"] else None,
-                "nodata": r["nodata"] if r["nodata"] is not None else 0.0,
-                "axes": json.loads(r["axes"]) if r["axes"] else None,
-                "geo_loc": json.loads(r["geo_loc"]) if r["geo_loc"] else None,
-            })
-        return self._cache_put(ckey, {"gdal": gdal})
+                ckey, {"files": sorted({r[i_path] for r in out_rows})})
+        return self._cache_put(
+            ckey, {"gdal": self._records(out_rows, generation)})
+
+    def _records(self, rows: List[tuple],
+                 generation: int) -> List[GdalRecord]:
+        """The `gdal` record of each SQL row, each a copy (its own top
+        level, shared insides) of the row's decoded record.  A row is
+        decoded (its JSON columns loaded, its stamps parsed) once per
+        generation; a kept record answers only for the row it was
+        decoded from (same id AND equal columns: sqlite hands a deleted
+        row's id to the next insert, and a query that read its
+        generation before an ingest may select after it)."""
+        gen, kept = self._rows
+        if gen < generation:
+            with self._cache_lock:
+                if self._rows[0] < generation:
+                    self._rows = (generation, {})
+                gen, kept = self._rows
+        if gen != generation:
+            kept = {}           # a query older than the data: its own
+        i_id = self._i_id
+        out = []
+        hits = 0
+        for row in rows:
+            entry = kept.get(row[i_id])
+            if entry is not None and entry[0] == row:
+                hits += 1
+                rec = entry[1]
+            else:
+                rec = self._decode(row)
+                with self._cache_lock:
+                    kept[row[i_id]] = (row, rec)
+                    while len(kept) > self._ROW_CACHE_MAX:
+                        del kept[next(iter(kept))]
+            out.append(rec.copy())
+        with MASStore._totals_lock:
+            self.row_hits += hits
+            self.row_misses += len(rows) - hits
+            MASStore.total_row_hits += hits
+            MASStore.total_row_misses += len(rows) - hits
+        return out
+
+    def _decode(self, row: tuple) -> GdalRecord:
+        r = dict(zip(self._columns, row))
+        rec = GdalRecord({
+            "file_path": r["path"],
+            "ds_name": r["ds_name"],
+            "namespace": r["namespace"],
+            "array_type": r["array_type"],
+            "srs": r["srs"],
+            "geo_transform": json.loads(r["geo_transform"] or "null"),
+            "timestamps": json.loads(r["timestamps"] or "[]"),
+            "polygon": r["polygon"],
+            "overviews": json.loads(r["overviews"]) if r["overviews"] else None,
+            "means": json.loads(r["means"]) if r["means"] else None,
+            "sample_counts": json.loads(r["sample_counts"])
+            if r["sample_counts"] else None,
+            "nodata": r["nodata"] if r["nodata"] is not None else 0.0,
+            "axes": json.loads(r["axes"]) if r["axes"] else None,
+            "geo_loc": json.loads(r["geo_loc"]) if r["geo_loc"] else None,
+        })
+        rec.unix = [parse_time(s) for s in rec["timestamps"]]
+        return rec
 
     def _cache_put(self, ckey, value: Dict) -> Dict:
         # NOTE: this, api.MasQueryCache and executor's geo cache are
@@ -428,7 +506,7 @@ class MASStore:
         # deliberately — a shared helper would couple their eviction
         # policies for ~10 lines of savings each
         if "gdal" in value:
-            kept = {"gdal": [dict(r) for r in value["gdal"]]}
+            kept = {"gdal": [r.copy() for r in value["gdal"]]}
         else:
             kept = {"files": list(value["files"])}
         with self._cache_lock:

@@ -60,6 +60,9 @@ def _resolve_cache_handles():
         handles.append(("mas_query", lambda cls=cls: {
             "hits": cls.total_query_hits,
             "misses": cls.total_query_misses}))
+        handles.append(("mas_rows", lambda cls=cls: {
+            "hits": cls.total_row_hits,
+            "misses": cls.total_row_misses}))
     except Exception:  # tier absent in this build - skip its counters
         pass
     try:
