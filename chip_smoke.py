@@ -54,7 +54,7 @@ SIZES = {
     # scene cache's byte budget (device.residency_budget).
     "scene_hw": (7601, 7761),
     # >= 4 overlapping scenes on consecutive days, each shifted a third
-    # of a scene east and a fifth south (bench.py's layout at size)
+    # of a scene east and a fifth south (the soak's layout at size)
     "mosaic_scenes": 4,
     # one 3-band scene of the same size (RGB bilinear composite)
     "rgb_bands": 3,

@@ -23,7 +23,6 @@ from .supervisor import (  # noqa: F401
     hang_deadline_s,
     integrity_check,
     pool_audit_enabled,
-    register_oom_hook,
     reset,
     run,
     staging_ok,

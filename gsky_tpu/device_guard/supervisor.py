@@ -13,8 +13,8 @@ PRs 3/6/9 made the *fleet* survive faults; this module supervises the
   ``BackendUnavailable``, so the gateway answers 503 + Retry-After and
   the worker client fails over without a breaker penalty).
 - **oom** — ``RESOURCE_EXHAUSTED`` triggers the one-shot relief
-  protocol (pool trim + pressure escalation + registered batch-cap
-  hooks) and a single retry before failing (:func:`run`).
+  protocol (pool trim + pressure escalation) and a single retry
+  before failing (:func:`run`).
 - **corruption** — the readback integrity probe
   (:func:`integrity_check`; ±inf is never a legal output value — the
   pipeline encodes validity as NaN) quarantines poisoned pages via the
@@ -50,7 +50,7 @@ import random
 import threading
 import time
 from collections import deque
-from typing import Callable, List, Optional
+from typing import Callable, Optional
 
 import numpy as np
 
@@ -94,7 +94,7 @@ class DeviceDead(DeviceGuardError):
 
 def guard_enabled() -> bool:
     """Escape hatch, read per call so it is live-tunable — the
-    GSKY_TILE_PIPELINE / GSKY_PAGED idiom."""
+    GSKY_PAGED idiom."""
     return os.environ.get("GSKY_DEVICE_GUARD", "1") != "0"
 
 
@@ -375,16 +375,6 @@ def staging_ok() -> bool:
     return _default.staging_ok()
 
 
-# hooks run by the OOM relief protocol (the executor registers a
-# batch-cap reduction here so the retry and all later waves are smaller)
-_oom_hooks: List[Callable[[], None]] = []
-
-
-def register_oom_hook(fn: Callable[[], None]) -> None:
-    if fn not in _oom_hooks:
-        _oom_hooks.append(fn)
-
-
 _UNSET = object()
 
 # -- two-in-flight wave supervision -------------------------------------
@@ -493,8 +483,8 @@ def supervised_sync(site: str, thunk: Callable,
 
 def _oom_relief() -> None:
     """The one-shot RESOURCE_EXHAUSTED relief protocol: trim the page
-    pool's cold half, escalate the pressure monitor (cache relief +
-    admission clamp + brownout), and run registered batch-cap hooks."""
+    pool's cold half and escalate the pressure monitor (cache relief +
+    admission clamp + brownout)."""
     try:
         from ..pipeline import pages
         if pages._default is not None:
@@ -506,11 +496,6 @@ def _oom_relief() -> None:
         default_monitor().escalate()
     except Exception:  # pressure monitor absent - relief is best-effort
         pass
-    for fn in list(_oom_hooks):
-        try:
-            fn()
-        except Exception:  # one failing OOM hook must not stop the rest
-            pass
 
 
 def run(site: str, thunk: Callable, reduced: Optional[Callable] = None):

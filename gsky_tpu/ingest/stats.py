@@ -8,7 +8,7 @@ both sides of the ``GSKY_INGEST`` escape hatch:
   COMPRESSED on-disk/on-wire bytes, the number an object store bills;
 * the **whole** path (scene-cache full-scene loads and the plain
   window decode that `GSKY_INGEST=0` restores) records the logical
-  bytes it materialised, so `bench.py cfg_ingest` and the ingest soak
+  bytes it materialised, so the ingest soak
   can state the reduction as ranged-vs-whole on the same ledger.
 
 Overlap: the dispatch stages (`tile_stages._dispatch_stage`,

@@ -5,7 +5,7 @@ Three concerns, one seam:
 
 * ``trace`` — a ContextVar-carried ``trace_id``/``span_id`` created at
   the OWS request boundary and threaded through the gateway, the tile
-  stages, the batcher, the export pipeline, and — via gRPC metadata —
+  stages, the export pipeline, and — via gRPC metadata —
   into the worker processes, whose child spans ride back on the RPC
   result and stitch into one tree.
 * ``recorder`` — an always-on in-memory ring of the last N complete
@@ -60,7 +60,6 @@ from .prom import (  # noqa: F401
 )
 from . import metrics  # noqa: F401  (registers default metric families)
 from .metrics import (  # noqa: F401
-    BATCH_FLUSHES,
     ENCODE_SECONDS,
     REQUESTS,
     REQUEST_SECONDS,

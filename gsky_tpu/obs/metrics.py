@@ -4,8 +4,8 @@ Two sourcing rules keep ``/metrics`` honest:
 
 * Distributions (latency, stage durations, RPC times) are observed at
   the exact measurement points that already feed ``/debug`` — in
-  ``server/metrics.py`` fold-in, the worker client, the batcher, and
-  the encode pool — never from a second clock.
+  ``server/metrics.py`` fold-in, the worker client, and the encode
+  pool — never from a second clock.
 * Monotonic counters and level gauges that already exist as live stats
   objects (caches, fleet router, resilience registry, encode pool,
   compile probe, flight recorder) are *collected at scrape time* from
@@ -40,9 +40,6 @@ RPC_SECONDS = _REG.histogram(
 ENCODE_SECONDS = _REG.histogram(
     "gsky_encode_seconds", "Encode-pool time by phase (wait vs cpu).",
     ["phase"], buckets=log_buckets(0.0005, 10.0))
-BATCH_FLUSHES = _REG.counter(
-    "gsky_batch_flushes_total", "Render-batcher flushes by trigger.",
-    ["kind"])
 WAVE_DISPATCHES = _REG.counter(
     "gsky_wave_dispatches_total",
     "Wave-scheduler device program invocations by result kind.",
@@ -276,30 +273,12 @@ def _collect_runtime():
     return out
 
 
-def _collect_batcher():
-    """RenderBatcher engagement + padding bill and the page-pool
-    residency stats (the ragged paged rendering telemetry,
-    docs/KERNELS.md)."""
+def _collect_paged():
+    """Paged-path engagement and the page-pool residency stats (the
+    ragged paged rendering telemetry, docs/KERNELS.md)."""
     out: List = []
     try:
         from ..pipeline.executor import default_executor
-        b = default_executor._batcher
-        st = b.stats()
-        out.append(_g("gsky_batch_knee",
-                      "Adaptive coalesce cap (tiles per flush).",
-                      [({}, float(st.get("batch_knee", 0)))]))
-        out.append(_c("gsky_batches_total",
-                      "Batch flushes by dispatch kind.",
-                      [({"kind": "windowed"},
-                        float(st.get("win_batches", 0))),
-                       ({"kind": "full"},
-                        float(st.get("full_batches", 0))),
-                       ({"kind": "paged"},
-                        float(st.get("paged_batches", 0)))]))
-        out.append(_c("gsky_pad_waste_bytes_total",
-                      "Bytes moved for pow2/bucket padding instead of "
-                      "payload across batch flushes.",
-                      [({}, float(st.get("pad_waste_bytes", 0)))]))
         out.append(_c("gsky_paged_dispatches_total",
                       "Executor dispatches served by the paged path vs "
                       "declined to buckets.",
@@ -717,7 +696,7 @@ def _collect_temporal():
 
 
 for _fn in (_collect_caches, _collect_fleet, _collect_resilience,
-            _collect_runtime, _collect_batcher, _collect_overload,
+            _collect_runtime, _collect_paged, _collect_overload,
             _collect_ingest, _collect_device, _collect_waves,
             _collect_mesh, _collect_expr, _collect_tsan,
             _collect_fabric, _collect_elastic, _collect_temporal):

@@ -26,7 +26,7 @@ Instrumentation has two hooks:
   server boots: tools/soak.py and server/main.py call
   :func:`maybe_install` first thing);
 * :func:`track` swizzles one object's class so attribute writes are
-  checked; the wave scheduler, page pool, and render batcher
+  checked; the wave scheduler and page pool
   self-register at construction when tsan is enabled (a disabled
   process pays a single ``if`` per constructor).
 

@@ -5,8 +5,7 @@ The bucketed dispatch (`ops.pallas_tpu` + `pipeline.executor`) bounds
 recompilation by padding every gather window up to `_WIN_BUCKETS` and
 every batch to a power of two — each (window-bucket x batch-pow2)
 combination is its own XLA program, pad waste inflates the expensive
-host<->device pull, and `RenderBatcher` can only coalesce tiles whose
-shapes already match.  Following Ragged Paged Attention (PAPERS.md),
+host<->device pull.  Following Ragged Paged Attention (PAPERS.md),
 which serves arbitrary ragged KV lengths from paged HBM pools with ONE
 compiled kernel, this module replaces the shape axes with a page
 indirection:
@@ -131,7 +130,7 @@ def paged_vmem_ok(slots: int, n_ns: int, pr: int, pc: int,
 # The pool->VMEM gather in `_paged_scored` is jit-traced, so a counter
 # inside it would tick once per COMPILE, not per dispatch.  The raced
 # wrappers (and the mesh dispatcher) account the bytes of each dispatch
-# they launch here, eagerly; bench.py and the plan soak read the total
+# they launch here, eagerly; the plan soak reads the total
 # to measure what superblock compaction actually saved.
 _GATHER_LOCK = __import__("threading").Lock()
 _GATHER_BYTES = 0
@@ -393,10 +392,9 @@ def render_byte_paged(pool, tables, params, ctrls, sps,
                       out_hw=(256, 256), step: int = 16,
                       auto: bool = True, colour_scale: int = 0,
                       interpret: bool = False, blk=None, sb_of=None):
-    """Paged counterpart of `ops.warp.render_scenes_ctrl` (and of the
-    batcher's `render_scenes_ctrl_many`): fused paged warp + mosaic,
-    then the SAME composite/byte-scale epilogue per tile.  sps (N, 3)
-    f32.  Returns PNG-ready uint8 (N, h, w) tiles."""
+    """Paged counterpart of `ops.warp.render_scenes_ctrl`: fused paged
+    warp + mosaic, then the SAME composite/byte-scale epilogue per
+    tile.  sps (N, 3) f32.  Returns PNG-ready uint8 (N, h, w) tiles."""
     from .warp import composite_scale
     canv, best = _paged_scored(pool, tables, params, ctrls, method,
                                n_ns, tuple(out_hw), step, interpret,

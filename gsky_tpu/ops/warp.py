@@ -568,33 +568,6 @@ def render_rgba_ctrl(granules, ctrl, params, prios, scale_params,
 
 @functools.partial(jax.jit,
                    static_argnames=("method", "n_ns", "out_hw", "step",
-                                    "auto", "colour_scale", "win"))
-def render_scenes_ctrl_many(stack, ctrls, params, scale_params,
-                            method: str = "near", n_ns: int = 1,
-                            out_hw: Tuple[int, int] = (256, 256),
-                            step: int = 16, auto: bool = True,
-                            colour_scale: int = 0,
-                            win: Optional[Tuple[int, int]] = None,
-                            win0=None):
-    """N whole GetMap tiles over one SHARED scene stack in one dispatch
-    (`pipeline.batcher.RenderBatcher` coalesces concurrent requests):
-    ctrls (N, 2, gh, gw), params (N, B, 11), scale_params (N, 3) ->
-    uint8 (N, h, w).  The device-stream round trips that bound
-    single-tile throughput are amortised N ways.
-
-    win/win0: one gather window shared by the WHOLE batch (the batcher
-    unions the per-tile footprints — coalesced tiles come from the
-    same map view, so the union stays small); unbatched on the vmap,
-    so the slice happens once."""
-    return jax.vmap(
-        lambda c, p, sp: _render_scenes_core(
-            stack, c, p, sp, method, n_ns, out_hw, step, auto,
-            colour_scale, win=win, win0=win0))(ctrls, params,
-                                               scale_params)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("method", "n_ns", "out_hw", "step",
                                     "win"))
 def warp_scenes_ctrl_scored(stack, ctrl, params, method: str = "near",
                             n_ns: int = 1,

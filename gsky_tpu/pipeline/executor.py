@@ -14,7 +14,7 @@ import math
 import os
 import threading
 from collections import OrderedDict
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -154,20 +154,13 @@ def _gather_window(params64: np.ndarray, cx: np.ndarray, cy: np.ndarray,
             c_hi = max(c_hi, made[3])
     if r_lo is None:
         return None
-    made = finish_window(r_lo, r_hi, c_lo, c_hi, bucket_h, bucket_w)
-    if made is None:
-        return None
-    win, win0 = made
-    # raw (unpadded, unclamped) bounds ride along so batch flushes can
-    # union footprints BEFORE bucketing (unioning padded windows would
-    # overshoot a bucket and decline needlessly)
-    return win, win0, (r_lo, r_hi, c_lo, c_hi)
+    return finish_window(r_lo, r_hi, c_lo, c_hi, bucket_h, bucket_w)
 
 
 def _gather_windows(params64: np.ndarray, cx: np.ndarray, cy: np.ndarray,
                     bucket_h: int, bucket_w: int):
     """`_gather_window` for a kernel that slices each scene by itself:
-    (win, win0 (B, 2), None), one window size, as large as the largest
+    (win, win0 (B, 2)), one window size, as large as the largest
     granule footprint, and one origin per granule, where the tile
     touches that granule.  Neighbouring granules of one grid lie a
     granule's pitch apart in each other's pixel coordinates, so one
@@ -190,15 +183,15 @@ def _gather_windows(params64: np.ndarray, cx: np.ndarray, cy: np.ndarray,
         return None
     win0 = np.stack([np.zeros(2, np.int32) if b is None
                      else finish(b[0], b[2])[1] for b in bounds])
-    return made[0], win0, None
+    return made[0], win0
 
 
 def finish_window(r_lo: int, r_hi: int, c_lo: int, c_hi: int,
                   bucket_h: int, bucket_w: int):
     """Bucket raw footprint bounds into (win, win0), or None when the
     window would be the whole stack — the ONE place the bucket /
-    decline / origin-clamp rules live (`_gather_window` and the
-    batcher's union flush both finish through here)."""
+    decline / origin-clamp rules live (`_gather_window` and
+    `_gather_windows` both finish through here)."""
     wr = min(_win_bucket(r_hi - r_lo), bucket_h)
     wc = min(_win_bucket(c_hi - c_lo), bucket_w)
     if wr >= bucket_h and wc >= bucket_w:
@@ -221,6 +214,23 @@ def _inv_gt_params(gt: GeoTransform, ox: float, oy: float):
     a0 = inv[0] * (ox - gt.x0) + inv[1] * (oy - gt.y0)
     a3 = inv[2] * (ox - gt.x0) + inv[3] * (oy - gt.y0)
     return (a0, inv[0], inv[1], a3, inv[2], inv[3])
+
+
+class SceneGroup(NamedTuple):
+    """What `_scene_groups` hands a fused scene kernel for one group of
+    granules on one source grid."""
+    # (B, bh, bw) stack the scene cache keeps, or (stacked=False) the
+    # tuple of the B scene arrays; B a power of two
+    stack: object
+    ctrl: np.ndarray            # host (2, gh, gw) f32 control grid
+    ctrl_dev: object            # its cached device copy
+    params: np.ndarray          # (B, 11) f32 kernel params
+    params64: np.ndarray        # the same in f64 (footprints, pages)
+    step: int                   # control-grid step, validated
+    skey: tuple                 # scene serials + (B,): content identity
+    win: Optional[Tuple[int, int]]      # gather window, None = whole
+    win0: Optional[np.ndarray]  # its origin: (2,), or (B, 2) unstacked
+    scenes: list                # the real granules' DeviceScenes
 
 
 class WarpExecutor:
@@ -251,17 +261,10 @@ class WarpExecutor:
         # pressure / multi-CRS)
         self.paged_engaged = 0
         self.paged_declined = 0
-        from .batcher import RenderBatcher
-        self._batcher = RenderBatcher()
-        # a device RESOURCE_EXHAUSTED shrinks the coalesce knee before
-        # the guard's one-shot retry (docs/RESILIENCE.md)
-        from ..device_guard import register_oom_hook
-        register_oom_hook(self._batcher.note_oom)
 
     def _note_win(self, win) -> None:
         """Engagement telemetry, recorded at the dispatches that
-        actually pass ``win`` to a kernel (the batcher branch drops the
-        window and must not count as engaged)."""
+        actually pass ``win`` to a kernel."""
         if not _window_mode():
             return
         with self._lock:
@@ -530,6 +533,69 @@ class WarpExecutor:
         return combine_scored(canvs, bests)
 
 
+    def _choose_leg(self, group: "SceneGroup", n_pad: int,
+                    lane_union: bool = False):
+        """Which leg serves one scene group, from what the code can
+        observe: ("spmd", the mesh dispatcher) under GSKY_SPMD compat
+        routing; ("wave" | "paged", (pool, tables, params16)) where the
+        paged kernels run (interpret mode only today: Mosaic refuses
+        their gather) and the page pool takes the group, a wave where
+        the tick scheduler is on; else ("bucketed", None), the leg a
+        TPU takes.  The ONE place the tile legs consult
+        `compat_spmd()`, `paged_enabled()` and `waves_enabled()`."""
+        spmd = compat_spmd()
+        if spmd is not None:
+            return "spmd", spmd
+        if paged_enabled():
+            made_p = self._paged_from_group(group, n_pad, lane_union)
+            self._note_paged(made_p is not None)
+            if made_p is not None:
+                from .waves import waves_enabled
+                return ("wave" if waves_enabled() else "paged"), made_p
+        return "bucketed", None
+
+    def _run_leg(self, leg: str, how, group: "SceneGroup", name: str,
+                 finish, bucketed, spmd=None, wave=None, paged=None):
+        """The spmd / wave / paged / bucketed skeleton, written once:
+        counts ``name`` + the leg's suffix and runs the caller's thunk
+        for `_choose_leg`'s answer under that leg's guard label.  The
+        device result of one tile goes through ``finish``; a wave's
+        comes back finished, from the host.
+
+        bucketed()                          the per-call XLA/raced form
+        spmd(mesh)                          the mesh-owned form
+        wave(pool, tables, params16, percall)
+            enqueue to the tick scheduler; ``percall`` is the guarded
+            bucketed form, this request alone (incident failover)
+        paged(parr, tables (1, T, S), params16)
+            the per-call paged form over the locked pool array"""
+        from .. import device_guard
+        if leg == "spmd":
+            self._count(name + "_spmd", (group.stack.shape, group.win))
+            self._note_win(group.win)
+            return finish(spmd(how))
+        if leg == "bucketed":
+            self._count(name, (group.stack.shape, group.win))
+            self._note_win(group.win)
+            return finish(device_guard.run("dispatch.bucketed", bucketed))
+        pool, tables, params16 = how
+        if leg == "wave":
+            self._count(name + "_wave", tables.shape)
+            return wave(pool, tables, params16,
+                        lambda: device_guard.run("dispatch.bucketed",
+                                                 bucketed))
+        self._count(name + "_paged", tables.shape)
+
+        def _dispatch():
+            with pool.locked_pool() as parr:
+                return paged(parr, jnp.asarray(tables[None]),
+                             jnp.asarray(params16))
+
+        try:
+            return finish(device_guard.run("dispatch.paged", _dispatch))
+        finally:
+            pool.unpin(tables)
+
     def warp_mosaic_scenes(self, granules, ns_ids: Sequence[int],
                            prios: Sequence[float], dst_gt: GeoTransform,
                            dst_crs: CRS, height: int, width: int,
@@ -548,108 +614,62 @@ class WarpExecutor:
         if groups is None:
             return None
         n_pad = _bucket_pow2(n_ns)
-        if len(groups) == 1:
-            stack, _, params, step, _, ctrl_dev, win, win0, *_ = groups[0]
-            spmd = compat_spmd()
-            if spmd is not None:
-                # mesh path (GSKY_SPMD=1 compat routing): granule axis
-                # over `granule`, width over `x` — the mesh-owned
-                # fused mosaic on 1..N chips (SURVEY §2.8 P5/P6)
-                self._count("scene_mosaic_spmd", (stack.shape, win))
-                self._note_win(win)
-                canv, best = spmd.mosaic_scored(
-                    stack, ctrl_dev, params, method, n_pad,
-                    (height, width), step, win=win, win0=win0)
-                return canv, best > -jnp.inf
-            if paged_enabled():
-                made_p = self._paged_from_group(groups[0], n_pad)
-                if made_p is not None:
-                    pool, tables, params16, _ = made_p
-                    self._note_paged(True)
-                    from .waves import default_waves, waves_enabled
-                    if waves_enabled():
-                        # wave path: enqueue to the tick scheduler —
-                        # this mosaic shares ONE stacked paged program
-                        # with whatever else the wave carries
-                        self._count("scene_mosaic_wave", tables.shape)
-                        ctrl_host = groups[0][1]
-                        from .. import device_guard
 
-                        def _percall():
-                            # incident failover: this request alone,
-                            # through the bucketed per-call leg
-                            c, b = device_guard.run(
-                                "dispatch.bucketed",
-                                lambda: warp_scored_raced(
-                                    stack, ctrl_dev,
-                                    jnp.asarray(params), method,
-                                    n_pad, (height, width), step,
-                                    win=win,
-                                    win0_dev=_dev_win0(win0)))
-                            return (np.asarray(c),
-                                    np.asarray(b) > -np.inf)
+        def scored(g):
+            return warp_scored_raced(
+                g.stack, g.ctrl_dev, jnp.asarray(g.params), method,
+                n_pad, (height, width), g.step, win=g.win,
+                win0_dev=_dev_win0(g.win0))
 
-                        c, v = default_waves().warp_scored(
-                            pool, tables, params16, ctrl_host,
-                            (method, n_pad, (height, width), step),
-                            (stack, params, win, win0), _percall,
-                            serials=groups[0][4])
-                        return jnp.asarray(c), jnp.asarray(v)
-                    self._count("scene_mosaic_paged", tables.shape)
-                    from ..ops.paged import warp_scored_paged_raced
+        if len(groups) > 1:
+            # multi-CRS granule set (e.g. scenes across UTM zones): one
+            # scored dispatch per source-CRS group, then a per-pixel
+            # priority combine — newest-wins survives the grouping
+            # because each partial carries its winners' priorities
+            self._count("scene_mosaic_multicrs", len(groups))
+            for g in groups:
+                self._note_win(g.win)
+            parts = [scored(g) for g in groups]
+            return combine_scored(jnp.stack([p[0] for p in parts]),
+                                  jnp.stack([p[1] for p in parts]))
+        g = groups[0]
+        statics = (method, n_pad, (height, width), g.step)
 
-                    def _xla():
-                        from ..ops.warp import warp_scenes_ctrl_scored
-                        c, b = warp_scenes_ctrl_scored(
-                            stack, ctrl_dev, jnp.asarray(params),
-                            method, n_pad, (height, width), step,
-                            win=win, win0=_dev_win0(win0))
-                        return c[None], b[None]
+        def wave(pool, tables, params16, percall):
+            def host():
+                c, b = percall()
+                return np.asarray(c), np.asarray(b) > -np.inf
 
-                    from .. import device_guard
+            from .waves import default_waves
+            c, v = default_waves().warp_scored(
+                pool, tables, params16, g.ctrl, statics,
+                (g.stack, g.params, g.win, g.win0), host,
+                serials=g.skey)
+            return jnp.asarray(c), jnp.asarray(v)
 
-                    def _dispatch():
-                        with pool.locked_pool() as parr:
-                            return warp_scored_paged_raced(
-                                parr, jnp.asarray(tables[None]),
-                                jnp.asarray(params16), ctrl_dev[None],
-                                method, n_pad, (height, width), step,
-                                _xla)
+        def paged(parr, tables, params16):
+            from ..ops.paged import warp_scored_paged_raced
+            from ..ops.warp import warp_scenes_ctrl_scored
 
-                    try:
-                        canvs, bests = device_guard.run(
-                            "dispatch.paged", _dispatch)
-                    finally:
-                        pool.unpin(tables)
-                    return canvs[0], bests[0] > -jnp.inf
-                self._note_paged(False)
-            self._count("scene_mosaic", (stack.shape, win))
-            self._note_win(win)
-            from .. import device_guard
-            canv, best = device_guard.run(
-                "dispatch.bucketed",
-                lambda: warp_scored_raced(stack, ctrl_dev,
-                                          jnp.asarray(params), method,
-                                          n_pad, (height, width), step,
-                                          win=win,
-                                          win0_dev=_dev_win0(win0)))
-            return canv, best > -jnp.inf
-        # multi-CRS granule set (e.g. scenes across UTM zones): one
-        # scored dispatch per source-CRS group, then a per-pixel
-        # priority combine — newest-wins survives the grouping because
-        # each partial carries its winners' priorities
-        self._count("scene_mosaic_multicrs", len(groups))
-        for g in groups:
-            self._note_win(g[6])
-        parts = [warp_scored_raced(
-                    stack, ctrl_dev, jnp.asarray(params),
-                    method, n_pad, (height, width), step,
-                    win=win, win0_dev=_dev_win0(win0))
-                 for stack, _, params, step, _, ctrl_dev, win,
-                 win0, *_ in groups]
-        canvs = jnp.stack([p[0] for p in parts])
-        bests = jnp.stack([p[1] for p in parts])
-        return combine_scored(canvs, bests)
+            def _xla():
+                c, b = warp_scenes_ctrl_scored(
+                    g.stack, g.ctrl_dev, jnp.asarray(g.params),
+                    *statics, win=g.win, win0=_dev_win0(g.win0))
+                return c[None], b[None]
+
+            canvs, bests = warp_scored_paged_raced(
+                parr, tables, params16, g.ctrl_dev[None], *statics,
+                _xla)
+            return canvs[0], bests[0]
+
+        return self._run_leg(
+            *self._choose_leg(g, n_pad), g, "scene_mosaic",
+            lambda cb: (cb[0], cb[1] > -jnp.inf),
+            lambda: scored(g),
+            spmd=lambda mesh: mesh.mosaic_scored(
+                g.stack, g.ctrl_dev, g.params, *statics,
+                win=g.win, win0=g.win0),
+            wave=wave, paged=paged)
 
     def render_byte_scenes(self, granules, ns_ids: Sequence[int],
                            prios: Sequence[float], dst_gt: GeoTransform,
@@ -659,108 +679,45 @@ class WarpExecutor:
                            clip: float = 0.0, colour_scale: int = 0,
                            auto: bool = True, cache=None):
         """Whole-tile fast path: cached scenes -> PNG-ready uint8
-        composite, coalesced with concurrent companion requests into one
-        vmapped dispatch (`pipeline.batcher.RenderBatcher`).  Returns a
-        host uint8 (H, W) array or None (fallback)."""
-        made = self._scene_inputs(granules, ns_ids, prios, dst_gt,
-                                  dst_crs, height, width, cache)
-        if made is None:
+        composite in one dispatch.  Returns a uint8 (H, W) array (host
+        from a wave, else device with its readback started) or None
+        (fallback)."""
+        g = self._scene_inputs(granules, ns_ids, prios, dst_gt,
+                               dst_crs, height, width, cache)
+        if g is None:
             return None
-        stack, ctrl, params, step, skey, ctrl_dev, win, win0, win_raw, \
-            *_ = made
         sp = np.array([offset, scale, clip], np.float32)
-        statics = (method, _bucket_pow2(n_ns), (height, width), step,
+        statics = (method, _bucket_pow2(n_ns), (height, width), g.step,
                    auto, colour_scale)
-        spmd = compat_spmd()
-        if spmd is not None:
-            self._count("render_byte_spmd", (stack.shape, win))
-            self._note_win(win)
-            return _prefetch(spmd.render_composite(
-                stack, ctrl_dev, params, sp, *statics,
-                win=win, win0=win0))
-        from .batcher import batching_enabled
-        if paged_enabled():
-            made_p = self._paged_from_group(made, statics[1])
-            if made_p is not None:
-                pool, tables, params16, real_pages = made_p
-                self._note_paged(True)
-                from .waves import default_waves, waves_enabled
-                if waves_enabled():
-                    # wave path: every eligible request of the tick —
-                    # tiles of ANY shape, plus drills — shares the
-                    # dispatch; checked before batching because wave
-                    # ticks subsume the batcher's flush entirely
-                    self._count("render_byte_wave", tables.shape)
-                    from .. import device_guard
 
-                    def _percall():
-                        out = device_guard.run(
-                            "dispatch.bucketed",
-                            lambda: render_byte_raced(
-                                stack, ctrl_dev, jnp.asarray(params),
-                                jnp.asarray(sp), *statics, win=win,
-                                win0_dev=_dev_win0(win0)))
-                        return np.asarray(out)
+        def wave(pool, tables, params16, percall):
+            from .waves import default_waves
+            return default_waves().render_byte(
+                pool, tables, params16, g.ctrl, sp, statics,
+                (g.stack, g.params, g.win, g.win0),
+                lambda: np.asarray(percall()), serials=g.skey)
 
-                    return default_waves().render_byte(
-                        pool, tables, params16, ctrl, sp, statics,
-                        (stack, params, win, win0), _percall,
-                        serials=skey)
-                if batching_enabled():
-                    # the paged batch key carries NO stack/shape
-                    # identity: tiles over different scene sets and
-                    # window sizes coalesce into one ragged dispatch
-                    self._count("render_byte_paged_batched",
-                                tables.shape)
-                    fallback = (stack, params, win, win0)
-                    return self._batcher.render_paged(
-                        ("paged",) + statics, pool, tables, params16,
-                        ctrl, sp, statics, real_pages, fallback)
-                self._count("render_byte_paged", tables.shape)
-                from ..ops.paged import render_byte_paged_raced
+        def paged(parr, tables, params16):
+            from ..ops.paged import render_byte_paged_raced
+            from ..ops.warp import render_scenes_ctrl
+            return render_byte_paged_raced(
+                parr, tables, params16, g.ctrl_dev[None],
+                jnp.asarray(sp[None]), *statics,
+                lambda: render_scenes_ctrl(
+                    g.stack, g.ctrl_dev, jnp.asarray(g.params),
+                    jnp.asarray(sp), *statics, win=g.win,
+                    win0=_dev_win0(g.win0))[None])[0]
 
-                def _xla():
-                    from ..ops.warp import render_scenes_ctrl
-                    return render_scenes_ctrl(
-                        stack, ctrl_dev, jnp.asarray(params),
-                        jnp.asarray(sp), *statics, win=win,
-                        win0=_dev_win0(win0))[None]
-
-                from .. import device_guard
-
-                def _dispatch():
-                    with pool.locked_pool() as parr:
-                        return render_byte_paged_raced(
-                            parr, jnp.asarray(tables[None]),
-                            jnp.asarray(params16), ctrl_dev[None],
-                            jnp.asarray(sp[None]), *statics, _xla)
-
-                try:
-                    out = device_guard.run("dispatch.paged", _dispatch)
-                finally:
-                    pool.unpin(tables)
-                return _prefetch(out[0])
-            self._note_paged(False)
-        if batching_enabled():
-            # batched tiles share one dispatch; the batcher unions the
-            # per-tile windows at flush (its win_batches/full_batches
-            # counters carry the engagement telemetry for this path)
-            self._count("render_byte_batched", stack.shape)
-            # scene-serial key (not id()): address reuse after eviction
-            # must never coalesce a request into another stack's batch
-            key = skey + statics
-            return self._batcher.render(key, stack, ctrl, params, sp,
-                                        statics, win_raw=win_raw)
-        self._count("render_byte", (stack.shape, win))
-        self._note_win(win)
-        from .. import device_guard
-        out = device_guard.run(
-            "dispatch.bucketed",
-            lambda: render_byte_raced(stack, ctrl_dev,
-                                      jnp.asarray(params),
-                                      jnp.asarray(sp), *statics,
-                                      win=win, win0_dev=_dev_win0(win0)))
-        return _prefetch(out)
+        return self._run_leg(
+            *self._choose_leg(g, statics[1]), g, "render_byte", _prefetch,
+            lambda: render_byte_raced(
+                g.stack, g.ctrl_dev, jnp.asarray(g.params),
+                jnp.asarray(sp), *statics, win=g.win,
+                win0_dev=_dev_win0(g.win0)),
+            spmd=lambda mesh: mesh.render_composite(
+                g.stack, g.ctrl_dev, g.params, sp, *statics,
+                win=g.win, win0=g.win0),
+            wave=wave, paged=paged)
 
     def render_expr_byte(self, granules, ns_ids: Sequence[int],
                          prios: Sequence[float], dst_gt: GeoTransform,
@@ -779,27 +736,19 @@ class WarpExecutor:
         is mosaic slot i); ``fp`` is the `ops.expr.ExprFingerprint`.
         Returns a uint8 (H, W) array or None — the caller then runs
         the unfused `evaluate_expressions` leg (multi-CRS granule sets,
-        page budget, SPMD compat mode)."""
-        made = self._scene_inputs(granules, ns_ids, prios, dst_gt,
-                                  dst_crs, height, width, cache)
-        if made is None:
-            return None
-        stack, ctrl, params, step, skey, ctrl_dev, win, win0, win_raw, \
-            *_ = made
-        if compat_spmd() is not None:
-            return None     # mesh compat routing has no expr epilogue
-        if not paged_enabled():
+        page budget, SPMD compat mode: the epilogue exists in paged
+        form only)."""
+        g = self._scene_inputs(granules, ns_ids, prios, dst_gt,
+                               dst_crs, height, width, cache)
+        if g is None:
             return None
         n_pad = _bucket_pow2(n_slots)
-        made_p = self._paged_from_group(made, n_pad, lane_union=True)
-        if made_p is None:
-            self._note_paged(False)
+        leg, how = self._choose_leg(g, n_pad, lane_union=True)
+        if leg in ("spmd", "bucketed"):
             return None
-        pool, tables, params16, real_pages = made_p
-        self._note_paged(True)
         sp = np.array([offset, scale, clip], np.float32)
         consts = fp.const_array()
-        statics = (method, n_pad, (height, width), step, auto,
+        statics = (method, n_pad, (height, width), g.step, auto,
                    colour_scale, fp.key)
         from ..ops.paged import expr_epilogue, note_expr_fused
 
@@ -810,48 +759,34 @@ class WarpExecutor:
             from ..ops.scale import scale_to_byte
             from ..ops.warp import warp_scenes_ctrl_scored
             c, b = warp_scenes_ctrl_scored(
-                stack, ctrl_dev, jnp.asarray(params), method, n_pad,
-                (height, width), step, win=win, win0=_dev_win0(win0))
+                g.stack, g.ctrl_dev, jnp.asarray(g.params), method,
+                n_pad, (height, width), g.step, win=g.win,
+                win0=_dev_win0(g.win0))
             plane, ok = expr_epilogue(c[None], b[None], fp.key,
                                       jnp.asarray(consts[None]))
             return scale_to_byte(plane, ok, offset, scale, clip,
                                  colour_scale, auto)
 
-        from .waves import default_waves, waves_enabled
-        if waves_enabled():
-            # wave path: expression lanes coalesce with every other
-            # lane of the tick that shares (statics, fingerprint, pool)
-            self._count("render_expr_wave", tables.shape)
-            note_expr_fused("wave")
-            from .. import device_guard
-
-            def _percall():
-                out = device_guard.run("dispatch.bucketed",
-                                       _unfused_xla)
-                return np.asarray(out[0])
-
+        def wave(pool, tables, params16, percall):
+            # expression lanes coalesce with every other lane of the
+            # tick that shares (statics, fingerprint, pool)
+            from .waves import default_waves
             return default_waves().render_expr(
-                pool, tables, params16, ctrl, sp, consts, statics,
-                (stack, params, win, win0), _percall, serials=skey)
-        self._count("render_expr_paged", tables.shape)
-        note_expr_fused("percall")
-        from ..ops.paged import render_expr_paged_raced
-        from .. import device_guard
+                pool, tables, params16, g.ctrl, sp, consts, statics,
+                (g.stack, g.params, g.win, g.win0),
+                lambda: np.asarray(percall()), serials=g.skey)
 
-        def _dispatch():
-            with pool.locked_pool() as parr:
-                return render_expr_paged_raced(
-                    parr, jnp.asarray(tables[None]),
-                    jnp.asarray(params16), ctrl_dev[None],
-                    jnp.asarray(sp[None]), jnp.asarray(consts[None]),
-                    method, n_pad, (height, width), step, auto,
-                    colour_scale, fp.key, fp.hash, _unfused_xla)
+        def paged(parr, tables, params16):
+            from ..ops.paged import render_expr_paged_raced
+            return render_expr_paged_raced(
+                parr, tables, params16, g.ctrl_dev[None],
+                jnp.asarray(sp[None]), jnp.asarray(consts[None]),
+                *statics, fp.hash, _unfused_xla)[0]
 
-        try:
-            out = device_guard.run("dispatch.paged", _dispatch)
-        finally:
-            pool.unpin(tables)
-        return _prefetch(out[0])
+        note_expr_fused("wave" if leg == "wave" else "percall")
+        return self._run_leg(leg, how, g, "render_expr", _prefetch,
+                             lambda: _unfused_xla()[0],
+                             wave=wave, paged=paged)
 
     def render_bands_byte(self, granules, ns_ids: Sequence[int],
                           prios: Sequence[float], dst_gt: GeoTransform,
@@ -867,20 +802,21 @@ class WarpExecutor:
         scenes as the scene cache holds them and stacks only their
         gather windows, so no copy of a raster is made or kept.
         Returns a device uint8 (n_out, H, W) array or None (fallback)."""
-        made = self._scene_inputs(granules, ns_ids, prios, dst_gt,
-                                  dst_crs, height, width, cache,
-                                  stacked=False)
-        if made is None:
+        g = self._scene_inputs(granules, ns_ids, prios, dst_gt,
+                               dst_crs, height, width, cache,
+                               stacked=False)
+        if g is None:
             return None
-        devs, _, params, step, _, ctrl_dev, win, win0, *_ = made
-        self._count("render_bands", ((len(devs),) + devs[0].shape, win))
-        self._note_win(win)
+        devs = g.stack
+        self._count("render_bands",
+                    ((len(devs),) + devs[0].shape, g.win))
+        self._note_win(g.win)
         sp = jnp.asarray(np.array([offset, scale, clip], np.float32))
         sel = jnp.asarray(np.asarray(out_sel, np.int32))
         return _prefetch(render_scenes_bands_ctrl(
-            devs, ctrl_dev, jnp.asarray(params), sp, sel,
-            method, _bucket_pow2(n_ns), (height, width), step, auto,
-            colour_scale, win=win, win0=_dev_win0(win0)))
+            devs, g.ctrl_dev, jnp.asarray(g.params), sp, sel,
+            method, _bucket_pow2(n_ns), (height, width), g.step, auto,
+            colour_scale, win=g.win, win0=_dev_win0(g.win0)))
 
     def render_rgba_byte(self, granules, ns_ids: Sequence[int],
                          prios: Sequence[float], out_sel: Sequence[int],
@@ -971,7 +907,7 @@ class WarpExecutor:
             # window bounds from the SAME param rows the kernel consumes
             made_w = _gather_windows(params, sx - ox, sy - oy, *s0.bucket)
             if made_w is not None:
-                win, win0, _ = made_w
+                win, win0 = made_w
         from ..ops.warp import render_rgba_ctrl
         self._count("render_rgba", ((G,) + s0.bucket + (3,), win))
         self._note_win(win)
@@ -989,16 +925,15 @@ class WarpExecutor:
             else:
                 self.paged_declined += 1
 
-    def _paged_from_group(self, group, n_pad: int,
+    def _paged_from_group(self, group: "SceneGroup", n_pad: int,
                           lane_union: bool = False):
-        """Page tables + 16-wide kernel params for one scene group
-        (`_scene_groups` tuple), or None when the paged path can't
-        serve it — page budget exceeded, pool full of pinned pages, or
-        the page block over VMEM — and the caller keeps the bucketed
-        dispatch.
+        """Page tables + 16-wide kernel params for one scene group, or
+        None when the paged path can't serve it — page budget exceeded,
+        pool full of pinned pages, or the page block over VMEM — and
+        the caller keeps the bucketed dispatch.
 
-        Returns (pool, tables (T, S) int32, params16 (T, 16) f32,
-        real_pages).  Page coverage per granule comes from the SAME
+        Returns (pool, tables (T, S) int32, params16 (T, 16) f32).
+        Page coverage per granule comes from the SAME
         `_granule_bounds` margins the bucketed window uses, so both
         paths gather identical taps; table slots come back PINNED and
         the caller must `pool.unpin(tables)` once its dispatch is
@@ -1009,7 +944,7 @@ class WarpExecutor:
         coords are oob-poisoned before the rebase."""
         from ..ops.paged import page_slots, paged_vmem_ok
         from .pages import default_page_pool
-        (_, ctrl, _, _, _, _, _, _, _, gs, params64) = group
+        ctrl, gs, params64 = group.ctrl, group.scenes, group.params64
         pool = default_page_pool()
         if gs:
             # mesh per-chip placement (GSKY_MESH_PLACE=1): the group's
@@ -1064,7 +999,6 @@ class WarpExecutor:
         params16 = np.zeros((T, PAGED_PARAMS_W), np.float32)
         params16[:, :11] = params64[:, :11].astype(np.float32)
         pinned = []
-        real_pages = 0
         for k, span in enumerate(spans):
             if span is None:
                 # zero-extent row (slots 13/14 stay 0): every tap is
@@ -1079,18 +1013,17 @@ class WarpExecutor:
                 return None
             pinned.append(slots)
             tables[k, :slots.size] = slots
-            real_pages += int(slots.size)
             params16[k, 11] = i0 * pr
             params16[k, 12] = j0 * pc
             params16[k, 13] = (i1 - i0 + 1) * pr
             params16[k, 14] = (j1 - j0 + 1) * pc
             params16[k, 15] = j1 - j0 + 1
-        return pool, tables, params16, real_pages
+        return pool, tables, params16
 
     def _scene_inputs(self, granules, ns_ids, prios, dst_gt, dst_crs,
                       height, width, cache=None, stacked=True):
-        """Single-group scene inputs; None when the granule set is not
-        uniform (the byte fast paths then fall back)."""
+        """The one `SceneGroup` of a uniform granule set; None when the
+        set is not uniform (the byte fast paths then fall back)."""
         groups = self._scene_groups(granules, ns_ids, prios, dst_gt,
                                     dst_crs, height, width, cache, stacked)
         if groups is None or len(groups) != 1:
@@ -1158,12 +1091,12 @@ class WarpExecutor:
                       height, width, cache=None, stacked=True):
         """Device inputs for the fused scene kernels, grouped by
         (source CRS, bucket shape, dtype) — curvilinear granules group
-        by their geolocation arrays instead: each group gets its own
-        (stack, ctrl, params, step); multi-group sets (granules spanning
-        UTM zones, or mixing regular and curvilinear grids) combine via
-        the scored kernels.  None when any scene is uncacheable.
+        by their geolocation arrays instead: one `SceneGroup` each;
+        multi-group sets (granules spanning UTM zones, or mixing regular
+        and curvilinear grids) combine via the scored kernels.  None
+        when any scene is uncacheable.
 
-        The group's first member is one (B, bh, bw) array, a copy of its
+        The group's ``stack`` is one (B, bh, bw) array, a copy of its
         scenes that the scene cache keeps and charges to its budget
         (`SceneCache.stack`), or with ``stacked=False`` the tuple of the
         B scene arrays themselves, for a kernel that stacks only their
@@ -1217,9 +1150,9 @@ class WarpExecutor:
             # the ~2 KB ctrl grid re-uploads on every render otherwise;
             # tile servers see heavy repeats, so keep the DEVICE copy in
             # the same LRU as the host grids.  The HOST array stays the
-            # group's ctrl: the batcher np.stacks ctrl grids, and a
-            # device array there would force a sync + download per
-            # queued tile — consumers pick the device copy up by dkey
+            # group's ctrl: the wave scheduler np.stacks ctrl grids, and
+            # a device array there would force a sync + download per
+            # queued tile
             ctrl_dev = self._geo_cache_get(dkey)
             if ctrl_dev is None:
                 ctrl_dev = jnp.asarray(ctrl)
@@ -1244,19 +1177,17 @@ class WarpExecutor:
             devs = tuple(s.dev for s in gs) + (s0.dev,) * (B - len(gs))
             stack = cache.stack(skey, lambda devs=devs: jnp.stack(devs)) \
                 if stacked else devs
-            win = win0 = win_raw = None
+            win = win0 = None
             if _window_mode():
                 made_w = (_gather_window if stacked else _gather_windows)(
                     params, np.asarray(ctrl[0], np.float64),
                     np.asarray(ctrl[1], np.float64), *s0.bucket)
                 if made_w is not None:
-                    win, win0, win_raw = made_w
-            # trailing members (scenes + f64 params) feed the paged
-            # dispatch (`_paged_from_group`); consumers of the bucketed
-            # 9-prefix unpack with `*_`
-            groups.append((stack, ctrl, params.astype(np.float32), step,
-                           skey, ctrl_dev, win, win0, win_raw, gs,
-                           params))
+                    win, win0 = made_w
+            groups.append(SceneGroup(
+                stack=stack, ctrl=ctrl, ctrl_dev=ctrl_dev,
+                params=params.astype(np.float32), params64=params,
+                step=step, skey=skey, win=win, win0=win0, scenes=gs))
         return groups
 
 

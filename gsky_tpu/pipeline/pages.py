@@ -31,8 +31,7 @@ built-but-not-yet-dispatched table are never evicted (`table_for`
 returns None instead — the caller falls back to the bucketed path).
 Pins are taken by `table_for` and must be released with `unpin` after
 the dispatch is enqueued; without the rule a concurrent request could
-recycle a queued batch item's pages between enqueue-to-batcher and
-flush.
+recycle a queued wave entry's pages between enqueue and dispatch.
 """
 
 from __future__ import annotations

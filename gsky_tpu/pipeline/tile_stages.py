@@ -17,13 +17,12 @@ dispatch gate releases) while request B occupies the dispatch slot and
 request C decodes scenes — double-buffering across the request stream.
 PNG/JPEG encode runs on `io/png.py`'s sized pool, off the event loop.
 
-Byte identity with the serial path is by construction: the stages call
-the SAME prep/dispatch halves (`TilePipeline.composite_prep`/
-`composite_dispatch`, `_bands_prep`/`_rgba_try`/`_bands_dispatch`) the
-serial fast path runs, in the same order, with the same inputs — only
-the thread scheduling and readback timing differ (asserted in
-tests/test_tile_pipeline.py).  `GSKY_TILE_PIPELINE=0` is the escape
-hatch, read per request like the export engine's GSKY_EXPORT_PIPELINE.
+The stages call the prep/dispatch halves of `TilePipeline`'s own
+render methods (`composite_prep`/`composite_dispatch`, `_bands_prep`/
+`_rgba_try`/`_bands_dispatch`); what `render_staged` declines takes the
+modular route (`OWSServer._render_with_fusion`), which shares neither
+with it and is what tests/test_tile_pipeline.py holds the staged
+answer to.
 
 Per-request stage spans land in the ``spans`` dict (seconds per stage +
 queue high-water marks) and are folded into /debug's ``tile_stages``
@@ -42,12 +41,6 @@ import numpy as np
 
 from ..obs import span as obs_span
 from ..resilience import check_cancel
-
-
-def tile_pipeline_enabled() -> bool:
-    """GSKY_TILE_PIPELINE=0 escape hatch — read per request so an
-    operator can flip a live server without restart."""
-    return os.environ.get("GSKY_TILE_PIPELINE", "1") != "0"
 
 
 def _env_int(name: str, default: int, lo: int = 1, hi: int = 64) -> int:
@@ -147,8 +140,8 @@ def _decode_stage(pipe, req, granules, spans: Dict) -> None:
     """Warm every distinct scene into the device cache under the decode
     gate.  Purely a prefetch: failures are swallowed here because the
     dispatch stage re-resolves each scene through the same cache and
-    surfaces (or degrades) errors exactly as the serial path does —
-    identical outcomes, just earlier, bounded, and overlapped."""
+    surfaces (or degrades) errors itself — identical outcomes, just
+    earlier, bounded, and overlapped."""
     from .export import _scene_key
     gate = _gate("decode")
     check_cancel("decode")
@@ -179,7 +172,6 @@ def _dispatch_stage(dispatch, spans: Dict):
     before returning, so by the time the gate releases the
     device->host transfer is already in flight — the next request's
     dispatch overlaps this one's readback."""
-    from .batcher import batching_enabled
     from .waves import waves_enabled
     from ..ingest import stats as ingest_stats
     check_cancel("dispatch")
@@ -195,14 +187,12 @@ def _dispatch_stage(dispatch, spans: Dict):
             except Exception:
                 compile_count, c0 = None, 0
             try:
-                if batching_enabled() or waves_enabled():
-                    # the batcher/wave scheduler NEEDS concurrent
-                    # arrivals to coalesce into one dispatch; a narrow
-                    # gate here would serialize them and defeat it, so
-                    # both modes keep their own admission (wave size +
-                    # brownout clamp for waves)
-                    sp.set(batched=batching_enabled(),
-                           waved=waves_enabled())
+                if waves_enabled():
+                    # the wave scheduler NEEDS concurrent arrivals to
+                    # coalesce into one dispatch; a narrow gate here
+                    # would serialize them and defeat it, so it keeps
+                    # its own admission (wave size + brownout clamp)
+                    sp.set(waved=True)
                     return dispatch()
                 with _gate("dispatch").enter(spans, "dispatch_queue_max"):
                     # re-check AFTER the gate wait: the client may have
@@ -246,8 +236,7 @@ def render_staged(pipe, req, n_exprs: int,
     """The staged GetMap fast path, run inside the request's worker
     thread.  Returns (kind, host_array) with kind in {"composite",
     "rgba", "planes"}, or None when the request doesn't qualify for the
-    fused path — callers then fall back to the modular render exactly
-    like the serial fast path does.
+    fused path — callers then fall back to the modular render.
 
     Stage structure per request:
       plan      qualification + namespace/selection resolution (host)

@@ -18,8 +18,8 @@ invocation per result kind:
   drains up to ``GSKY_WAVE_MAX`` entries (clamped by the brownout
   level under pressure), drops cancelled entries at assembly, groups
   by (kind, statics, pool), runs the dataflow planner
-  (`autoplan.plan_wave_group`), stacks page tables and param rows
-  exactly like `RenderBatcher._execute_paged` — padding rows carrying
+  (`autoplan.plan_wave_group`), stacks page tables and param rows on
+  a pow2 leading dim — padding rows carrying
   ns_id -1 so every real row is bit-independent of its wave
   companions — and uploads the stacks into a persistent
   double-buffered input `_StagingRing` (two donated staging slots per
@@ -362,7 +362,7 @@ class WaveScheduler:
 
     def _effective_max(self) -> int:
         """Brownout/pressure clamp: a degraded device gets smaller
-        waves (same shape as the batcher's OOM knee ratchet)."""
+        waves."""
         m = self._wave_max()
         try:
             from ..resilience.pressure import brownout_level
@@ -1297,12 +1297,6 @@ def default_waves() -> WaveScheduler:
         with _default_lock:
             if _default is None:
                 _default = WaveScheduler()
-    return _default
-
-
-def active_waves() -> Optional[WaveScheduler]:
-    """The live scheduler or None — never instantiates (collectors and
-    the batcher's delegation probe must not boot threads)."""
     return _default
 
 

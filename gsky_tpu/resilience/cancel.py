@@ -12,7 +12,6 @@ the token object is shared), and every expensive stage *checks* it:
     gateway admission queue   (serving/admission.py)
     tile stage gates          (pipeline/tile_stages.py)
     export planner loops      (pipeline/export.py, via on_cancel)
-    batcher flush waits       (pipeline/batcher.py)
     worker RPCs               (worker/client.py, gRPC future.cancel)
     worker-side warp          (worker/server.py, ctx.is_active)
     encode pool jobs          (io/png.py)

@@ -55,7 +55,7 @@ from ..pipeline.export import ExportPipeline
 from ..pipeline.export import pipeline_enabled as export_pipeline_enabled
 from ..pipeline.extent import compute_reprojection_extent
 from ..pipeline.feature_info import get_feature_info
-from ..pipeline.tile_stages import render_staged, tile_pipeline_enabled
+from ..pipeline.tile_stages import render_staged
 from ..pipeline.types import AxisSelector, MaskSpec
 from .. import device_guard, obs
 from ..resilience import (BackendUnavailable, Deadline, DeadlineExceeded,
@@ -417,17 +417,10 @@ class OWSServer:
                 "stride_cache": len(ex._stride_cache),
                 "dispatches": dict(ex.bucket_stats),
                 # gather-window engagement (GSKY_WARP_WINDOW): groups
-                # that got a footprint window vs declined, + batched
-                # flushes with/without a union window
+                # that got a footprint window vs declined
                 "gather_window": {
                     "engaged": ex.win_engaged,
-                    "declined": ex.win_declined,
-                    "batches_windowed": ex._batcher.win_batches,
-                    "batches_full": ex._batcher.full_batches,
-                    # adaptive coalesce cap + the per-padded-size
-                    # per-tile latency EMAs that set it, plus the
-                    # win/full/paged flush counters and padding bill
-                    **ex._batcher.stats()},
+                    "declined": ex.win_declined},
                 # ragged paged rendering (GSKY_PAGED, docs/KERNELS.md):
                 # dispatches served from the page pool vs declined back
                 # to buckets, and the pool's residency stats
@@ -1084,20 +1077,19 @@ class OWSServer:
         scaled = None
         n_exprs = len(req.band_exprs.expr_names)
         # per-request span record of the staged tile path; stays None
-        # on the serial path (GSKY_TILE_PIPELINE=0) and on renders that
-        # fell back to the modular pipeline
+        # on renders that fell back to the modular pipeline
         spans = None
         # one deadline budget for the whole render: every stage's
         # wait_for AND every downstream timeout (MAS HTTP, worker gRPC)
         # draws from what is LEFT of wms_timeout, not a fresh allowance
         with deadline_scope(Deadline(lay.wms_timeout)) as dl:
-            if not lay.input_layers and 1 <= n_exprs <= 4 \
-                    and tile_pipeline_enabled():
-                # staged fast path: the same fused prep/dispatch halves
-                # as the serial ladder below, decomposed into bounded
-                # plan/index/decode/dispatch/readback stages so
-                # concurrent requests overlap (tile N+1's output is in
-                # flight while tile N encodes) — byte-identical output
+            if not lay.input_layers and 1 <= n_exprs <= 4:
+                # staged fast path: one fused dispatch per tile (the
+                # modular path below costs several device round trips
+                # per request), decomposed into bounded plan/index/
+                # decode/dispatch/readback stages so concurrent
+                # requests overlap (tile N+1's output is in flight
+                # while tile N encodes)
                 stats: Dict[str, int] = {}
                 made_spans: Dict = {}
                 made = await asyncio.wait_for(
@@ -1139,74 +1131,6 @@ class OWSServer:
                             encode_rgba_png, rgba,
                             compress_level=_png_level(lay, style),
                             spans=spans))
-            elif not lay.input_layers and 1 <= n_exprs <= 4:
-                # single-dispatch SERIAL fast path (the escape hatch):
-                # fused warp+mosaic+scale on device, one pull (the
-                # modular path below costs several device round trips
-                # per request); single-band styles composite, RGB
-                # styles emit per-band planes
-                stats = {}
-                if n_exprs == 1:
-                    sb = await asyncio.wait_for(
-                        asyncio.to_thread(pipe.render_composite_byte, req,
-                                          style.offset_value,
-                                          style.scale_value,
-                                          style.clip_value,
-                                          style.colour_scale, auto, stats),
-                        timeout=dl.remaining())
-                elif n_exprs == 3:
-                    # channel-packed single-scene RGB kernel first
-                    # (indices computed once for all bands, one RGBA
-                    # pull), then the general per-band path
-                    sb = await asyncio.wait_for(
-                        asyncio.to_thread(self._render_rgb, pipe, req,
-                                          style, auto, stats),
-                        timeout=dl.remaining())
-                    if sb is not None:
-                        self.metrics.record_rgb_route(sb[0])
-                else:
-                    sb = await asyncio.wait_for(
-                        asyncio.to_thread(pipe.render_bands_byte, req,
-                                          style.offset_value,
-                                          style.scale_value,
-                                          style.clip_value,
-                                          style.colour_scale, auto, stats),
-                        timeout=dl.remaining())
-                if sb is not None:
-                    td = time.time()
-                    rgba = None
-                    if isinstance(sb, tuple):  # tagged RGB-ladder result
-                        kind, dev = sb
-                        # the one device pull, under the device guard
-                        # (hang watchdog + integrity probe)
-                        arr = device_guard.guarded_readback(
-                            "tile.readback", lambda dev=dev:
-                            np.asarray(dev))
-                        if kind == "rgba":
-                            rgba = arr          # (H, W, 4)
-                            scaled = [arr[..., 0], arr[..., 1],
-                                      arr[..., 2]]
-                        else:                   # "planes": (3, H, W)
-                            scaled = list(arr)
-                    else:
-                        arr = device_guard.guarded_readback(
-                            "tile.readback", lambda sb=sb:
-                            np.asarray(sb))  # the one device pull
-                        scaled = [arr] if arr.ndim == 2 else list(arr)
-                    collector.info["device"]["duration"] = \
-                        int((time.time() - td) * 1e9)
-                    collector.info["device"]["platform"] = _jax_platform()
-                    collector.info["indexer"]["num_granules"] = \
-                        stats.get("granules", 0)
-                    collector.info["indexer"]["num_files"] = \
-                        stats.get("files", 0)
-                    if rgba is not None and \
-                            p.format.lower() not in ("image/jpeg",
-                                                     "image/jpg"):
-                        collector.info["rpc"]["duration"] = \
-                            int((time.time() - t0) * 1e9)
-                        return _png(encode_rgba_png(
-                            rgba, compress_level=_png_level(lay, style)))
             if scaled is None:
                 res = await asyncio.wait_for(
                     asyncio.to_thread(_render_with_fusion, pipe, req, lay,
@@ -1434,29 +1358,15 @@ class OWSServer:
         return frames
 
     async def _encode_tile(self, fn, *args, spans=None, **kw):
-        """PNG/JPEG encode off the event loop on io/png's sized pool
-        when the staged tile path is on; inline under the
-        GSKY_TILE_PIPELINE=0 escape hatch (byte-identical either way —
-        same codec, same arguments).  A staged render's completed span
-        record rides along and is folded into the /debug `tile_stages`
-        aggregates once the encode lands."""
-        if not tile_pipeline_enabled():
-            with obs.span("encode", inline=True):
-                return fn(*args, **kw)
+        """PNG/JPEG encode off the event loop on io/png's sized pool.
+        A staged render's completed span record rides along and is
+        folded into the /debug `tile_stages` aggregates once the
+        encode lands."""
         try:
             return await encode_async(fn, *args, spans=spans, **kw)
         finally:
             if spans is not None:
                 self.metrics.record_tile(spans)
-
-    @staticmethod
-    def _render_rgb(pipe, req, style, auto: bool, stats):
-        """RGB fast-path ladder (one index pass): channel-packed RGBA
-        kernel, then the per-band planes kernel.  Returns
-        ("rgba", dev (H,W,4)) / ("planes", dev (3,H,W)) / None."""
-        return pipe.render_rgb_auto(req, style.offset_value,
-                                    style.scale_value, style.clip_value,
-                                    style.colour_scale, auto, stats)
 
     async def _feature_info(self, cfg: Config, p):
         if not p.layers:

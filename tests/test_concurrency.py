@@ -135,81 +135,6 @@ def test_mas_store_concurrent_queries(archive):
     assert not errors, errors
 
 
-def test_batched_render_matches_unbatched(archive, monkeypatch):
-    """GSKY_RENDER_BATCH=1 coalesces concurrent fused renders into one
-    vmapped dispatch; results must equal the unbatched path."""
-    pipe = TilePipeline(MASClient(archive["store"]))
-    reqs = [_req(archive, s) for s in (0.0, 0.005, 0.01, 0.015)]
-    plain = [np.asarray(pipe.render_composite_byte(r, auto=True))
-             for r in reqs]
-    assert all(p is not None for p in plain)
-
-    monkeypatch.setenv("GSKY_RENDER_BATCH", "1")
-    out = [None] * 8
-
-    def worker(i):
-        out[i] = np.asarray(
-            pipe.render_composite_byte(reqs[i % len(reqs)], auto=True))
-
-    threads = [threading.Thread(target=worker, args=(i,))
-               for i in range(8)]
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join(120)
-    for i, o in enumerate(out):
-        assert o is not None
-        np.testing.assert_array_equal(o, plain[i % len(reqs)])
-
-
-def test_batched_render_union_window(tmp_path_factory, monkeypatch):
-    """Batching + GSKY_WARP_WINDOW: the flush unions the per-tile
-    footprint windows into one batch-wide slice — results must equal
-    the unbatched unwindowed path, and the union must really engage."""
-    from gsky_tpu.pipeline.executor import WarpExecutor
-
-    arch = make_archive(str(tmp_path_factory.mktemp("bw")), scenes=2,
-                        size=512)
-    pipe = TilePipeline(MASClient(arch["store"]),
-                        executor=WarpExecutor())
-    # small tiles + small shifts: each footprint AND their union bucket
-    # to 256 < the 512-px scenes, so the union window must engage
-    shifts = [0.0, 0.005, 0.01, 0.015]
-
-    def req(s):
-        bb = transform_bbox(
-            BBox(148.02 + s, -35.27, 148.07 + s, -35.22),
-            EPSG4326, EPSG3857)
-        return GeoTileRequest(collection=arch["root"], bands=[NS],
-                              bbox=bb, crs=EPSG3857, width=96,
-                              height=96)
-
-    plain = [np.asarray(pipe.render_composite_byte(req(s), auto=True))
-             for s in shifts]
-    assert all(p is not None for p in plain)
-
-    monkeypatch.setenv("GSKY_RENDER_BATCH", "1")
-    monkeypatch.setenv("GSKY_WARP_WINDOW", "1")
-    out = [None] * 8
-
-    def worker(i):
-        out[i] = np.asarray(pipe.render_composite_byte(
-            req(shifts[i % len(shifts)]), auto=True))
-
-    threads = [threading.Thread(target=worker, args=(i,))
-               for i in range(8)]
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join(120)
-    for i, o in enumerate(out):
-        assert o is not None
-        np.testing.assert_array_equal(o, plain[i % len(shifts)])
-    b = pipe.executor._batcher
-    assert b.win_batches > 0 and b.full_batches == 0, \
-        (b.win_batches, b.full_batches)
-
-
 def test_drill_stack_cache_single_load_under_contention(tmp_path):
     """16 threads racing the same drill stack must trigger exactly one
     load (the inflight latch), and all get the same device buffer."""

@@ -215,6 +215,29 @@ class TestBBoxOps:
         assert sorted({t[1] for t in tiles}) == [0, 1024, 2048]
         assert tiles[0][3] == 1024 and tiles[-1][3] == 2500 - 2048
 
+    def test_split_ragged_last_row_and_column(self):
+        """The ragged edge-tile contract the WCS export plan depends
+        on."""
+        tiles = split_bbox(BBox(0.0, 0.0, 100.0, 60.0), 100, 60, 32, 32)
+        # 4 columns (32,32,32,4) x 2 rows (32,28)
+        assert len(tiles) == 8
+        assert sorted({t[1] for t in tiles}) == [0, 32, 64, 96]
+        assert sorted({t[2] for t in tiles}) == [0, 32]
+        by_off = {(t[1], t[2]): t for t in tiles}
+        assert by_off[(96, 0)][3] == 4      # ragged last column width
+        assert by_off[(0, 32)][4] == 28     # ragged last row height
+        # offsets + sizes tile the output exactly, no overlap, no gap
+        cover = np.zeros((60, 100), np.int32)
+        for tb, ox, oy, tw, th in tiles:
+            cover[oy:oy + th, ox:ox + tw] += 1
+        assert (cover == 1).all()
+        # each tile's bbox is the pixel-aligned slice of the request
+        for tb, ox, oy, tw, th in tiles:
+            assert tb.xmin == pytest.approx(ox)
+            assert tb.xmax == pytest.approx(ox + tw)
+            assert tb.ymax == pytest.approx(60 - oy)
+            assert tb.ymin == pytest.approx(60 - (oy + th))
+
     def test_xyz(self):
         b = xyz_tile_bbox(0, 0, 0)
         assert b.xmin == pytest.approx(-20037508.342789244)

@@ -3,10 +3,9 @@ interpret-mode parity of the paged warp kernel against the XLA
 reference AND the bucketed pallas kernel (bit-exact nearest, <= 2 ulp
 bilinear, page-boundary-crossing gathers, ragged scene counts in one
 batch), PagePool residency semantics (LRU, sharing, pins, decline
-rollback), ledger token versioning, and executor/batcher engagement
+rollback), ledger token versioning, and executor engagement
 with the GSKY_PAGED=0 byte-identity escape."""
 
-import threading
 from types import SimpleNamespace
 
 import numpy as np
@@ -427,11 +426,11 @@ class TestLedgerTokenVersioning:
 
 
 def _fake_group(B=3, sh=200, sw=220, h=96, w=96, step=16, shift=True):
-    """A crafted `executor._scene_groups` single-group tuple (11
-    members) so executor tests drive the real `_paged_from_group` span
-    logic without a scene cache: B granules, one with its affine
-    shifted off the top-left edge (partial page coverage)."""
-    from gsky_tpu.pipeline.executor import _bucket_pow2
+    """A crafted `executor.SceneGroup` so executor tests drive the real
+    `_paged_from_group` span logic without a scene cache: B granules,
+    one with its affine shifted off the top-left edge (partial page
+    coverage)."""
+    from gsky_tpu.pipeline.executor import SceneGroup, _bucket_pow2
     rng = np.random.default_rng(21)
     scenes = rng.uniform(0.0, 100.0, (B, sh, sw)).astype(np.float32)
     scenes[0, 40:60, 50:80] = np.nan
@@ -455,8 +454,10 @@ def _fake_group(B=3, sh=200, sw=220, h=96, w=96, step=16, shift=True):
           for k in range(B)]
     devs = [g.dev for g in gs] + [gs[0].dev] * (Bp - B)
     stack = jnp.stack(devs)
-    return (stack, ctrl, params64.astype(np.float32), step, ("sk",),
-            jnp.asarray(ctrl), None, None, None, gs, params64)
+    return SceneGroup(stack=stack, ctrl=ctrl, ctrl_dev=jnp.asarray(ctrl),
+                      params=params64.astype(np.float32),
+                      params64=params64, step=step, skey=("sk",),
+                      win=None, win0=None, scenes=gs)
 
 
 @pytest.fixture()
@@ -523,55 +524,3 @@ class TestExecutorPaged:
         np.testing.assert_array_equal(np.asarray(vx), np.asarray(vp))
         np.testing.assert_array_equal(np.asarray(cx), np.asarray(cp))
 
-
-class TestBatcherPaged:
-    def test_ragged_tiles_coalesce_one_flush(self, monkeypatch):
-        """Two concurrent tiles with DIFFERENT granule counts (T=1 vs
-        T=2 after pow2) coalesce into one paged flush; each gets its
-        own per-tile XLA-reference byte tile back, pins release, and
-        the pad-waste ledger sees the padded pages."""
-        monkeypatch.setenv("GSKY_PALLAS", "interpret")
-        # pin waves off: this test exercises the batcher's OWN flush;
-        # with a live wave scheduler render_paged delegates to it
-        # (pipeline/waves.py) and no batcher flush would happen
-        monkeypatch.setenv("GSKY_WAVES", "0")
-        from gsky_tpu.pipeline.batcher import RenderBatcher
-        pool = _pool(cap=64)
-        b = RenderBatcher(max_batch=4, max_wait_s=10.0)
-        b.knee = 2
-        tiles = [_inputs(0, B=1, lo=1.0, hi=4000.0),
-                 _inputs(1, B=2, lo=1.0, hi=4000.0)]
-        _, _, _, h, w, step, n_ns = tiles[0]
-        statics = ("near", n_ns, (h, w), step, True, 0)
-        sp = np.array([10.0, 250.0, 0.0], np.float32)
-        staged = [_stage_full(pool, t[0], t[2], serial0=100 * (i + 1))
-                  for i, t in enumerate(tiles)]
-        results = [None, None]
-        errors = [None, None]
-
-        def go(i):
-            stack, ctrl, params, *_ = tiles[i]
-            tables, p16 = staged[i]
-            fallback = (stack, params, None, None)
-            try:
-                results[i] = b.render_paged(
-                    ("paged",) + statics, pool, tables, p16,
-                    np.asarray(ctrl), sp, statics,
-                    int((tables != 0).sum()), fallback)
-            except Exception as e:   # noqa: BLE001 - assert below
-                errors[i] = e
-        ts = [threading.Thread(target=go, args=(i,)) for i in range(2)]
-        for t in ts:
-            t.start()
-        for t in ts:
-            t.join(timeout=60)
-        assert errors == [None, None]
-        assert b.paged_batches == 1
-        assert b.pad_waste_bytes > 0        # padded page slots billed
-        assert pool.stats()["pinned"] == 0
-        for i, (stack, ctrl, params, h, w, step, n_ns) in \
-                enumerate(tiles):
-            rx = render_scenes_ctrl(stack, ctrl, params,
-                                    jnp.asarray(sp), *statics)
-            assert results[i].shape == (h, w)
-            np.testing.assert_array_equal(np.asarray(rx), results[i])

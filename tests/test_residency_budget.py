@@ -173,10 +173,12 @@ class TestLedger:
         gs = [_granule(tmp_path, k) for k in range(3)]
         dst_gt = GeoTransform(16478548.0, 40.0, 0.0, -4198025.0, 0.0, -40.0)
         args = (gs, [0, 0, 0], [3.0, 2.0, 1.0], dst_gt, EPSG3857, 256, 256)
-        (stack, *_), = ex._scene_groups(*args, cache=cache)
+        group, = ex._scene_groups(*args, cache=cache)
+        stack = group.stack
         assert stack.shape == (4, SIDE, SIDE)   # 3 scenes, padded to 4
         assert cache.stats()["stack_bytes"] == 4 * SCENE_BYTES
-        (devs, *_), = ex._scene_groups(*args, cache=cache, stacked=False)
+        group, = ex._scene_groups(*args, cache=cache, stacked=False)
+        devs = group.stack
         assert len(devs) == 4 and devs[3] is devs[0]
         assert all(d.shape == (SIDE, SIDE) for d in devs)
         assert cache.stats()["stack_bytes"] == 4 * SCENE_BYTES

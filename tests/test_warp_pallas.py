@@ -113,7 +113,7 @@ class TestWindowedParity:
         made = _gather_window(np.asarray(params, np.float64),
                               ctrl_np[0], ctrl_np[1], S, S)
         assert made is not None
-        win, win0, _raw = made
+        win, win0 = made
         return win, jnp.asarray(win0)
 
     def test_edge_straddling_window_bit_exact(self):

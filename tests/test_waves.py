@@ -641,30 +641,3 @@ class TestWaveGate:
         monkeypatch.delenv("GSKY_WAVES", raising=False)
         monkeypatch.setenv("GSKY_PAGED", "0")
         assert not W.waves_enabled()     # no paged kernels, no waves
-
-    def test_batcher_flush_subsumed_by_live_scheduler(self,
-                                                      monkeypatch):
-        """`RenderBatcher.render_paged` delegates to a LIVE wave
-        scheduler: no batcher flush happens, the tile joins the wave,
-        and the result still matches the per-call reference."""
-        monkeypatch.setenv("GSKY_PALLAS", "interpret")
-        from gsky_tpu.pipeline.batcher import RenderBatcher
-        pool = test_paged._pool(cap=64)
-        sched = W.default_waves()       # live singleton -> delegation
-        b = RenderBatcher(max_batch=4, max_wait_s=10.0)
-        tile = test_paged._inputs(0, B=1, lo=1.0, hi=4000.0)
-        stack, ctrl, params, h, w, step, n_ns = tile
-        statics = _byte_statics(n_ns, h, w, step)
-        sp = np.array([10.0, 250.0, 0.0], np.float32)
-        tables, p16 = test_paged._stage_full(pool, stack, params,
-                                             serial0=40)
-        out = b.render_paged(("paged",) + statics, pool, tables, p16,
-                             np.asarray(ctrl), sp, statics,
-                             int((tables != 0).sum()),
-                             (stack, params, None, None))
-        assert b.paged_batches == 0      # no batcher flush
-        assert sched.stats()["requests"] == 1
-        rx = render_scenes_ctrl(stack, ctrl, params, jnp.asarray(sp),
-                                *statics)
-        np.testing.assert_array_equal(np.asarray(rx), out)
-        assert pool.stats()["pinned"] == 0

@@ -70,7 +70,7 @@ class TestKernelWindowParity:
     @pytest.mark.parametrize("method", ["near", "bilinear", "cubic"])
     def test_scored_bit_parity(self, method):
         stack, ctrl, params = _synthetic_inputs()
-        win, win0, _ = _gather_window(params, ctrl[0].astype(np.float64),
+        win, win0 = _gather_window(params, ctrl[0].astype(np.float64),
                                    ctrl[1].astype(np.float64),
                                    stack.shape[1], stack.shape[2])
         assert win is not None
@@ -90,7 +90,7 @@ class TestKernelWindowParity:
 
     def test_render_byte_bit_parity(self):
         stack, ctrl, params = _synthetic_inputs(seed=6)
-        win, win0, _ = _gather_window(params, ctrl[0].astype(np.float64),
+        win, win0 = _gather_window(params, ctrl[0].astype(np.float64),
                                    ctrl[1].astype(np.float64),
                                    stack.shape[1], stack.shape[2])
         p32 = jnp.asarray(params.astype(np.float32))
@@ -105,7 +105,7 @@ class TestKernelWindowParity:
 
     def test_bands_bit_parity(self):
         stack, ctrl, params = _synthetic_inputs(seed=7)
-        win, win0, _ = _gather_window(params, ctrl[0].astype(np.float64),
+        win, win0 = _gather_window(params, ctrl[0].astype(np.float64),
                                    ctrl[1].astype(np.float64),
                                    stack.shape[1], stack.shape[2])
         p32 = jnp.asarray(params.astype(np.float32))
@@ -125,7 +125,7 @@ class TestKernelWindowParity:
         rows) must clamp the window, not shift values."""
         stack, ctrl, params = _synthetic_inputs(seed=8)
         params[1, 3] = -120.0      # rows go negative for granule 1
-        win, win0, _ = _gather_window(params, ctrl[0].astype(np.float64),
+        win, win0 = _gather_window(params, ctrl[0].astype(np.float64),
                                    ctrl[1].astype(np.float64),
                                    stack.shape[1], stack.shape[2])
         assert win is not None and int(win0[0]) == 0
@@ -145,7 +145,7 @@ class TestKernelWindowParity:
         the host-computed window (the correctness contract)."""
         from gsky_tpu.ops.warp import _bilerp_grid
         stack, ctrl, params = _synthetic_inputs(seed=9)
-        win, win0, _ = _gather_window(params, ctrl[0].astype(np.float64),
+        win, win0 = _gather_window(params, ctrl[0].astype(np.float64),
                                    ctrl[1].astype(np.float64),
                                    stack.shape[1], stack.shape[2])
         sx = np.asarray(_bilerp_grid(jnp.asarray(ctrl[0]), 256, 256, 16))
@@ -166,7 +166,7 @@ class TestKernelWindowParity:
         anyway), keep a small window, and stay bit-identical."""
         stack, ctrl, params = _synthetic_inputs(seed=12)
         params[:, 0] = 1800.0   # cols run past true width (S-60)
-        win, win0, _ = _gather_window(params, ctrl[0].astype(np.float64),
+        win, win0 = _gather_window(params, ctrl[0].astype(np.float64),
                                    ctrl[1].astype(np.float64),
                                    stack.shape[1], stack.shape[2])
         assert win is not None and win[1] <= 512

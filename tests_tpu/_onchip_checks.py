@@ -186,7 +186,7 @@ def _():
                           ctrl[1].astype(np.float64),
                           stack.shape[1], stack.shape[2])
     assert made is not None, "window must engage at this shape"
-    win, win0, _ = made
+    win, win0 = made
     kw = dict(method="cubic", n_ns=2, out_hw=(256, 256), step=16,
               auto=True, colour_scale=0)
     full = np.asarray(render_scenes_ctrl(
@@ -219,7 +219,7 @@ def _():
                           ctrl[0].astype(np.float64),
                           ctrl[1].astype(np.float64), S, S)
     assert made is not None, "window must engage at this shape"
-    win, win0, _ = made
+    win, win0 = made
     kw = dict(method="bilinear", out_hw=(256, 256), step=16, auto=True,
               colour_scale=0)
     prio = jnp.ones((1, 3), jnp.float32)
@@ -471,29 +471,7 @@ def _():
                                np.asarray(canv_c)[both], rtol=1e-5)
 
 
-# --- batched multi-tile kernels -------------------------------------------
-
-@check("render_many_batched")
-def _():
-    """The batcher's N-tile vmapped kernel == N single-tile dispatches."""
-    from gsky_tpu.ops.warp import render_scenes_ctrl, render_scenes_ctrl_many
-    stack, ctrl, params = _render_inputs()
-    N = 4
-    ctrls = np.stack([ctrl + k * 2.0 for k in range(N)])
-    paramss = np.stack([params] * N)
-    sps = np.zeros((N, 3), np.float32)
-    kw = dict(method="near", n_ns=2, out_hw=(256, 256), step=16,
-              auto=True, colour_scale=0)
-    many = np.asarray(render_scenes_ctrl_many(
-        jnp.asarray(stack), jnp.asarray(ctrls), jnp.asarray(paramss),
-        jnp.asarray(sps), **kw))
-    for k in range(N):
-        one = np.asarray(render_scenes_ctrl(
-            jnp.asarray(stack), jnp.asarray(ctrls[k]),
-            jnp.asarray(paramss[k]), jnp.asarray(sps[k]), **kw))
-        mism = np.mean(many[k] != one)
-        assert mism < 0.001, f"tile {k}: {mism:.2%}"
-
+# --- shared-source multi-tile gather -------------------------------------
 
 @check("warp_gather_shared")
 def _():

@@ -157,7 +157,7 @@ def suite_selftest(conc: int, n_tiles: int) -> int:
     plat = ensure_platform()
     print(f"selftest on {plat['platform']} ({plat['device_kind']})",
           flush=True)
-    import bench as B
+    from tools import sample_archive as B
     from gsky_tpu.index import MASClient
     from gsky_tpu.server.config import ConfigWatcher
     from gsky_tpu.server.metrics import MetricsLogger
@@ -287,7 +287,7 @@ def suite_selftest(conc: int, n_tiles: int) -> int:
     started.wait(30)
     host = host_holder["host"]
 
-    # GetMap URL grid over the mosaic core (as bench.py lays it out)
+    # GetMap URL grid over the mosaic core (as the soak lays it out)
     span = B.SCENE_SIZE * 30.0
     core = BBox(590000.0 + span * 0.2, 6105000.0 - span * 1.1,
                 590000.0 + span * 1.1, 6105000.0 - span * 0.2)

@@ -26,10 +26,10 @@ echo "[demo] generating sample archive under $DEMO"
 JAX_PLATFORMS=cpu $PY - "$DEMO" <<'EOF'
 import json, os, sys
 sys.path.insert(0, os.getcwd())
-import bench
+from tools import sample_archive
 demo = sys.argv[1]
 data = os.path.join(demo, "data"); os.makedirs(data, exist_ok=True)
-store, utm, paths = bench.build_archive(data)
+store, utm, paths = sample_archive.build_archive(data)
 conf = os.path.join(demo, "conf"); os.makedirs(conf, exist_ok=True)
 with open(os.path.join(conf, "config.json"), "w") as fp:
     json.dump({
@@ -40,7 +40,7 @@ with open(os.path.join(conf, "config.json"), "w") as fp:
             "name": "landsat", "title": "Synthetic Landsat mosaic",
             "data_source": data,
             "rgb_products": [f"LC08_20200{110+k}_T1"
-                             for k in range(bench.N_SCENES)],
+                             for k in range(sample_archive.N_SCENES)],
             "time_generator": "mas",
             "palette": {"interpolate": True, "colours": [
                 {"R": 0, "G": 0, "B": 120, "A": 255},

@@ -5,7 +5,7 @@ For every class that creates a ``threading.Lock``/``RLock`` on
 an owned lock or never under one.  An attribute written both ways is
 the textbook latent race: the locked sites prove the author believed
 the attribute is shared, so the unlocked site is a hole (page pool
-slots, wave counters, batcher state — the structures the wave ticker
+slots, wave counters — the structures the wave ticker
 and drainer threads touch concurrently).
 
 Mechanics (deliberately syntactic — this is a consistency check, not

@@ -283,6 +283,9 @@ import urllib.request
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
+from tools.sample_archive import (  # noqa: E402
+    N_SCENES, SCENE_SIZE, build_archive)
+
 
 def rss_mb() -> float:
     with open("/proc/self/status") as fp:
@@ -387,7 +390,6 @@ def _run(argv=None):
 
     import numpy as np
 
-    import bench as B
     from gsky_tpu.geo.crs import EPSG4326, EPSG3857
     from gsky_tpu.geo.transform import BBox, transform_bbox
     from gsky_tpu.index import MASClient
@@ -396,7 +398,7 @@ def _run(argv=None):
     from gsky_tpu.server.ows import OWSServer
 
     root = tempfile.mkdtemp(prefix="gsky_soak_")
-    store, utm, paths = B.build_archive(root)
+    store, utm, paths = build_archive(root)
     mas_client = MASClient(store)
     conf_dir = os.path.join(root, "conf")
     os.makedirs(conf_dir)
@@ -426,7 +428,7 @@ def _run(argv=None):
     # has no bbox/size params, so dap_to_wcs reads them off the layer,
     # and a tile cap below the frame splits the export into >1 staged
     # tile -- the precondition for the streamed-spool DAP4 leg
-    dap_span = B.SCENE_SIZE * 30.0
+    dap_span = SCENE_SIZE * 30.0
     dap_core = BBox(590000.0, 6105000.0 - dap_span * 1.3,
                     590000.0 + dap_span * 1.3, 6105000.0)
     dap_ll = transform_bbox(dap_core, utm, EPSG4326)
@@ -437,7 +439,7 @@ def _run(argv=None):
                 "name": "landsat", "title": "soak",
                 "data_source": root,
                 "rgb_products": [f"LC08_20200{110 + k}_T1"
-                                 for k in range(B.N_SCENES)],
+                                 for k in range(N_SCENES)],
                 "time_generator": "mas",
                 "wcs_max_width": 4096, "wcs_max_height": 4096,
                 "wcs_max_tile_width": 256,
@@ -450,7 +452,7 @@ def _run(argv=None):
                 "name": "landsat_chaos", "title": "chaos soak",
                 "data_source": root,
                 "rgb_products": [f"LC08_20200{110 + k}_T1"
-                                 for k in range(B.N_SCENES)],
+                                 for k in range(N_SCENES)],
                 "time_generator": "mas",
                 "cache_max_age": 3,
                 "wcs_max_width": 4096, "wcs_max_height": 4096,
@@ -474,7 +476,7 @@ def _run(argv=None):
                 "name": "landsat_dap", "title": "dap soak",
                 "data_source": root,
                 "rgb_products": [f"LC08_20200{110 + k}_T1"
-                                 for k in range(B.N_SCENES)],
+                                 for k in range(N_SCENES)],
                 "time_generator": "mas",
                 "default_geo_bbox": [dap_ll.xmin, dap_ll.ymin,
                                      dap_ll.xmax, dap_ll.ymax],
@@ -498,7 +500,7 @@ def _run(argv=None):
                 "data_sources": [{
                     "data_source": root,
                     "rgb_products": [f"LC08_20200{110 + k}_T1"
-                                     for k in range(B.N_SCENES)]}],
+                                     for k in range(N_SCENES)]}],
                 "approx": False},
                 # algebra scenario: the drill minority evaluates band
                 # expressions per date, so the compile cache absorbs
@@ -547,7 +549,7 @@ def _run(argv=None):
         started.wait(30)
         return host_holder["host"]
 
-    span = B.SCENE_SIZE * 30.0
+    span = SCENE_SIZE * 30.0
     core = BBox(590000.0, 6105000.0 - span * 1.3,
                 590000.0 + span * 1.3, 6105000.0)
     merc = transform_bbox(transform_bbox(core, utm, EPSG4326),
@@ -612,7 +614,7 @@ def _run(argv=None):
         url = (f"http://{host}/ows?service=WMS&request=GetMap"
                f"&version=1.3.0&layers=landsat&crs=EPSG:3857&bbox={bb}"
                f"&width=256&height=256&format=image/png"
-               f"&time=2020-01-{10 + i % B.N_SCENES:02d}T00:00:00.000Z")
+               f"&time=2020-01-{10 + i % N_SCENES:02d}T00:00:00.000Z")
         with urllib.request.urlopen(url, timeout=120) as r:
             body = r.read()
             return r.status == 200 and body[:8] == b"\x89PNG\r\n\x1a\n"
@@ -1197,9 +1199,6 @@ def run_burst(args, watcher, mas_client, merc, boot) -> int:
     from gsky_tpu.server.prewarm import (compile_count,
                                          install_compile_probe, prewarm)
 
-    # the scenario *is* the staged path — don't let an inherited
-    # escape-hatch setting silently soak the serial path instead
-    os.environ.pop("GSKY_TILE_PIPELINE", None)
     # waves ON (this retires the PR 12 caveat that pinned GSKY_WAVES=0
     # here): wave occupancy is runtime-nondeterministic, but the
     # pipelined scheduler pushes FULL pow2 result blocks through its
@@ -1455,7 +1454,6 @@ def run_fleet(args, watcher, mas_client, merc, boot) -> int:
 
         # the fleet layer lives in its own namespace so its
         # worker_nodes don't leak into the other scenarios' layers
-        import bench as B
         ns_dir = os.path.join(conf_dir, "fleet")
         os.makedirs(ns_dir, exist_ok=True)
         with open(os.path.join(ns_dir, "config.json"), "w") as fp:
@@ -1466,7 +1464,7 @@ def run_fleet(args, watcher, mas_client, merc, boot) -> int:
                     "name": "landsat_fleet", "title": "fleet soak",
                     "data_source": data_root,
                     "rgb_products": [f"LC08_20200{110 + k}_T1"
-                                     for k in range(B.N_SCENES)],
+                                     for k in range(N_SCENES)],
                     "time_generator": "mas",
                     "wms_timeout": 120,
                     "wcs_max_width": 4096, "wcs_max_height": 4096,
@@ -3231,7 +3229,6 @@ def run_fabric(args, watcher, mas_client, merc, boot) -> int:
                 print("SOAK FAILED", flush=True)
                 return 1
 
-        import bench as B
         ns_dir = os.path.join(conf_dir, "fabric")
         os.makedirs(ns_dir, exist_ok=True)
         with open(os.path.join(ns_dir, "config.json"), "w") as fp:
@@ -3242,7 +3239,7 @@ def run_fabric(args, watcher, mas_client, merc, boot) -> int:
                     "name": "landsat_fabric", "title": "fabric soak",
                     "data_source": data_root,
                     "rgb_products": [f"LC08_20200{110 + k}_T1"
-                                     for k in range(B.N_SCENES)],
+                                     for k in range(N_SCENES)],
                     "time_generator": "mas",
                     "wms_timeout": 120,
                     "wcs_max_width": 4096, "wcs_max_height": 4096,
@@ -3549,7 +3546,6 @@ def run_elastic(args, watcher, mas_client, merc, boot) -> int:
                 print("SOAK FAILED", flush=True)
                 return 1
 
-        import bench as B
         ns_dir = os.path.join(conf_dir, "elastic")
         os.makedirs(ns_dir, exist_ok=True)
         with open(os.path.join(ns_dir, "config.json"), "w") as fp:
@@ -3560,7 +3556,7 @@ def run_elastic(args, watcher, mas_client, merc, boot) -> int:
                     "name": "landsat_elastic", "title": "elastic soak",
                     "data_source": data_root,
                     "rgb_products": [f"LC08_20200{110 + k}_T1"
-                                     for k in range(B.N_SCENES)],
+                                     for k in range(N_SCENES)],
                     "time_generator": "mas",
                     "wms_timeout": 120,
                     "wcs_max_width": 4096, "wcs_max_height": 4096,
@@ -4151,7 +4147,6 @@ def run_animation(args, watcher, mas_client, merc, boot) -> int:
 
     import numpy as np
 
-    import bench as B
     from gsky_tpu.obs import metrics as om
     from gsky_tpu.pipeline.waves import wave_stats
     from gsky_tpu.server.metrics import MetricsLogger
@@ -4178,7 +4173,7 @@ def run_animation(args, watcher, mas_client, merc, boot) -> int:
                            metrics=MetricsLogger(), gateway=None)
         host = boot(server)
 
-        n_frames = B.N_SCENES
+        n_frames = N_SCENES
         time_list = ",".join(f"2020-01-{10 + k:02d}T00:00:00.000Z"
                              for k in range(n_frames))
         grid = 5
@@ -4381,7 +4376,6 @@ def run_dap4(args, watcher, mas_client, merc, boot) -> int:
     import threading
     import urllib.parse
 
-    import bench as B
     from gsky_tpu.geo.crs import EPSG3857, EPSG4326
     from gsky_tpu.geo.transform import transform_bbox
     from gsky_tpu.obs import metrics as om
@@ -4402,7 +4396,7 @@ def run_dap4(args, watcher, mas_client, merc, boot) -> int:
                            metrics=MetricsLogger(), gateway=None)
         host = boot(server)
 
-        bands = [f"LC08_20200{110 + k}_T1" for k in range(B.N_SCENES)]
+        bands = [f"LC08_20200{110 + k}_T1" for k in range(N_SCENES)]
         ll = transform_bbox(merc, EPSG3857, EPSG4326)
         # x-clamp fractions stay well inside the coverage frame so the
         # filter survives dap_to_wcs's in-bbox validity check
