@@ -20,6 +20,7 @@ equivalent).  Ingest takes the same `{"filename", "file_type",
 
 from __future__ import annotations
 
+import contextlib
 import datetime as dt
 import hashlib
 import json
@@ -78,6 +79,123 @@ class GdalRecord(dict):
         r = GdalRecord(self)
         r.unix = self.unix
         return r
+
+
+class _Footprint:
+    """What the polygon refinement asks of a geometry in EPSG:4326 (a
+    dataset row's footprint, or the query's), as float64 arrays made
+    once: its bbox; the vertices it puts to the other side (every
+    exterior's, each max(1, n // 64)-th); every ring's edges for the
+    even-odd ray cast, end to end in one block; every exterior's
+    segments, closed, for the crossing test.  `meets` is the
+    ST_Intersects stand-in the store has always computed: bboxes
+    overlap, and a vertex of either lies in the other or two exterior
+    edges cross; a point geometry meets what contains one of its
+    points.  The arithmetic is `Geometry.contains_point`'s and the
+    parametric segment test's, element for element, so an answer does
+    not depend on which of the two made it."""
+
+    __slots__ = ("bbox", "points", "verts", "x", "y", "y2", "dx", "dy",
+                 "starts", "polys", "sx", "sy", "sdx", "sdy")
+
+    def __init__(self, g: geom.Geometry):
+        self.bbox = g.bbox()
+        self.points = np.asarray(g.points, np.float64) \
+            if g.kind in ("Point", "MultiPoint") else None
+        polys = [poly for poly in g.polys if poly and len(poly[0])]
+        self.verts = _stacked(
+            [poly[0][:: max(1, len(poly[0]) // 64)] for poly in polys])
+        # ray cast: ring k's edges are [starts[k], starts[k + 1]) of the
+        # block; a polygon is (its exterior's k, its holes' ks)
+        rings, self.polys = [], []
+        for poly in polys:
+            holes = [r for r in poly[1:] if len(r)]
+            self.polys.append(
+                (len(rings),
+                 list(range(len(rings) + 1, len(rings) + 1 + len(holes)))))
+            rings += [poly[0]] + holes
+        xy = _stacked(rings)
+        nxt = _stacked([np.roll(r, -1, axis=0) for r in rings])
+        self.x, self.y, self.y2 = xy[:, 0], xy[:, 1], nxt[:, 1]
+        self.dx, self.dy = nxt[:, 0] - self.x, nxt[:, 1] - self.y
+        self.starts = np.cumsum([0] + [len(r) for r in rings[:-1]])
+        # crossing: the exteriors' segments, each ring closed first
+        closed = [r if r[0][0] == r[-1][0] and r[0][1] == r[-1][1]
+                  else np.vstack([r, r[:1]])
+                  for r in (poly[0] for poly in polys)]
+        p = _stacked([r[:-1] for r in closed])
+        d = _stacked([r[1:] - r[:-1] for r in closed])
+        self.sx, self.sy, self.sdx, self.sdy = \
+            p[:, 0], p[:, 1], d[:, 0], d[:, 1]
+
+    def contains(self, pts: np.ndarray) -> np.ndarray:
+        """`Geometry.contains_point` of each of pts (K, 2): in a
+        polygon's exterior and in none of its holes, even-odd."""
+        if not len(pts) or not len(self.x):
+            return np.zeros(len(pts), bool)
+        px, py = pts[:, 0:1], pts[:, 1:2]
+        cond = (self.y > py) != (self.y2 > py)
+        xint = self.x + (py - self.y) * self.dx / self.dy
+        hit = cond & (px < xint)                        # (K, edges)
+        if len(self.starts) == 1:
+            return np.bitwise_xor.reduce(hit, axis=1)
+        odd = np.bitwise_xor.reduceat(hit, self.starts, axis=1)
+        inside = np.zeros(len(pts), bool)
+        for ext, holes in self.polys:
+            inside |= odd[:, ext] & ~odd[:, holes].any(axis=1)
+        return inside
+
+    def _edges_cross(self, other: "_Footprint") -> bool:
+        if not len(self.sx) or not len(other.sx):
+            return False
+        prx, pry = self.sdx[:, None], self.sdy[:, None]
+        qsx, qsy = other.sdx[None, :], other.sdy[None, :]
+        dx = other.sx[None, :] - self.sx[:, None]
+        dy = other.sy[None, :] - self.sy[:, None]
+        rxs = prx * qsy - pry * qsx
+        tt = (dx * qsy - dy * qsx) / rxs
+        uu = (dx * pry - dy * prx) / rxs
+        return bool(((rxs != 0) & (tt >= 0) & (tt <= 1)
+                     & (uu >= 0) & (uu <= 1)).any())
+
+    def meets(self, q: "_Footprint") -> bool:
+        """Does this row's footprint intersect the query's?  Called
+        under `np.errstate(divide="ignore", invalid="ignore")`: a
+        horizontal edge and a pair of parallel segments divide by zero
+        on the way to an answer that masks them out."""
+        if not self.bbox.intersects(q.bbox):
+            return False
+        if q.points is not None:
+            return bool(self.contains(q.points).any())
+        if self.points is not None:
+            return bool(q.contains(self.points).any())
+        # the likeliest first: a tile inside a granule
+        return bool(self.contains(q.verts).any()
+                    or q.contains(self.verts).any()
+                    or self._edges_cross(q))
+
+
+def _stacked(arrays: List[np.ndarray]) -> np.ndarray:
+    return np.concatenate(arrays, axis=0) if arrays \
+        else np.zeros((0, 2), np.float64)
+
+
+_NOT_MADE = object()
+
+
+class _KeptRow:
+    """One dataset row as the store keeps it for a generation: the SQL
+    row it was made from, and what queries derive from it, each made by
+    the first query that needs it: `footprint` by the polygon
+    refinement (a `_Footprint`; None for a polygon that does not parse,
+    which keeps its row), `record` by a `gdal` answer."""
+
+    __slots__ = ("row", "footprint", "record")
+
+    def __init__(self, row: tuple):
+        self.row = row
+        self.footprint = _NOT_MADE
+        self.record: Optional[GdalRecord] = None
 
 
 _SCHEMA = """
@@ -149,9 +267,14 @@ class MASStore:
     # this generation (hit) or decoded for this query (miss)
     total_row_hits = 0
     total_row_misses = 0
+    # candidate rows the polygon refinement tested: footprint prepared
+    # earlier under this generation (hit) or prepared for this query
+    total_footprint_hits = 0
+    total_footprint_misses = 0
     _totals_lock = threading.Lock()
-    # decoded rows kept per store, oldest out first.  A row of 1,000
-    # stamps is ~0.15 MB decoded; a tile archive's rows hold one stamp
+    # rows kept per store, oldest out first.  A row of 1,000 stamps is
+    # ~0.15 MB decoded; a tile archive's rows hold one stamp; a
+    # footprint is a few hundred bytes a ring
     _ROW_CACHE_MAX = 2048
 
     def __init__(self, db_path: str = ":memory:"):
@@ -161,13 +284,15 @@ class MASStore:
         self._cache_lock = threading.Lock()
         self.query_hits = 0
         self.query_misses = 0
-        # (generation, {row id: (sql row, GdalRecord)}): what a gdal
-        # query answers for a row, decoded once per generation.  Swapped
-        # whole when the generation moves; read without a lock
-        self._rows: Tuple[int, Dict[int, Tuple[tuple, GdalRecord]]] = \
-            (-1, {})
+        # (generation, {row id: _KeptRow}): what queries derive from a
+        # row (its prepared footprint, its gdal record), each made once
+        # per generation.  Swapped whole when the generation moves; read
+        # without a lock
+        self._rows: Tuple[int, Dict[int, _KeptRow]] = (-1, {})
         self.row_hits = 0
         self.row_misses = 0
+        self.footprint_hits = 0
+        self.footprint_misses = 0
         self._local = threading.local()
         self._memory_conn: Optional[sqlite3.Connection] = None
         # a single :memory: connection is shared across threads, so every
@@ -201,7 +326,6 @@ class MASStore:
         return int(row[0]) if row else 0
 
     def _maybe_lock(self):
-        import contextlib
         return self._lock if self._memory_conn is not None \
             else contextlib.nullcontext()
 
@@ -332,14 +456,21 @@ class MASStore:
         """`mas_intersects` (`mas/api/mas.sql:363-547`).  Returns
         {"files": [...]} or {"gdal": [...]} when metadata == "gdal".
 
-        A row's record is decoded once per generation (`_records`);
-        results cache per (args, generation) — the in-process stand-in
-        for the reference's memcached tier in front of MAS
-        (`mas/api/api.go:43-52`): a tile server asks the same question
-        for every zoom-level repeat, and the polygon refinement below is
-        ~3 ms a call.  Any ingest bumps the generation (even from
-        another process against the same file DB), so cached answers
-        die with the data they were computed from."""
+        What a query derives from a row is made once per generation and
+        kept with the row (`_KeptRow`): its footprint parsed,
+        reprojected to EPSG:4326, split at the dateline and laid out as
+        arrays (`_refine`), its record decoded (`_records`).  A query
+        that finds its candidate rows prepared parses and reprojects
+        its own geometry only, and tests each row with a few array
+        operations (~20 us a row, 50 where no vertex decides and the
+        edges are crossed; the first query to meet a UTM row pays ~0.2 ms
+        for it).  Results cache per (args, generation) — the
+        in-process stand-in for the reference's memcached tier in front
+        of MAS (`mas/api/api.go:43-52`): a tile server asks the same
+        question for every zoom-level repeat.  Any ingest bumps the
+        generation (even from another process against the same file
+        DB), so cached answers and kept rows die with the data they
+        were computed from."""
         generation = self.generation
         ckey = (gpath, srs, wkt, nseg, time, until,
                 tuple(namespaces) if namespaces else None, metadata,
@@ -364,7 +495,7 @@ class MASStore:
             if "gdal" in hit:
                 return {"gdal": [r.copy() for r in hit["gdal"]]}
             return {"files": list(hit["files"])}
-        q_geom = None
+        query = None
         if wkt:
             g = geom.from_wkt(wkt)
             if srs:
@@ -378,16 +509,16 @@ class MASStore:
                         lambda x, y: crs.transform_to(EPSG4326, x, y))
             # antimeridian-crossing queries split into hemisphere parts
             # (ST_SplitDatelineWGS84, mas.sql:13-84)
-            q_geom = g.split_dateline()
+            query = _Footprint(g.split_dateline())
 
         t_a = parse_time(time) if time else None
         t_b = parse_time(until) if until else None
 
-        if q_geom is not None:
+        if query is not None:
             # R*Tree walk instead of a table scan (GIST-index role);
             # NULL-bbox rows are absent from the tree, matching the old
             # prefilter's `xmin IS NULL` exclusion
-            qb = q_geom.bbox()
+            qb = query.bbox
             sql = ("SELECT datasets.* FROM datasets"
                    " JOIN datasets_rtree AS rt ON datasets.id = rt.id"
                    " WHERE datasets.path LIKE ? ESCAPE '\\'"
@@ -409,66 +540,107 @@ class MASStore:
             sql += " AND namespace IN (%s)" % ",".join("?" * len(namespaces))
             args += list(namespaces)
         rows = self._fetchall(sql, args)
-        i_path, i_srs, i_polygon = self._i_path, self._i_srs, self._i_polygon
-
-        # refine: exact polygon intersection in 4326
-        out_rows = []
-        for row in rows:
-            if q_geom is not None and row[i_polygon]:
-                try:
-                    p = geom.from_wkt(row[i_polygon])
-                    if row[i_srs]:
-                        crs = parse_crs(row[i_srs])
-                        if crs != EPSG4326:
-                            p = p.transform(lambda x, y: crs.transform_to(
-                                EPSG4326, x, y))
-                    # zone-60/zone-1 footprints: split before testing
-                    p = p.split_dateline()
-                    if not _geoms_intersect(p, q_geom):
-                        continue
-                except (ValueError, KeyError):
-                    pass
-            out_rows.append(row)
-            if limit and len(out_rows) >= limit:
-                break
+        if query is not None:
+            rows = self._refine(rows, query, generation, limit)
+        elif limit:
+            rows = rows[:limit]
 
         if metadata != "gdal":
             return self._cache_put(
-                ckey, {"files": sorted({r[i_path] for r in out_rows})})
+                ckey, {"files": sorted({r[self._i_path] for r in rows})})
         return self._cache_put(
-            ckey, {"gdal": self._records(out_rows, generation)})
+            ckey, {"gdal": self._records(rows, generation)})
 
-    def _records(self, rows: List[tuple],
-                 generation: int) -> List[GdalRecord]:
-        """The `gdal` record of each SQL row, each a copy (its own top
-        level, shared insides) of the row's decoded record.  A row is
-        decoded (its JSON columns loaded, its stamps parsed) once per
-        generation; a kept record answers only for the row it was
-        decoded from (same id AND equal columns: sqlite hands a deleted
-        row's id to the next insert, and a query that read its
-        generation before an ingest may select after it)."""
+    def _refine(self, rows: List[tuple], query: _Footprint,
+                generation: int, limit: int) -> List[tuple]:
+        """The rows whose footprint intersects the query's exactly, in
+        EPSG:4326, in the order given, cut at `limit`.  A row's
+        footprint is prepared once per generation and kept with the row
+        (`_kept_row`); a row with no polygon, or with one that does not
+        parse, stays in."""
+        kept = self._kept_rows(generation)
+        i_polygon = self._i_polygon
+        out = []
+        tested = hits = 0
+        with np.errstate(divide="ignore", invalid="ignore"):
+            for row in rows:
+                if row[i_polygon]:
+                    tested += 1
+                    entry = self._kept_row(kept, row)
+                    footprint = entry.footprint
+                    if footprint is _NOT_MADE:
+                        footprint = entry.footprint = self._prepare(row)
+                    else:
+                        hits += 1
+                    if footprint is not None and not footprint.meets(query):
+                        continue
+                out.append(row)
+                if limit and len(out) >= limit:
+                    break
+        with MASStore._totals_lock:
+            self.footprint_hits += hits
+            self.footprint_misses += tested - hits
+            MASStore.total_footprint_hits += hits
+            MASStore.total_footprint_misses += tested - hits
+        return out
+
+    def _prepare(self, row: tuple) -> Optional[_Footprint]:
+        try:
+            p = geom.from_wkt(row[self._i_polygon])
+            if row[self._i_srs]:
+                crs = parse_crs(row[self._i_srs])
+                if crs != EPSG4326:
+                    p = p.transform(
+                        lambda x, y: crs.transform_to(EPSG4326, x, y))
+            # zone-60/zone-1 footprints: split before testing
+            return _Footprint(p.split_dateline())
+        except (ValueError, KeyError):
+            return None
+
+    def _kept_rows(self, generation: int) -> Dict[int, _KeptRow]:
+        """The rows kept for `generation`: the store's own dict, begun
+        anew when the generation has moved on; a dict of the query's own
+        for one that read its generation before an ingest that others
+        have seen since."""
         gen, kept = self._rows
         if gen < generation:
             with self._cache_lock:
                 if self._rows[0] < generation:
                     self._rows = (generation, {})
                 gen, kept = self._rows
-        if gen != generation:
-            kept = {}           # a query older than the data: its own
-        i_id = self._i_id
+        return kept if gen == generation else {}
+
+    def _kept_row(self, kept: Dict[int, _KeptRow], row: tuple) -> _KeptRow:
+        """The kept entry of an SQL row, begun now if there is none.  An
+        entry answers only for the row it was made from (same id AND
+        equal columns: sqlite hands a deleted row's id to the next
+        insert, and a query that read its generation before an ingest
+        may select after it)."""
+        entry = kept.get(row[self._i_id])
+        if entry is None or entry.row != row:
+            entry = _KeptRow(row)
+            with self._cache_lock:
+                kept[row[self._i_id]] = entry
+                while len(kept) > self._ROW_CACHE_MAX:
+                    del kept[next(iter(kept))]
+        return entry
+
+    def _records(self, rows: List[tuple],
+                 generation: int) -> List[GdalRecord]:
+        """The `gdal` record of each SQL row, each a copy (its own top
+        level, shared insides) of the row's decoded record.  A row is
+        decoded (its JSON columns loaded, its stamps parsed) once per
+        generation and kept with the row (`_kept_row`)."""
+        kept = self._kept_rows(generation)
         out = []
         hits = 0
         for row in rows:
-            entry = kept.get(row[i_id])
-            if entry is not None and entry[0] == row:
-                hits += 1
-                rec = entry[1]
+            entry = self._kept_row(kept, row)
+            rec = entry.record
+            if rec is None:
+                rec = entry.record = self._decode(row)
             else:
-                rec = self._decode(row)
-                with self._cache_lock:
-                    kept[row[i_id]] = (row, rec)
-                    while len(kept) > self._ROW_CACHE_MAX:
-                        del kept[next(iter(kept))]
+                hits += 1
             out.append(rec.copy())
         with MASStore._totals_lock:
             self.row_hits += hits
@@ -602,51 +774,3 @@ def _float_or_none(v) -> Optional[float]:
 def _like_prefix(gpath: str) -> str:
     esc = gpath.replace("\\", "\\\\").replace("%", r"\%").replace("_", r"\_")
     return esc + "%"
-
-
-def _geoms_intersect(a: geom.Geometry, b: geom.Geometry) -> bool:
-    """Polygon/polygon (or point) intersection test."""
-    if not a.bbox().intersects(b.bbox()):
-        return False
-    if b.kind in ("Point", "MultiPoint"):
-        return any(a.contains_point(p[0], p[1]) for p in b.points)
-    if a.kind in ("Point", "MultiPoint"):
-        return any(b.contains_point(p[0], p[1]) for p in a.points)
-    # vertex containment either way
-    for poly in a.polys:
-        for p in poly[0][:: max(1, len(poly[0]) // 64)]:
-            if b.contains_point(p[0], p[1]):
-                return True
-    for poly in b.polys:
-        for p in poly[0][:: max(1, len(poly[0]) // 64)]:
-            if a.contains_point(p[0], p[1]):
-                return True
-    # edge crossings
-    for pa in a.polys:
-        for pb in b.polys:
-            if _rings_cross(pa[0], pb[0]):
-                return True
-    return False
-
-
-def _rings_cross(r1: np.ndarray, r2: np.ndarray) -> bool:
-    """Any segment of r1 crosses any segment of r2 (vectorised)."""
-    def closed(r):
-        if r[0][0] != r[-1][0] or r[0][1] != r[-1][1]:
-            return np.vstack([r, r[:1]])
-        return r
-    r1 = closed(r1)
-    r2 = closed(r2)
-    p = r1[:-1][:, None, :]   # (N,1,2)
-    pr = r1[1:][:, None, :] - p
-    q = r2[:-1][None, :, :]   # (1,M,2)
-    qs = r2[1:][None, :, :] - q
-    d = q - p                 # (N,M,2)
-    rxs = np.cross(pr, qs)    # (N,M)
-    t = np.cross(d, qs)
-    u = np.cross(d, pr)
-    with np.errstate(divide="ignore", invalid="ignore"):
-        tt = t / rxs
-        uu = u / rxs
-    hit = (rxs != 0) & (tt >= 0) & (tt <= 1) & (uu >= 0) & (uu <= 1)
-    return bool(hit.any())

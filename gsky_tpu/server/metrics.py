@@ -63,6 +63,9 @@ def _resolve_cache_handles():
         handles.append(("mas_rows", lambda cls=cls: {
             "hits": cls.total_row_hits,
             "misses": cls.total_row_misses}))
+        handles.append(("mas_footprints", lambda cls=cls: {
+            "hits": cls.total_footprint_hits,
+            "misses": cls.total_footprint_misses}))
     except Exception:  # tier absent in this build - skip its counters
         pass
     try:

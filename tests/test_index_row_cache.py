@@ -427,9 +427,10 @@ def test_executes_give_the_bytes_of_a_fresh_parse(served):
 
 
 def _kept(store):
+    records = {rid: e.record for rid, e in store._rows[1].items()}
     return {rid: (rec, rec["timestamps"], rec.unix, rec["geo_transform"],
                   rec["axes"], rec["means"])
-            for rid, (_, rec) in store._rows[1].items()}
+            for rid, rec in records.items()}
 
 
 def test_shared_lists_are_unchanged_after_a_drill_and_a_tile(served):
