@@ -486,6 +486,41 @@ def _resample_c(src, nodata, rows, cols, method: str):
     return out, ok
 
 
+def _mosaic_band_sets(granules, ctrl, params, prios, method: str,
+                      out_hw: Tuple[int, int], step: int, win, win0):
+    """The warp + mosaic the channel-packed kernels share: from the band
+    scenes of G granule sets (each a C-tuple of (sh, sw) arrays on one
+    grid) to (data (h, w, C) f32, best (h, w, C) f32): warp indices and
+    tap weights once a set, C-vector gathers from the packed gather
+    windows, and per channel the valid tap of the highest priority
+    (``best`` is -inf where no set holds the channel)."""
+    h, w = out_hw
+    C = len(granules[0])
+    sx = _bilerp_grid(ctrl[0], h, w, step)
+    sy = _bilerp_grid(ctrl[1], h, w, step)
+    data = jnp.zeros((h, w, C), jnp.float32)
+    best = jnp.full((h, w, C), -jnp.inf, jnp.float32)
+    for k, bands in enumerate(granules):
+        p = params[k]
+        cols = (p[0] + p[1] * sx + p[2] * sy) - 0.5
+        rows = (p[3] + p[4] * sx + p[5] * sy) - 0.5
+        oob = (rows < -0.5) | (rows > p[6] - 0.5) \
+            | (cols < -0.5) | (cols > p[7] - 0.5)
+        rows = jnp.where(oob, jnp.nan, rows)
+        if win is not None:
+            cut = [_window_slice(b, win, win0[k], axis=0) for b in bands]
+            bands = [c[0] for c in cut]
+            rows = rows - cut[0][1]
+            cols = cols - cut[0][2]
+        d, o = _resample_c(jnp.stack(bands, axis=-1), p[8], rows, cols,
+                           method)
+        score = jnp.where(o, prios[k], -jnp.inf)
+        take = score > best
+        data = jnp.where(take, d, data)
+        best = jnp.where(take, score, best)
+    return data, best
+
+
 @functools.partial(jax.jit,
                    static_argnames=("method", "out_hw", "step", "auto",
                                     "colour_scale", "win"))
@@ -519,29 +554,8 @@ def render_rgba_ctrl(granules, ctrl, params, prios, scale_params,
     scale_params (3,) as elsewhere.
     """
     from .scale import auto_byte_scale, scale_to_byte
-    h, w = out_hw
-    sx = _bilerp_grid(ctrl[0], h, w, step)
-    sy = _bilerp_grid(ctrl[1], h, w, step)
-    data = jnp.zeros((h, w, 3), jnp.float32)
-    best = jnp.full((h, w, 3), -jnp.inf, jnp.float32)
-    for k, bands in enumerate(granules):
-        p = params[k]
-        cols = (p[0] + p[1] * sx + p[2] * sy) - 0.5
-        rows = (p[3] + p[4] * sx + p[5] * sy) - 0.5
-        oob = (rows < -0.5) | (rows > p[6] - 0.5) \
-            | (cols < -0.5) | (cols > p[7] - 0.5)
-        rows = jnp.where(oob, jnp.nan, rows)
-        if win is not None:
-            cut = [_window_slice(b, win, win0[k], axis=0) for b in bands]
-            bands = [c[0] for c in cut]
-            rows = rows - cut[0][1]
-            cols = cols - cut[0][2]
-        d, o = _resample_c(jnp.stack(bands, axis=-1), p[8], rows, cols,
-                           method)
-        score = jnp.where(o, prios[k], -jnp.inf)
-        take = score > best
-        data = jnp.where(take, d, data)
-        best = jnp.where(take, score, best)
+    data, best = _mosaic_band_sets(granules, ctrl, params, prios, method,
+                                   out_hw, step, win, win0)
     ok = best > -jnp.inf
     if auto:
         if colour_scale == 1:
@@ -564,6 +578,48 @@ def render_rgba_ctrl(granules, ctrl, params, prios, scale_params,
     alpha = jnp.where(jnp.all(rgb == jnp.uint8(255), axis=-1),
                       jnp.uint8(0), jnp.uint8(255))
     return jnp.concatenate([rgb, alpha[..., None]], axis=-1)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("fp", "method", "out_hw", "step",
+                                    "auto", "colour_scale", "win"))
+def render_expr_ctrl(granules, ctrl, params, prios, scale_params, consts,
+                     fp: tuple, method: str = "near",
+                     out_hw: Tuple[int, int] = (256, 256),
+                     step: int = 16, auto: bool = True,
+                     colour_scale: int = 0,
+                     win: Optional[Tuple[int, int]] = None, win0=None):
+    """Band-algebra fast path in `render_rgba_ctrl`'s form: one dispatch
+    from the band scenes of G granule sets, each a C-tuple of (sh, sw)
+    arrays on one grid as the scene cache holds them (slot i of the
+    tuple is variable i of the expression), to the PNG-ready (h, w)
+    uint8 tile, 255 = no data.  The per-channel newest-wins mosaic is
+    `_mosaic_band_sets`; the expression is evaluated AFTER it, as the
+    merger does (`processor/tile_merger.go:523-731`), by the traced
+    epilogue every fused leg shares (`ops.paged.expr_epilogue`: valid
+    where every referenced slot is and the result is finite,
+    `CompiledExpr.eval_masked`'s rule), then `scale_to_byte`.
+
+    The kernel takes unstacked scenes because a stack is a copy: at
+    Sentinel-2 granule size a band is 484.7 MB on the device, so the
+    (n, bh, bw) stack `warp_scenes_ctrl_scored` asks for is 0.97 GB for
+    NDVI over one granule and 3.88 GB over four, beside 5.8 GB of
+    resident scenes.  Only the gather windows are packed here.
+
+    ``fp`` (static) is the normalized fingerprint key
+    (`ops.expr.fingerprint`): one program a structure, a window bucket
+    and a granule count, never one an expression string; ``consts``
+    (K,) f32 are its lifted literals.  params, prios (G, C), win0 (G, 2)
+    and scale_params as `render_rgba_ctrl`."""
+    from .paged import expr_epilogue
+    from .scale import scale_to_byte
+    data, best = _mosaic_band_sets(granules, ctrl, params, prios, method,
+                                   out_hw, step, win, win0)
+    plane, ok = expr_epilogue(jnp.moveaxis(data, -1, 0)[None],
+                              jnp.moveaxis(best, -1, 0)[None], fp,
+                              consts[None])
+    return scale_to_byte(plane[0], ok[0], scale_params[0], scale_params[1],
+                         scale_params[2], colour_scale, auto)
 
 
 @functools.partial(jax.jit,

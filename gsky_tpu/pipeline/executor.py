@@ -28,6 +28,7 @@ from ..ops.pallas_tpu import render_byte_raced, warp_scored_raced
 from ..ops.warp import (combine_scored, render_scenes_bands_ctrl,
                         warp_gather_batch)
 from ..mesh.dispatch import compat_spmd
+from ..obs import set_attr as obs_set_attr
 from .decode import DecodedWindow
 
 # padded source-window shape buckets (H and W independently bucketed)
@@ -216,6 +217,62 @@ def _inv_gt_params(gt: GeoTransform, ox: float, oy: float):
     return (a0, inv[0], inv[1], a3, inv[2], inv[3])
 
 
+def _complete_sets(grids, ns_ids, n_chan: int):
+    """Band sets of a granule list: ``grids[i]`` names the grid granule
+    i lies on, ``ns_ids[i]`` its channel.  Returns one list per grid,
+    in first-seen order, of the granule index of each channel
+    0..n_chan-1, or None unless every grid holds exactly one granule
+    per channel (two dates on one grid, or a grid that lacks a band,
+    are the per-band kernels')."""
+    sets: Dict[tuple, Dict[int, int]] = {}
+    for i, grid in enumerate(grids):
+        members = sets.setdefault(grid, {})
+        if ns_ids[i] in members:
+            return None
+        members[ns_ids[i]] = i
+    want = list(range(n_chan))
+    if not sets or any(sorted(m) != want for m in sets.values()):
+        return None
+    return [[m[c] for c in want] for m in sets.values()]
+
+
+class BandSets(NamedTuple):
+    """What a channel-packed kernel (`ops.warp.render_rgba_ctrl`,
+    `render_expr_ctrl`) takes for G granule sets of C bands each."""
+    bands: tuple                # G C-tuples of scene arrays, as cached
+    params: np.ndarray          # (G, 11) f32, a row a set
+    prios: np.ndarray           # (G, C) f32, -inf rows are padding
+    key: tuple                  # (G, bh, bw, C): the dispatch key's shape
+    win: Optional[Tuple[int, int]]
+    win0: Optional[np.ndarray]  # (G, 2)
+
+
+def _band_sets(chans, rows, chan_prios, windows) -> BandSets:
+    """Kernel operands for ``chans``, a list of sets, each the C
+    scenes (`DeviceScene`s of one bucket) of one grid in channel order:
+    ``rows[k]`` is set k's (affine6, height, width, nodata) param row,
+    ``chan_prios[k]`` its C mosaic priorities.  G is a power of two
+    (bounded jit variants): the filling repeats the first set with a
+    priority that never wins.  ``windows(params64)`` gives the gather
+    windows, (win, win0 (G, 2)) or None, of the SAME param rows the
+    kernel consumes."""
+    G = _bucket_pow2(len(chans))
+    C = len(chans[0])
+    bands = tuple(tuple(s.dev for s in got) for got in chans)
+    bands += (bands[0],) * (G - len(chans))
+    params = np.zeros((G, 11), np.float64)
+    params[:, 10] = -1.0
+    for k, row in enumerate(rows):
+        params[k, :9] = row
+        params[k, 10] = 0.0
+    prios = np.full((G, C), -np.inf, np.float32)
+    prios[:len(chans)] = chan_prios
+    win, win0 = (windows(params) if _window_mode() else None) \
+        or (None, None)
+    return BandSets(bands, params.astype(np.float32), prios,
+                    (G,) + tuple(chans[0][0].dev.shape) + (C,), win, win0)
+
+
 class SceneGroup(NamedTuple):
     """What `_scene_groups` hands a fused scene kernel for one group of
     granules on one source grid."""
@@ -274,6 +331,9 @@ class WarpExecutor:
                 self.win_declined += 1
 
     def _count(self, path: str, bucket=None) -> None:
+        """One dispatch through leg ``path``; the open span (the staged
+        tile path's `tile.dispatch`) carries the leg's name."""
+        obs_set_attr(leg=path)
         key = f"{path}:{bucket}" if bucket is not None else path
         with self._lock:
             self.bucket_stats[key] = self.bucket_stats.get(key, 0) + 1
@@ -727,25 +787,35 @@ class WarpExecutor:
                          clip: float = 0.0, colour_scale: int = 0,
                          auto: bool = True, cache=None):
         """Fused band-algebra fast path (GSKY_EXPR_FUSE): cached scenes
-        -> one paged program that gathers EVERY referenced band's
-        window, interpolates each, evaluates the expression as a traced
+        -> one program that gathers EVERY referenced band's window,
+        interpolates each, evaluates the expression as a traced
         epilogue and scales to byte — no per-band mosaic dispatches, no
-        f32 plane round-trips through HBM.
+        f32 plane round-trips through HBM.  On the bucketed leg (a
+        TPU's) that program is `ops.warp.render_expr_ctrl` over the
+        scene cache's own arrays (`_render_expr_sets`); the wave and
+        paged legs run the paged epilogue.
 
         ``ns_ids`` are fingerprint SLOT indices (variable i of ``fp``
         is mosaic slot i); ``fp`` is the `ops.expr.ExprFingerprint`.
         Returns a uint8 (H, W) array or None — the caller then runs
         the unfused `evaluate_expressions` leg (multi-CRS granule sets,
-        page budget, SPMD compat mode: the epilogue exists in paged
-        form only)."""
+        granules that form no band sets, page budget, SPMD compat
+        mode)."""
         g = self._scene_inputs(granules, ns_ids, prios, dst_gt,
-                               dst_crs, height, width, cache)
+                               dst_crs, height, width, cache,
+                               stacked=False)
         if g is None:
             return None
         n_pad = _bucket_pow2(n_slots)
         leg, how = self._choose_leg(g, n_pad, lane_union=True)
-        if leg in ("spmd", "bucketed"):
+        if leg == "spmd":
             return None
+        if leg == "bucketed":
+            return self._render_expr_sets(
+                g, n_slots, fp, method, (height, width), offset, scale,
+                clip, colour_scale, auto)
+        # the paged forms race a stacked XLA reference
+        g = self._stacked(g, cache)
         sp = np.array([offset, scale, clip], np.float32)
         consts = fp.const_array()
         statics = (method, n_pad, (height, width), g.step, auto,
@@ -787,6 +857,48 @@ class WarpExecutor:
         return self._run_leg(leg, how, g, "render_expr", _prefetch,
                              lambda: _unfused_xla()[0],
                              wave=wave, paged=paged)
+
+    def _render_expr_sets(self, g: "SceneGroup", n_slots: int, fp,
+                          method: str, out_hw: Tuple[int, int],
+                          offset: float, scale: float, clip: float,
+                          colour_scale: int, auto: bool):
+        """`render_expr_byte`'s bucketed leg: the unstacked group's
+        scenes as band sets, one per grid with a scene per slot (rows
+        of one grid share their affine, size and nodata), through ONE
+        `ops.warp.render_expr_ctrl` dispatch.  None where the scenes
+        form no such sets (a grid that lacks a variable's band, two
+        dates on one grid): the unfused leg's."""
+        n = len(g.scenes)
+        p64 = g.params64
+        sets = _complete_sets([p64[k, :9].tobytes() for k in range(n)],
+                              [int(p64[k, 10]) for k in range(n)],
+                              n_slots)
+        if sets is None:
+            return None
+
+        def windows(params):
+            # the group's own: a set's scenes share one row, so one
+            # footprint, and the group made a window for each scene
+            if g.win is None:
+                return None
+            win0 = np.zeros((len(params), 2), np.int32)
+            win0[:len(sets)] = g.win0[[m[0] for m in sets]]
+            return g.win, win0
+
+        b = _band_sets([[g.scenes[i] for i in m] for m in sets],
+                       [p64[m[0], :9] for m in sets],
+                       [[p64[i, 9] for i in m] for m in sets], windows)
+        from ..ops.paged import note_expr_fused
+        from ..ops.warp import render_expr_ctrl
+        self._count("render_expr", (b.key, b.win))
+        self._note_win(b.win)
+        note_expr_fused("bucketed")
+        sp = np.array([offset, scale, clip], np.float32)
+        return _prefetch(render_expr_ctrl(
+            b.bands, g.ctrl_dev, jnp.asarray(b.params),
+            jnp.asarray(b.prios), jnp.asarray(sp),
+            jnp.asarray(fp.const_array()), fp.key, method, out_hw, g.step,
+            auto, colour_scale, win=b.win, win0=_dev_win0(b.win0)))
 
     def render_bands_byte(self, granules, ns_ids: Sequence[int],
                           prios: Sequence[float], dst_gt: GeoTransform,
@@ -836,17 +948,11 @@ class WarpExecutor:
         falls back to the per-band path)."""
         if len(out_sel) != 3 or sorted(out_sel) != [0, 1, 2]:
             return None
-        # grid -> {namespace id: granule index}: every set one granule
-        # per band (two dates on one grid are the per-band kernel's)
-        sets: Dict[tuple, Dict[int, int]] = {}
-        for i, g in enumerate(granules):
-            if g.geo_loc or g.srs != granules[0].srs:
-                return None
-            members = sets.setdefault(tuple(g.geo_transform), {})
-            if ns_ids[i] in members:
-                return None
-            members[ns_ids[i]] = i
-        if not sets or any(sorted(m) != [0, 1, 2] for m in sets.values()):
+        if any(g.geo_loc or g.srs != granules[0].srs for g in granules):
+            return None
+        sets = _complete_sets([tuple(g.geo_transform) for g in granules],
+                              ns_ids, 3)
+        if sets is None:
             return None
         from ..geo.crs import parse_crs
         from .scene_cache import default_scene_cache
@@ -863,7 +969,7 @@ class WarpExecutor:
         # out_sel maps expression order -> ns id: channel k of a set
         # comes from its granule whose ns id equals out_sel[k]
         chans, chan_prios = [], []
-        for members in sets.values():
+        for members in sets:
             picked = [members[ns] for ns in out_sel]
             got = [cache.get(granules[i], stride, dst_bbox=rgba_bbox,
                              dst_crs=dst_crs) for i in picked]
@@ -889,34 +995,19 @@ class WarpExecutor:
             ctrl_dev = jnp.asarray(
                 np.stack([sx - ox, sy - oy]).astype(np.float32))
             self._geo_cache_put(dkey, ctrl_dev)
-        # G is a power of two (bounded jit variants): the filling
-        # repeats the first set with a priority that never wins
-        G = _bucket_pow2(len(chans))
-        bands = tuple(tuple(s.dev for s in got) for got in chans)
-        bands += (bands[0],) * (G - len(chans))
-        params = np.zeros((G, 11), np.float64)
-        params[:, 10] = -1.0
-        for k, got in enumerate(chans):
-            params[k, :6] = _inv_gt_params(got[0].gt, ox, oy)
-            params[k, 6:9] = (s0.height, s0.width, s0.nodata)
-            params[k, 10] = 0.0
-        prio = np.full((G, 3), -np.inf, np.float32)
-        prio[:len(chans)] = chan_prios
-        win = win0 = None
-        if _window_mode():
-            # window bounds from the SAME param rows the kernel consumes
-            made_w = _gather_windows(params, sx - ox, sy - oy, *s0.bucket)
-            if made_w is not None:
-                win, win0 = made_w
+        b = _band_sets(
+            chans, [_inv_gt_params(got[0].gt, ox, oy)
+                    + (s0.height, s0.width, s0.nodata) for got in chans],
+            chan_prios, lambda params: _gather_windows(
+                params, sx - ox, sy - oy, *s0.bucket))
         from ..ops.warp import render_rgba_ctrl
-        self._count("render_rgba", ((G,) + s0.bucket + (3,), win))
-        self._note_win(win)
+        self._count("render_rgba", (b.key, b.win))
+        self._note_win(b.win)
         sp = np.array([offset, scale, clip], np.float32)
         return _prefetch(render_rgba_ctrl(
-            bands, ctrl_dev, jnp.asarray(params.astype(np.float32)),
-            jnp.asarray(prio), jnp.asarray(sp),
-            method, (height, width), step, auto, colour_scale,
-            win=win, win0=_dev_win0(win0)))
+            b.bands, ctrl_dev, jnp.asarray(b.params), jnp.asarray(b.prios),
+            jnp.asarray(sp), method, (height, width), step, auto,
+            colour_scale, win=b.win, win0=_dev_win0(b.win0)))
 
     def _note_paged(self, engaged: bool) -> None:
         with self._lock:
@@ -1175,20 +1266,39 @@ class WarpExecutor:
 
             skey = tuple(s.serial for s in gs) + (B,)
             devs = tuple(s.dev for s in gs) + (s0.dev,) * (B - len(gs))
-            stack = cache.stack(skey, lambda devs=devs: jnp.stack(devs)) \
-                if stacked else devs
             win = win0 = None
-            if _window_mode():
-                made_w = (_gather_window if stacked else _gather_windows)(
+            if _window_mode() and not stacked:
+                made_w = _gather_windows(
                     params, np.asarray(ctrl[0], np.float64),
                     np.asarray(ctrl[1], np.float64), *s0.bucket)
                 if made_w is not None:
                     win, win0 = made_w
-            groups.append(SceneGroup(
-                stack=stack, ctrl=ctrl, ctrl_dev=ctrl_dev,
+            group = SceneGroup(
+                stack=devs, ctrl=ctrl, ctrl_dev=ctrl_dev,
                 params=params.astype(np.float32), params64=params,
-                step=step, skey=skey, win=win, win0=win0, scenes=gs))
+                step=step, skey=skey, win=win, win0=win0, scenes=gs)
+            groups.append(self._stacked(group, cache) if stacked
+                          else group)
         return groups
+
+    @staticmethod
+    def _stacked(group: "SceneGroup", cache) -> "SceneGroup":
+        """The group with its scenes stacked: ``stack`` the (B, bh, bw)
+        copy the scene cache keeps and charges to its budget, and one
+        gather window over the whole stack."""
+        from .scene_cache import default_scene_cache
+        devs = group.stack
+        win = win0 = None
+        if _window_mode():
+            made_w = _gather_window(
+                group.params64, np.asarray(group.ctrl[0], np.float64),
+                np.asarray(group.ctrl[1], np.float64), *devs[0].shape)
+            if made_w is not None:
+                win, win0 = made_w
+        return group._replace(
+            stack=(cache or default_scene_cache).stack(
+                group.skey, lambda: jnp.stack(devs)),
+            win=win, win0=win0)
 
 
 # module-level default executor (compile cache shared across requests)

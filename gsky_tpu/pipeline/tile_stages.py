@@ -256,6 +256,8 @@ def render_staged(pipe, req, n_exprs: int,
         else:
             made = pipe._bands_prep(req, stats=stats, spans=spans)
         psp.set(qualified=made is not None)
+        if made is not None and len(made) == 5:     # `_expr_prep` form
+            psp.set(expr=made[4].hash, slots=made[3])
     # "plan" is the prep minus the index query it contains
     spans["plan_s"] = spans.get("plan_s", 0.0) \
         + max(0.0, time.perf_counter() - t0 - spans.get("index_s", 0.0))
