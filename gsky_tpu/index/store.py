@@ -198,6 +198,96 @@ class _KeptRow:
         self.record: Optional[GdalRecord] = None
 
 
+_ALL_ROWS = ("SELECT datasets.*, rt.xmin, rt.xmax, rt.ymin, rt.ymax"
+             " FROM datasets LEFT JOIN datasets_rtree AS rt"
+             " ON rt.id = datasets.id ORDER BY datasets.id")
+# LIKE folds the ASCII letters and no others
+_LIKE_FOLD = {c: c + 32 for c in range(ord("A"), ord("Z") + 1)}
+
+
+class _GenerationRows:
+    """Every dataset row of one generation of a store in memory, and the
+    columns `intersects` selects on as arrays over the rows, so a query
+    picks its candidates by a few vector comparisons and runs no
+    statement (at the price of the rows twice in memory, sqlite's and
+    these tuples: ~1.6 s and ~170 MB to build at 100,000 rows,
+    once a generation).  The predicates are the statement's: the box is the
+    R*Tree's own (float32, rounded outwards; NaN for a row the tree
+    leaves out, which then meets no box), a NULL stamp is NaN and passes
+    no time test, the path test is LIKE's prefix match (it folds the
+    ASCII letters).  The order is the statement's plan's: by (namespace,
+    id) under a namespace filter, which walks `idx_ds_ns` (every tile's
+    and every drill's query), and by id without one.  By id is how a
+    table scan answers and how the R*Tree hands out the cells of its one
+    node while the store holds up to 51 boxes; past that the tree walks
+    its nodes, an order that depends on the history of its splits and
+    that no array reproduces: a box query without namespaces then gets
+    the same rows by id, and its `limit` cuts there."""
+
+    _PREFIXES_MAX = 64
+
+    def __init__(self, generation: int, joined: List[tuple],
+                 i_path: int, i_ns: int, i_stamps: int):
+        self.generation = generation
+        self.rows = [r[:-4] for r in joined]
+        num = np.array([r[-4:] + r[i_stamps:i_stamps + 2] for r in joined],
+                       np.float64).reshape(len(joined), 6)
+        self.xmin, self.xmax, self.ymin, self.ymax, self.min_stamp, \
+            self.max_stamp = num.T.copy()
+        # OVERLAPS' second of slack, as the statement adds it
+        self._ends, self._begins = self.max_stamp + 1, self.min_stamp - 1
+        # names in idx_ds_ns's order, so a code sorts as its name does;
+        # the last slot of a query's table answers for NULL (-1)
+        self.ns_code = {n: k for k, n in enumerate(sorted(
+            {r[i_ns] for r in joined if r[i_ns] is not None}))}
+        self.ns = np.array([self.ns_code.get(r[i_ns], -1) for r in joined],
+                           np.intp)
+        codes: Dict[str, int] = {}
+        self._path_of = np.array(
+            [codes.setdefault(r[i_path], len(codes)) for r in joined],
+            np.intp)
+        self._paths = [p.translate(_LIKE_FOLD) for p in codes]
+        self._prefixes: Dict[str, np.ndarray] = {}
+        self._prefixes_lock = threading.Lock()
+
+    def under(self, gpath: str) -> np.ndarray:
+        """Mask of the rows whose path `LIKE gpath%`, made the first
+        time the prefix is asked for."""
+        mask = self._prefixes.get(gpath)
+        if mask is None:
+            prefix = gpath.translate(_LIKE_FOLD)
+            mask = np.array([p.startswith(prefix) for p in self._paths],
+                            bool)[self._path_of]
+            with self._prefixes_lock:
+                self._prefixes[gpath] = mask
+                while len(self._prefixes) > self._PREFIXES_MAX:
+                    del self._prefixes[next(iter(self._prefixes))]
+        return mask
+
+    def select(self, mask: np.ndarray, qb: Optional[BBox],
+               t_a: Optional[float], t_b: Optional[float],
+               namespaces: Optional[Sequence[str]]) -> List[tuple]:
+        """The rows of `mask` the statement's WHERE lets through, in the
+        statement's order."""
+        if qb is not None:
+            mask = mask & (self.xmax >= qb.xmin) & (self.xmin <= qb.xmax) \
+                & (self.ymax >= qb.ymin) & (self.ymin <= qb.ymax)
+        if t_a is not None and t_b is None:
+            mask = mask & (self.min_stamp <= t_a) & (self.max_stamp >= t_a)
+        elif t_a is not None:
+            mask = mask & (t_a < self._ends) & (self._begins < t_b)
+        if namespaces:
+            asked = np.zeros(len(self.ns_code) + 1, bool)
+            asked[[self.ns_code[n] for n in namespaces
+                   if n in self.ns_code]] = True
+            picked = np.flatnonzero(mask & asked[self.ns])
+            picked = picked[np.argsort(self.ns[picked], kind="stable")]
+        else:
+            picked = np.flatnonzero(mask)
+        rows = self.rows
+        return [rows[i] for i in picked.tolist()]
+
+
 _SCHEMA = """
 CREATE TABLE IF NOT EXISTS files(
     path TEXT PRIMARY KEY,
@@ -254,7 +344,14 @@ INSERT INTO datasets_rtree
 
 
 class MASStore:
-    """The index.  Thread-safe for concurrent reads."""
+    """The index.  Safe for concurrent reads beside a writer.  A file
+    database gives every thread a connection of its own and takes no
+    lock.  A store in memory has one connection, and `_lock` serialises
+    what runs on it: the writes, the set-up reads (`timestamps`,
+    `extents`, `list_files`) and the one read that builds a generation's
+    rows; `intersects` itself reads the generation as a number and
+    picks its candidates from that generation's arrays
+    (`_GenerationRows`), so a query takes no statement and no `_lock`."""
 
     _QUERY_CACHE_MAX = 1024
     # process-wide totals across store instances, reachable by the
@@ -271,6 +368,9 @@ class MASStore:
     # earlier under this generation (hit) or prepared for this query
     total_footprint_hits = 0
     total_footprint_misses = 0
+    # SQL statements run by the queries the answer cache missed (the
+    # read of the generation among them)
+    total_sql_statements = 0
     _totals_lock = threading.Lock()
     # rows kept per store, oldest out first.  A row of 1,000 stamps is
     # ~0.15 MB decoded; a tile archive's rows hold one stamp; a
@@ -293,12 +393,21 @@ class MASStore:
         self.row_misses = 0
         self.footprint_hits = 0
         self.footprint_misses = 0
+        self.sql_statements = 0
         self._local = threading.local()
         self._memory_conn: Optional[sqlite3.Connection] = None
         # a single :memory: connection is shared across threads, so every
-        # statement must serialise through _lock; file databases get one
-        # connection per thread instead and need no lock
+        # statement on it must serialise through _lock: the ingests and
+        # the reads named in the class's docstring.  Not a query: a
+        # thread back from `execute` waits for the GIL before it can let
+        # the lock go, so a lock every query takes is a convoy.  File
+        # databases get one connection per thread instead and no lock
         self._lock = threading.Lock()
+        # a store in memory is written by this process alone, so its
+        # generation is the number of ingests committed here: kept
+        # beside gsky_meta's, moved under _lock, read without it
+        self._generation = 0
+        self._held: Optional[_GenerationRows] = None
         if db_path == ":memory:":
             self._memory_conn = sqlite3.connect(":memory:",
                                                 check_same_thread=False)
@@ -312,6 +421,8 @@ class MASStore:
         self._i_id, self._i_path, self._i_srs, self._i_polygon = (
             self._columns.index(c)
             for c in ("id", "path", "srs", "polygon"))
+        self._i_ns, self._i_stamps = (
+            self._columns.index(c) for c in ("namespace", "min_stamp"))
         # bumped on every ingest; response caches key on it so cached
         # answers die with the data they were computed from.  Persisted
         # in sqlite (gsky_meta) so an ingest from ANOTHER process against
@@ -320,9 +431,10 @@ class MASStore:
 
     @property
     def generation(self) -> int:
-        with self._maybe_lock():
-            row = self._conn().execute(
-                "SELECT v FROM gsky_meta WHERE k = 'generation'").fetchone()
+        if self._memory_conn is not None:
+            return self._generation
+        row = self._conn().execute(
+            "SELECT v FROM gsky_meta WHERE k = 'generation'").fetchone()
         return int(row[0]) if row else 0
 
     def _maybe_lock(self):
@@ -349,20 +461,7 @@ class MASStore:
         "geo_metadata": [...]}.  Returns number of datasets indexed.
         (The bash ingest pipeline `mas/db/shard_ingest.sh` analogue is a
         loop over these.)"""
-        path = record.get("filename") or record.get("file_path")
-        if not path:
-            raise ValueError("record missing filename")
-        with self._maybe_lock():
-            try:
-                self._conn().execute(
-                    "UPDATE gsky_meta SET v = v + 1 WHERE k = 'generation'")
-                return self._ingest_locked(record, path)
-            except BaseException:
-                # a half-ingested record must not linger in the open
-                # implicit transaction, where the next successful ingest
-                # would commit it
-                self._conn().rollback()
-                raise
+        return self.ingest_many([record])
 
     def ingest_many(self, records) -> int:
         """Batch ingest under ONE transaction + one generation bump —
@@ -379,16 +478,22 @@ class MASStore:
                     path = record.get("filename") or record.get("file_path")
                     if not path:
                         raise ValueError("record missing filename")
-                    n += self._ingest_locked(record, path, commit=False)
+                    n += self._ingest_locked(conn, record, path)
                 conn.commit()
             except BaseException:
+                # a half-ingested record must not linger in the open
+                # implicit transaction, where the next successful ingest
+                # would commit it
                 conn.rollback()
                 raise
+            if self._memory_conn is not None:
+                # with the commit and not with the UPDATE: a query never
+                # reads a generation that may yet be rolled back
+                self._generation += 1
         return n
 
-    def _ingest_locked(self, record: Dict, path: str,
-                       commit: bool = True) -> int:
-        conn = self._conn()
+    def _ingest_locked(self, conn: sqlite3.Connection, record: Dict,
+                       path: str) -> int:
         conn.execute("INSERT OR REPLACE INTO files(path, file_type, meta) "
                      "VALUES (?,?,?)",
                      (path, record.get("file_type", ""), json.dumps(record)))
@@ -443,8 +548,6 @@ class MASStore:
                  json.dumps(ds.get("overviews"))
                  if ds.get("overviews") else None))
             n += 1
-        if commit:
-            conn.commit()
         return n
 
     # -- queries -------------------------------------------------------------
@@ -470,7 +573,9 @@ class MASStore:
         question for every zoom-level repeat.  Any ingest bumps the
         generation (even from another process against the same file
         DB), so cached answers and kept rows die with the data they
-        were computed from."""
+        were computed from.  A query reads the generation once; on a
+        store in memory that is a number, and the candidate rows come
+        from that generation's arrays (`_candidates`)."""
         generation = self.generation
         ckey = (gpath, srs, wkt, nseg, time, until,
                 tuple(namespaces) if namespaces else None, metadata,
@@ -514,11 +619,43 @@ class MASStore:
         t_a = parse_time(time) if time else None
         t_b = parse_time(until) if until else None
 
+        rows = self._candidates(generation, gpath,
+                                query.bbox if query is not None else None,
+                                t_a, t_b, namespaces)
         if query is not None:
+            rows = self._refine(rows, query, generation, limit)
+        elif limit:
+            rows = rows[:limit]
+
+        if metadata != "gdal":
+            return self._cache_put(
+                ckey, {"files": sorted({r[self._i_path] for r in rows})})
+        return self._cache_put(
+            ckey, {"gdal": self._records(rows, generation)})
+
+    def _candidates(self, generation: int, gpath: str, qb: Optional[BBox],
+                    t_a: Optional[float], t_b: Optional[float],
+                    namespaces: Optional[Sequence[str]]) -> List[tuple]:
+        """The dataset rows under `gpath` that pass the box `qb` (None:
+        no geometry, no box test), the time test and the namespace
+        filter.  The two kinds of store reach them their own way: one in
+        memory from its generation's arrays, a file database by the
+        statement; the same rows in the same order, and everything
+        after this step is shared."""
+        if self._memory_conn is not None:
+            held = self._generation_rows(generation)
+            return held.select(held.under(gpath), qb, t_a, t_b, namespaces)
+        self._count_statements(2)       # the generation's, and this one
+        return self._select(gpath, qb, t_a, t_b, namespaces)
+
+    def _select(self, gpath: str, qb: Optional[BBox],
+                t_a: Optional[float], t_b: Optional[float],
+                namespaces: Optional[Sequence[str]]) -> List[tuple]:
+        """`_candidates` by the SQL statement."""
+        if qb is not None:
             # R*Tree walk instead of a table scan (GIST-index role);
             # NULL-bbox rows are absent from the tree, matching the old
             # prefilter's `xmin IS NULL` exclusion
-            qb = query.bbox
             sql = ("SELECT datasets.* FROM datasets"
                    " JOIN datasets_rtree AS rt ON datasets.id = rt.id"
                    " WHERE datasets.path LIKE ? ESCAPE '\\'"
@@ -539,17 +676,30 @@ class MASStore:
         if namespaces:
             sql += " AND namespace IN (%s)" % ",".join("?" * len(namespaces))
             args += list(namespaces)
-        rows = self._fetchall(sql, args)
-        if query is not None:
-            rows = self._refine(rows, query, generation, limit)
-        elif limit:
-            rows = rows[:limit]
+        return self._fetchall(sql, args)
 
-        if metadata != "gdal":
-            return self._cache_put(
-                ckey, {"files": sorted({r[self._i_path] for r in rows})})
-        return self._cache_put(
-            ckey, {"gdal": self._records(rows, generation)})
+    def _generation_rows(self, generation: int) -> _GenerationRows:
+        """The rows a store in memory holds for `generation`, read by
+        one statement under the lock the first time a query meets the
+        generation.  A query that read its generation before an ingest
+        nobody has built for yet is given the newer rows, as the
+        statement would have given it."""
+        held = self._held
+        if held is None or held.generation < generation:
+            with self._lock:
+                held = self._held
+                if held is None or held.generation != self._generation:
+                    held = self._held = _GenerationRows(
+                        self._generation,
+                        self._memory_conn.execute(_ALL_ROWS).fetchall(),
+                        self._i_path, self._i_ns, self._i_stamps)
+                    self._count_statements(1)
+        return held
+
+    def _count_statements(self, n: int) -> None:
+        with MASStore._totals_lock:
+            self.sql_statements += n
+            MASStore.total_sql_statements += n
 
     def _refine(self, rows: List[tuple], query: _Footprint,
                 generation: int, limit: int) -> List[tuple]:

@@ -123,6 +123,8 @@ def _collect_caches():
         from ..server.metrics import cache_stats
         hits, misses = [], []
         for cache, st in (cache_stats() or {}).items():
+            if "hits" not in st:    # mas_sql counts statements, not hits
+                continue
             hits.append(({"cache": cache}, float(st.get("hits", 0))))
             misses.append(({"cache": cache}, float(st.get("misses", 0))))
         if hits:

@@ -66,6 +66,9 @@ def _resolve_cache_handles():
         handles.append(("mas_footprints", lambda cls=cls: {
             "hits": cls.total_footprint_hits,
             "misses": cls.total_footprint_misses}))
+        handles.append(("mas_sql", lambda cls=cls: {
+            "queries": cls.total_query_misses,
+            "statements": cls.total_sql_statements}))
     except Exception:  # tier absent in this build - skip its counters
         pass
     try:

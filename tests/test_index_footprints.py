@@ -177,21 +177,20 @@ def oracle(row, query):
 
 class Watched:
     """A store whose `intersects` calls are written down with the
-    candidate rows their SQL selected, per thread: the refinement is held
-    to the oracle over exactly those rows, in their order."""
+    candidate rows their candidate step selected (the statement's rows,
+    or a store in memory's from its arrays), per thread: the refinement
+    is held to the oracle over exactly those rows, in their order."""
 
     def __init__(self, store):
         self.store = store
         self.calls = []
         self._local = threading.local()
-        fetchall = store._fetchall
+        candidates = store._candidates
 
-        def watched(sql, args=()):
-            rows = fetchall(sql, args)
-            if sql.startswith("SELECT datasets.*"):
-                self._local.rows = rows
+        def watched(*args):
+            rows = self._local.rows = candidates(*args)
             return rows
-        store._fetchall = watched
+        store._candidates = watched
 
     def intersects(self, gpath, **kw):
         self._local.rows = None
