@@ -32,13 +32,25 @@ jointly, fetch shared inputs once):
   they enter the encode queue, so the pull overlaps the next tile's
   warp.
 
-* **Observability** — per-stage busy seconds, queue high-water marks
-  and dedup counts come back as a stats dict; the OWS server folds them
-  into `server.metrics.MetricsLogger` and `/debug` serves them under
-  ``export_pipeline``.
+* **Observability** — per-stage busy seconds (`plan_s`, `decode_s`,
+  `warp_s`, `encode_s`), queue high-water marks, dedup counts, the
+  tiles rendered from resident scenes against those that fell to the
+  host-decoded window (`tiles_resident` / `tiles_fallback`, one
+  `export.tile` span a tile carrying its route) and the bytes the
+  encoders pulled off the device (`readback_bytes`) come back as a
+  stats dict; the OWS server adds `write_s` (the assembly after the
+  engine, span `export.write`), folds the dict once per answered export
+  into `server.metrics.MetricsLogger`, and `/debug` serves the sums
+  under ``export_pipeline``.
 
-Escape hatch: ``GSKY_EXPORT_PIPELINE=0`` restores the per-tile serial
-path (read per request, so A/B benchmarking needs no restart).
+Only a coverage that `server/ows.py::_getcoverage` splits into more
+than one tile reaches this engine (`len(local_tiles) > 1`): a
+single-tile export renders on the per-tile path, so
+``export_pipeline.exports`` counts engine exports only.  The benchmark
+cell `landsat8-export.coverage-2k-cubic` (four 1024-px tiles an export)
+runs the engine.  ``GSKY_EXPORT_PIPELINE=0`` restores the per-tile
+serial path (read per request): an escape hatch, not measured on the
+chip by any cell.
 
 Knobs: ``GSKY_EXPORT_DECODE_WORKERS`` (default 4),
 ``GSKY_EXPORT_ENCODE_WORKERS`` (default 4),
@@ -147,6 +159,9 @@ class ExportPipeline:
         # scene keys whose memo decode RAISED (vs. merely not
         # intersecting): feeds the partial-failure degradation policy
         self._memo_failed: set = set()
+        # route -> tiles rendered that way; a batch renders concurrently
+        self._routes: Dict[str, int] = {}
+        self._routes_lock = threading.Lock()
         # tile index -> co-submission batch id (filled by _plan)
         self._batch_of: List[int] = list(range(len(self.tiles)))
         self.stats: Dict[str, object] = {}
@@ -337,24 +352,39 @@ class ExportPipeline:
     # -- stage 2: warp (runs on the caller's thread) -------------------------
 
     def _render_tile(self, req, gs: List[Granule]):
-        """Render one tile from pre-warmed sources — the engine-side
-        twin of `TilePipeline._render_fused`, with the decode fallback
-        replaced by the export-wide memo windows."""
+        """One tile under its `export.tile` span, which carries the
+        route `_render_route` took: `resident` (the fused kernel over
+        scenes the device holds), `fallback` (the export-wide
+        host-decoded windows), `modular` or `empty`.  The first two
+        reach the stats (`tiles_resident` / `tiles_fallback`)."""
+        with obs_span("export.tile") as sp:
+            res, route = self._render_route(req, gs)
+            sp.set(route=route)
+        with self._routes_lock:
+            self._routes[route] = self._routes.get(route, 0) + 1
+        return res
+
+    def _render_route(self, req, gs: List[Granule]):
+        """(result, route): render one tile from pre-warmed sources —
+        the engine-side twin of `TilePipeline._render_fused`, with the
+        decode fallback replaced by the export-wide memo windows."""
         exprs = req.band_exprs
         H, W = req.height, req.width
         if not gs:
-            return _empty_result(exprs, H, W)
+            return _empty_result(exprs, H, W), "empty"
         if self.pipe.remote is not None or req.mask is not None:
             # modular path (mask bands / worker fan-out): the pipeline
             # still gets plan-once indexing and stage overlap; window
             # dedup is the scene cache's business on this route
-            return self.pipe.render(req, gs)
+            return self.pipe.render(req, gs), "modular"
         ex = self.pipe.executor
         names, ns_ids, prio = ns_prio(gs)
+        route = "resident"
         sc = ex.warp_mosaic_scenes(gs, ns_ids, prio, req.dst_gt(),
                                    req.crs, H, W, len(names),
                                    req.resample)
         if sc is None:
+            route = "fallback"
             ws = [self._memo_window(g) if not g.geo_loc else None
                   for g in gs]
             # this runs on the warp stage (the request's to_thread
@@ -365,7 +395,7 @@ class ExportPipeline:
             check_partial(failed, len(gs), "decode")
             live = [(g, w) for g, w in zip(gs, ws) if w is not None]
             if not live:
-                return _empty_result(exprs, H, W)
+                return _empty_result(exprs, H, W), route
             names, ns_ids, prio = ns_prio([g for g, _ in live])
             sc = ex.warp_mosaic([w for _, w in live], ns_ids, prio,
                                 req.dst_gt(), req.crs, H, W,
@@ -376,7 +406,7 @@ class ExportPipeline:
         return evaluate_expressions(
             exprs, data_env, valid_env, H, W,
             granule_count=len(gs),
-            file_count=len({g.path for g in gs}))
+            file_count=len({g.path for g in gs})), route
 
     def _flush_batch(self, batch, q_encode, pool) -> bool:
         """Render one co-submission batch and hand the results to the
@@ -457,23 +487,28 @@ class ExportPipeline:
 
     # -- stage 3: encode / write ---------------------------------------------
 
-    def _encode_one(self, ox: int, oy: int, tw: int, th: int, res) -> None:
+    def _encode_one(self, ox: int, oy: int, tw: int, th: int, res) -> int:
+        """Pull one tile's planes to the host and into the sink; returns
+        the bytes pulled."""
+        pulled = 0
+        block = None
         if self.writer is not None:
             block = np.full((len(self.ns_names), th, tw), self.nodata,
                             np.float32)
-            for i, n in enumerate(self.ns_names):
-                if n in res.data:
-                    d = np.asarray(res.data[n])
-                    v = np.asarray(res.valid[n])
-                    block[i] = np.where(v, d, self.nodata)
+        for i, n in enumerate(self.ns_names):
+            if n not in res.data:
+                continue
+            d = np.asarray(res.data[n])
+            v = np.asarray(res.valid[n])
+            pulled += d.nbytes + v.nbytes
+            if block is not None:
+                block[i] = np.where(v, d, self.nodata)
+            else:
+                self.out[n][oy:oy + th, ox:ox + tw] = d
+                self.valid[n][oy:oy + th, ox:ox + tw] = v
+        if block is not None:
             self.writer.write_region(ox, oy, block)
-            return
-        for n in self.ns_names:
-            if n in res.data:
-                self.out[n][oy:oy + th, ox:ox + tw] = \
-                    np.asarray(res.data[n])
-                self.valid[n][oy:oy + th, ox:ox + tw] = \
-                    np.asarray(res.valid[n])
+        return pulled
 
     def _encode_stage(self, q_encode: queue.Queue, busy: List[float]
                       ) -> None:
@@ -484,7 +519,7 @@ class ExportPipeline:
                     return
                 (ox, oy, tw, th), res = item
                 t0 = time.monotonic()
-                self._encode_one(ox, oy, tw, th, res)
+                busy[1] += self._encode_one(ox, oy, tw, th, res)
                 busy[0] += time.monotonic() - t0
         except BaseException as e:     # noqa: BLE001
             self._fail(e)
@@ -508,10 +543,12 @@ class ExportPipeline:
         from ..resilience import current_token
         tok = current_token()
         unhook = tok.on_cancel(self.cancel) if tok else None
+        t_plan = time.monotonic()
         with obs_span("export.plan") as psp:
             plan = self._plan()
             psp.set(tiles=len(self.tiles),
                     granules=self.stats.get("granules", 0))
+        self.stats["plan_s"] = round(time.monotonic() - t_plan, 6)
         q_warp: queue.Queue = queue.Queue(self.queue_depth)
         q_encode: queue.Queue = queue.Queue(self.queue_depth)
 
@@ -533,7 +570,8 @@ class ExportPipeline:
             target=_traced("export.decode_stage",
                            self._decode_stage, plan, q_warp),
             name="gsky-export-plan", daemon=True)
-        enc_busy = [[0.0] for _ in range(self.encode_workers)]
+        # per encoder: [busy seconds, bytes pulled off the device]
+        enc_busy = [[0.0, 0] for _ in range(self.encode_workers)]
         encoders = [threading.Thread(
             target=_traced("export.encode_stage",
                            self._encode_stage, q_encode, enc_busy[i]),
@@ -563,6 +601,11 @@ class ExportPipeline:
         if self._stop.is_set():
             raise RuntimeError("export cancelled")
         self.stats["encode_s"] = round(sum(b[0] for b in enc_busy), 6)
+        self.stats["readback_bytes"] = sum(b[1] for b in enc_busy)
+        with self._routes_lock:
+            routes = dict(self._routes)
+        self.stats["tiles_resident"] = routes.get("resident", 0)
+        self.stats["tiles_fallback"] = routes.get("fallback", 0)
         self.stats["wall_s"] = round(time.monotonic() - t0, 6)
         refs = self.stats.get("granule_tile_refs", 0)
         self.stats["dedup_saved"] = max(

@@ -248,8 +248,9 @@ class MetricsLogger:
     # the latest value via the "last" snapshot
     _EXPORT_SUMS = ("tiles", "granules", "index_queries", "scenes_warmed",
                     "scenes_uncacheable", "windows_decoded",
-                    "granule_tile_refs", "dedup_saved", "decode_s",
-                    "warp_s", "encode_s", "wall_s")
+                    "granule_tile_refs", "dedup_saved", "plan_s",
+                    "decode_s", "warp_s", "encode_s", "write_s", "wall_s",
+                    "tiles_resident", "tiles_fallback", "readback_bytes")
     _EXPORT_MAXES = ("warp_queue_max", "encode_queue_max")
 
     def record_export(self, stats: Dict) -> None:
@@ -267,7 +268,8 @@ class MetricsLogger:
                         e[k] = max(e.get(k, 0), stats[k])
                 e["last"] = dict(stats)
             from ..obs.metrics import STAGE_SECONDS
-            for k in ("decode_s", "warp_s", "encode_s", "wall_s"):
+            for k in ("plan_s", "decode_s", "warp_s", "encode_s",
+                      "write_s", "wall_s"):
                 if k in stats:
                     STAGE_SECONDS.labels(
                         stage="export_" + k[:-2]).observe(stats[k])

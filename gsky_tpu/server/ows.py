@@ -127,6 +127,17 @@ from .params import (OWSError, infer_service, normalise_query, parse_wcs,
                      parse_wms, parse_wps)
 
 
+def export_temp_dir(path: str) -> str:
+    """The directory GetCoverage assembles its files in: ``path``, made
+    if it is not there yet (`-temp_dir` named a directory nobody had
+    made, and every GeoTIFF and NetCDF export answered 500: the first
+    thing the export cell's rehearsal found), or the system's."""
+    if not path:
+        return tempfile.gettempdir()
+    os.makedirs(path, exist_ok=True)
+    return path
+
+
 _GATEWAY_DEFAULT = object()     # sentinel: None means "no gateway"
 _FABRIC_DEFAULT = object()      # sentinel: None means "no fabric"
 
@@ -140,7 +151,7 @@ class OWSServer:
         self.mas_factory = mas_factory
         self.metrics = metrics or MetricsLogger()
         self.static_dir = static_dir
-        self.temp_dir = temp_dir or tempfile.gettempdir()
+        self.temp_dir = export_temp_dir(temp_dir)
         self._pipelines: Dict[str, Tuple[tuple, TilePipeline]] = {}
         # serving gateway: response cache + singleflight + admission in
         # front of the pipelines; pass gateway=None for the raw server
@@ -1703,14 +1714,24 @@ class OWSServer:
                 local_tiles, ns_names, p.bbox, width, height,
                 nodata=nodata, writer=writer, out=out, valid=valid)
 
+        export_stats: Dict = {}
+
         async def render_local():
             if engine is None:
                 await asyncio.gather(*(render_tile(*t)
                                        for t in local_tiles))
                 return
-            stats = await asyncio.to_thread(engine.run)
+            export_stats.update(await asyncio.to_thread(engine.run))
+
+        def fold_export(write_s=None):
+            """Once per answered engine export, when its last timed part
+            is over: the stats into /debug `export_pipeline`."""
+            if not export_stats:
+                return
+            if write_s is not None:
+                export_stats["write_s"] = round(write_s, 6)
             try:
-                self.metrics.record_export(stats)
+                self.metrics.record_export(export_stats)
             except Exception:  # export metrics are telemetry only
                 pass
 
@@ -1735,6 +1756,8 @@ class OWSServer:
                 except OSError:
                     pass
             raise
+        if writer is not None:
+            fold_export()       # a streamed export has no assembly
         if stream_dap:
             # the coverage is complete on disk; the DAP4 body now
             # streams spool row-batches through the chunk framer, so
@@ -1769,6 +1792,21 @@ class OWSServer:
             return web.FileResponse(writer.path, headers={
                 "Content-Disposition": f'attachment; filename="{fname}"',
                 "Content-Type": "image/geotiff"})
+        # the assembly after the engine, for the in-RAM legs: mask to
+        # nodata, encode, write, read the file back into the body
+        t_write = time.perf_counter()
+        with obs.span("export.write", format=fmt, width=width,
+                      height=height):
+            resp = await self._assemble_coverage(
+                lay, fmt, ns_names, out, valid, nodata, gt, p, stamp,
+                width, height)
+        fold_export(time.perf_counter() - t_write)
+        return resp
+
+    async def _assemble_coverage(self, lay, fmt, ns_names, out, valid,
+                                 nodata, gt, p, stamp, width, height):
+        """The response of an in-RAM GetCoverage from its whole-coverage
+        canvases."""
         # finalise in place: the render is done with out[n], so masking
         # nodata needs no second full-coverage copy (a 4-band 4K export
         # peaked at 2x the float32 canvases)
