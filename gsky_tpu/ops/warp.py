@@ -24,6 +24,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 import numpy as np
 
 from ..geo.crs import CRS
@@ -236,13 +237,70 @@ def warp_gather_batch(src, valid, rows, cols, method: str = "near"):
         src, valid, rows, cols)
 
 
+def _cell_corners(ctrl, h: int, w: int, step: int, x0=0):
+    """The four corner values of every pixel's control-grid cell, as
+    (c00, c10, c01, c11), each (h, w): pixel (i, j) holds the corners
+    of cell (min(i // step, gh - 2), min((x0 + j) // step, gw - 2)).
+
+    The grid is regular, so which cell a pixel reads is known when the
+    program is traced: each corner plane is a (gh - 1, gw - 1) slice of
+    ``ctrl`` with every value repeated ``step`` x ``step`` times, a
+    broadcast and a reshape.  No gather: a TPU gather costs by the
+    element gathered whatever it reads (PERF.md, PRs 27, 38).  Past the
+    last cell (the pixel ON the last node, a strip's padding columns)
+    the last cell repeats, which is the clip to ``gh - 2``.
+
+    ``x0`` a Python int: every cut is static.  ``x0`` traced (the SPMD
+    render's strip): the strip's cells are cut from the control
+    columns first, then the strip from their pixels.
+
+    The planes leave through an optimization barrier, where the gathers'
+    results stood: what a kernel does with them is then the program it
+    was, and two kernels over one grid (the staged and the modular
+    route of a tile) round alike, to the byte."""
+    gh, gw = ctrl.shape
+    p = jnp.stack([ctrl[:-1, :-1], ctrl[1:, :-1],
+                   ctrl[:-1, 1:], ctrl[1:, 1:]])
+
+    if isinstance(x0, int):
+        c0 = min(x0 // step, gw - 2)
+        nx = -(-(x0 - c0 * step + w) // step)
+
+        def cut(a, start, size):
+            return lax.slice_in_dim(a, start, start + size, axis=2)
+    else:
+        # a strip of w pixels touches at most nx cells, whatever its
+        # alignment; past the grid every cell is the last one, so the
+        # clamped start of the second cut reads the same values
+        c0 = jnp.clip(x0 // step, 0, gw - 2)
+        nx = (w - 1) // step + 2
+
+        def cut(a, start, size):
+            return lax.dynamic_slice_in_dim(a, start, size, axis=2)
+    ny = -(-h // step)
+    # the last cell again, as far as a row or a strip may reach
+    p = jnp.pad(p, ((0, 0), (0, max(ny - (gh - 1), 0)), (0, nx)),
+                mode="edge")[:, :ny]
+    p = cut(p, c0, nx)
+    # columns first, on (ny, nx) cells: merging (nx, step) into the
+    # minor dimension is a relayout, paid on ny rows and not on h; the
+    # rows then repeat along the second-minor dimension
+    p = jnp.broadcast_to(p[..., None], (4, ny, nx, step))
+    p = cut(p.reshape(4, ny, nx * step), x0 - c0 * step, w)
+    p = jnp.broadcast_to(p[:, :, None, :], (4, ny, step, w))
+    p = lax.optimization_barrier(p.reshape(4, ny * step, w)[:, :h])
+    return p[0], p[1], p[2], p[3]
+
+
 def _bilerp_grid(ctrl, h: int, w: int, step: int, x0=0):
     """Upsample a control-point grid (gh, gw) to full (h, w) dst
     resolution — the on-device analogue of GDAL's approx transformer
     (`worker/gdalprocess/warp.go:219` uses err 0.125 px): the host
     projects only every ``step``-th pixel centre; the dense grid is
     bilinear interpolation, whose error over a few-hundred-metre block is
-    far below a pixel for any smooth projection.
+    far below a pixel for any smooth projection.  The corners reach a
+    pixel without a gather (`_cell_corners`); a NaN node poisons
+    exactly the pixels of the cells it is a corner of.
 
     ``x0``: global x of this grid's first column — the SPMD render
     shards the output width, and each shard reconstructs only its strip
@@ -250,14 +308,9 @@ def _bilerp_grid(ctrl, h: int, w: int, step: int, x0=0):
     gh, gw = ctrl.shape
     yy = jnp.arange(h, dtype=jnp.float32)[:, None] / step
     xx = (x0 + jnp.arange(w, dtype=jnp.float32)[None, :]) / step
-    y0 = jnp.clip(jnp.floor(yy).astype(jnp.int32), 0, gh - 2)
-    x0 = jnp.clip(jnp.floor(xx).astype(jnp.int32), 0, gw - 2)
-    ty = yy - y0
-    tx = xx - x0
-    c00 = ctrl[y0, x0]
-    c10 = ctrl[y0 + 1, x0]
-    c01 = ctrl[y0, x0 + 1]
-    c11 = ctrl[y0 + 1, x0 + 1]
+    ty = yy - jnp.clip(jnp.floor(yy).astype(jnp.int32), 0, gh - 2)
+    tx = xx - jnp.clip(jnp.floor(xx).astype(jnp.int32), 0, gw - 2)
+    c00, c10, c01, c11 = _cell_corners(ctrl, h, w, step, x0)
     return (c00 * (1 - ty) + c10 * ty) * (1 - tx) \
         + (c01 * (1 - ty) + c11 * ty) * tx
 
@@ -538,9 +591,11 @@ def render_rgba_ctrl(granules, ctrl, params, prios, scale_params,
     a raster stays on the device.  Compared with
     `render_scenes_bands_ctrl` this computes warp indices and tap
     weights ONCE a granule for all three bands and gathers 3-vectors:
-    a TPU gather costs by the element gathered, so a tile over two
-    granules takes 8 gathers where the per-band kernel takes 32
-    (22.7 ms against 5.6 ms for one granule, PERF.md PR 27).  The host
+    a TPU gather costs by the element gathered, so a bilinear tile
+    over two granules takes 8 tap gathers of 3-vectors where the
+    per-band kernel takes 24 of scalars (22.7 ms against 5.6 ms for one
+    granule, PERF.md PR 27), and none beside the taps: the control grid
+    reaches a pixel without one (`_cell_corners`).  The host
     pulls one contiguous buffer that feeds the PNG encoder without an
     interleave pass.  Alpha is 0 exactly where all three scaled bytes
     are 255 — the transparency rule of the RGB PNG encoder

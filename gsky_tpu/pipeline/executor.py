@@ -379,7 +379,10 @@ class WarpExecutor:
         ``step``-th row/col projected into src CRS (f64, host).  The
         dense grid is reconstructed on device (`ops.warp._bilerp_grid`),
         GDAL-approx-transformer style, so only ~2 KB of coordinates are
-        uploaded per tile.
+        uploaded per tile.  The helper leans on the grid being regular
+        (nodes at ``k * step``, ``gh = (height - 1 + step - 1) // step
+        + 1``): a pixel's cell is known when the kernel is traced, so
+        its corners are repeated to it and not gathered.
 
         Like GDAL's approx transformer (0.125 px error bound,
         `worker/gdalprocess/warp.go:219`), the grid is validated once

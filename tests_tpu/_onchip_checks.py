@@ -471,6 +471,45 @@ def _():
                                np.asarray(canv_c)[both], rtol=1e-5)
 
 
+# --- control-grid upsample -------------------------------------------------
+
+@check("ctrl_upsample_f64")
+def _():
+    """`_bilerp_grid` on the chip against a float64 numpy bilinear of
+    the same grid, at the export tile's and the map tile's size.  The
+    formula itself, in float32, stands up to 2.3 ulp off the float64
+    value (four products and three sums of ~1e5 m; the gather form read
+    the same): so the chip is held to the float32 formula within 1 ulp
+    (what `_window_slice`'s docstring grants XLA's contraction between
+    programs) and to float64 within 4."""
+    from gsky_tpu.ops.warp import _bilerp_grid
+    for h, w, step in ((1024, 1024, 16), (256, 256, 16)):
+        gh = (h - 1 + step - 1) // step + 1
+        gw = (w - 1 + step - 1) // step + 1
+        cc, rr = np.meshgrid(np.arange(gw) * step * 30.0,
+                             np.arange(gh) * step * 30.0)
+        ctrl = (1.2e5 + cc + 40.0 * np.sin(rr / 900.0)
+                + rng.normal(0.0, 3.0, cc.shape)).astype(np.float32)
+        got = np.asarray(_bilerp_grid(jnp.asarray(ctrl), h, w, step))
+        assert got.shape == (h, w) and got.dtype == np.float32
+
+        def bilinear(dt):
+            c = ctrl.astype(dt)
+            yy = (np.arange(h, dtype=dt) / dt(step))[:, None]
+            xx = (np.arange(w, dtype=dt) / dt(step))[None, :]
+            y0 = np.clip(np.floor(yy).astype(int), 0, gh - 2)
+            x0 = np.clip(np.floor(xx).astype(int), 0, gw - 2)
+            ty, tx = yy - y0.astype(dt), xx - x0.astype(dt)
+            return (c[y0, x0] * (1 - ty) + c[y0 + 1, x0] * ty) * (1 - tx) \
+                + (c[y0, x0 + 1] * (1 - ty) + c[y0 + 1, x0 + 1] * ty) * tx
+
+        ulp = np.spacing(np.abs(got)).astype(np.float64)
+        off32 = np.abs(got.astype(np.float64) - bilinear(np.float32)) / ulp
+        off64 = np.abs(got.astype(np.float64) - bilinear(np.float64)) / ulp
+        assert off32.max() <= 1.0, (h, w, step, off32.max())
+        assert off64.max() <= 4.0, (h, w, step, off64.max())
+
+
 # --- shared-source multi-tile gather -------------------------------------
 
 @check("warp_gather_shared")
