@@ -86,8 +86,11 @@ async def encode_async(fn, *args, spans: Optional[Dict] = None, **kw):
     """Run one encode callable on the sized pool, awaitable from the
     event loop.  Exceptions propagate to the awaiting handler exactly
     as they would inline.  ``spans`` (the staged tile path's
-    per-request record) gets ``encode_s`` and the observed
-    ``encode_queue_max`` occupancy folded in."""
+    per-request record) gets ``encode_s``, ``encode_cpu_s`` (the job
+    thread's CPU) and the observed ``encode_queue_max`` occupancy
+    folded in.  The `encode` span carries the same CPU as ``cpu_s``
+    and its wall less that CPU as ``wait_s``, as does
+    ``gsky_encode_seconds{phase}``."""
     loop = asyncio.get_running_loop()
     pool = encode_pool()
     with _pool_lock:
@@ -113,18 +116,22 @@ async def encode_async(fn, *args, spans: Optional[Dict] = None, **kw):
         return fn(*args, **kw)
 
     def run():
-        t1 = time.perf_counter()
+        # the job's wall (the pool's busy seconds) and its thread's CPU
+        t1, c1 = time.perf_counter(), time.thread_time()
         try:
             return ctx.run(_job)
         finally:
-            cpu[0] = time.perf_counter() - t1
+            cpu[0] = time.thread_time() - c1
+            busy = time.perf_counter() - t1
             with _pool_lock:
-                _pool_stats["busy_s"] += cpu[0]
+                _pool_stats["busy_s"] += busy
 
     ok = False
     try:
         with obs_span("encode") as esp:
             out = await loop.run_in_executor(pool, run)
+            # the rest of the encode's wall: the queue for a pool
+            # thread, the GIL, the hop back to the event loop
             wait_s = max(0.0, time.perf_counter() - t0 - cpu[0])
             esp.set(cpu_s=round(cpu[0], 6), wait_s=round(wait_s, 6))
             try:
@@ -143,6 +150,7 @@ async def encode_async(fn, *args, spans: Optional[Dict] = None, **kw):
         if ok and spans is not None:
             spans["encode_s"] = spans.get("encode_s", 0.0) \
                 + time.perf_counter() - t0
+            spans["encode_cpu_s"] = spans.get("encode_cpu_s", 0.0) + cpu[0]
 
 # zlib level 1 default: on satellite composites levels 6-9 buy ~10%
 # smaller tiles for >2x the encode time, and the encode sits on the

@@ -697,11 +697,37 @@ def _collect_temporal():
     return out
 
 
+def _collect_gc():
+    """The cyclic collector's runs and pauses by generation, from the
+    watch `/debug` `process.gc` reads (`obs/process.py`).  Rendered only
+    where the server installed it — exposition stays byte-identical
+    otherwise."""
+    out: List = []
+    try:
+        from .process import gc_stats
+        st = gc_stats()
+        if st is not None:
+            out.append(_c("gsky_gc_collections_total",
+                          "Cyclic garbage collections by generation.",
+                          [({"generation": str(g)}, float(n))
+                           for g, n in enumerate(st["collections"])]))
+            out.append(_c("gsky_gc_pause_seconds_total",
+                          "Seconds the cyclic collector held the "
+                          "interpreter, by generation.",
+                          [({"generation": str(g)}, float(s))
+                           for g, s in enumerate(st["pause_s"])]))
+    except Exception:
+        # scrape-time collectors must never break /metrics
+        pass
+    return out
+
+
 for _fn in (_collect_caches, _collect_fleet, _collect_resilience,
             _collect_runtime, _collect_paged, _collect_overload,
             _collect_ingest, _collect_device, _collect_waves,
             _collect_mesh, _collect_expr, _collect_tsan,
-            _collect_fabric, _collect_elastic, _collect_temporal):
+            _collect_fabric, _collect_elastic, _collect_temporal,
+            _collect_gc):
     _REG.register_collector(_fn)
 
 

@@ -24,6 +24,7 @@ import contextvars
 import os
 import threading
 import time
+from asyncio import _get_running_loop
 from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
 
 # jax.profiler.TraceAnnotation, resolved by the first traced span: while
@@ -74,10 +75,16 @@ def trace_enabled() -> bool:
 class Span:
     """One timed operation inside a trace.  Mutable while open; the
     instrumented code may attach attributes (``set``) and point events
-    (``event``) through the handle yielded by ``span()``."""
+    (``event``) through the handle yielded by ``span()``.
+
+    ``cpu_s`` is the CPU time of the thread that opened the span, over
+    its length, where one thread that runs no event loop opened and
+    closed it (``span()`` decides); None elsewhere.  On an event-loop
+    thread coroutines interleave, and that thread's clock would count
+    other requests' work."""
 
     __slots__ = ("span_id", "parent_id", "name", "process", "t0",
-                 "dur_s", "attrs", "events", "_pc0")
+                 "dur_s", "cpu_s", "attrs", "events", "_pc0", "_cpu0")
 
     def __init__(self, span_id: str, parent_id: Optional[str], name: str,
                  process: str, attrs: Optional[Dict[str, Any]] = None):
@@ -88,6 +95,9 @@ class Span:
         self.t0 = time.time()
         self._pc0 = time.perf_counter()
         self.dur_s: Optional[float] = None
+        self.cpu_s: Optional[float] = None
+        # (thread id, its CPU clock) where this span's thread is timed
+        self._cpu0: Optional[Tuple[int, float]] = None
         self.attrs: Dict[str, Any] = dict(attrs) if attrs else {}
         self.events: List[Dict[str, Any]] = []
 
@@ -103,12 +113,17 @@ class Span:
     def close(self) -> None:
         if self.dur_s is None:
             self.dur_s = time.perf_counter() - self._pc0
+            c = self._cpu0
+            if c is not None and c[0] == threading.get_ident():
+                self.cpu_s = time.thread_time() - c[1]
 
     def to_dict(self) -> Dict[str, Any]:
         d: Dict[str, Any] = {
             "span_id": self.span_id, "parent_id": self.parent_id,
             "name": self.name, "process": self.process,
             "t0": self.t0, "dur_s": self.dur_s}
+        if self.cpu_s is not None:
+            d["cpu_s"] = self.cpu_s
         if self.attrs:
             d["attrs"] = self.attrs
         if self.events:
@@ -168,6 +183,17 @@ class Trace:
             for sp in self._spans:
                 if sp.dur_s is not None:
                     out[sp.name] = out.get(sp.name, 0.0) + sp.dur_s
+        return out
+
+    def cpu_by_name(self) -> Dict[str, float]:
+        """{name: summed thread CPU seconds} over the closed child spans
+        that carry ``cpu_s``, as ``seconds_by_name`` sums their wall: a
+        name whose spans all ran on an event-loop thread is absent."""
+        out: Dict[str, float] = {}
+        with self._lock:
+            for sp in self._spans:
+                if sp.cpu_s is not None:
+                    out[sp.name] = out.get(sp.name, 0.0) + sp.cpu_s
         return out
 
     def count(self, name: str) -> int:
@@ -284,13 +310,16 @@ def event(name: str, **attrs) -> None:
 def span(name: str, **attrs) -> Iterator[Any]:
     """Open a child span of the current context.  Yields the ``Span``
     (or a shared no-op handle when untraced) so callers can ``.set()``
-    attributes discovered mid-flight."""
+    attributes discovered mid-flight.  Off an event-loop thread the
+    span also times its thread's CPU (``Span.cpu_s``)."""
     cur = _CURRENT.get()
     if cur is None:
         yield _NULL
         return
     trace, parent = cur
     sp = Span(_new_id(), parent, name, trace.process, attrs or None)
+    if _get_running_loop() is None:
+        sp._cpu0 = (threading.get_ident(), time.thread_time())
     with trace._lock:
         trace._open[sp.span_id] = sp
     tok = _CURRENT.set((trace, sp.span_id))

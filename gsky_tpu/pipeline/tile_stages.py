@@ -55,17 +55,22 @@ class StageGate:
     """Bounded stage admission: a semaphore plus the telemetry the
     /debug `tile_stages` block needs — occupancy high-water (how many
     requests were at the gate when one arrived), cumulative busy
-    seconds, entry count.  One gate per stage, shared by every request
-    in the process, so the bounds hold across concurrent handlers."""
+    seconds, cumulative seconds requests waited for a slot, entry
+    count.  One gate per stage, shared by every request in the process,
+    so the bounds hold across concurrent handlers.  The wait is a
+    `tile.<name>_gate` span, so that a request queued at the gate is an
+    annotation on the profiler's clock."""
 
     def __init__(self, name: str, limit: int):
         self.name = name
         self.limit = limit
         self._sem = threading.Semaphore(limit)
         self._lock = threading.Lock()
+        self._span = f"tile.{name}_gate"
         self.waiting = 0          # requests at the gate right now
         self.queue_max = 0        # high-water of `waiting`
         self.busy_s = 0.0
+        self.wait_s = 0.0
         self.entries = 0
 
     @contextlib.contextmanager
@@ -80,10 +85,14 @@ class StageGate:
             # occupancy INCLUDING self, like export's qsize()+1 marks:
             # 1 means uncontended, >1 means the stage actually queued
             spans[qkey] = max(spans.get(qkey, 0), occupancy)
-        self._sem.acquire()
+        with obs_span(self._span):
+            t0 = time.perf_counter()
+            self._sem.acquire()
+            waited = time.perf_counter() - t0
         with self._lock:
             self.waiting -= 1
             self.entries += 1
+            self.wait_s += waited
         t0 = time.perf_counter()
         try:
             yield
@@ -97,7 +106,8 @@ class StageGate:
         with self._lock:
             return {"limit": self.limit, "waiting": self.waiting,
                     "queue_max": self.queue_max, "entries": self.entries,
-                    "busy_s": round(self.busy_s, 6)}
+                    "busy_s": round(self.busy_s, 6),
+                    "wait_s": round(self.wait_s, 6)}
 
 
 _gates: Dict[str, StageGate] = {}

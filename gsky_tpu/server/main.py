@@ -22,8 +22,10 @@ def main(argv=None, run_app=web.run_app):
     start-up step an operator's process does."""
     # GSKY_TSAN=1: patch threading.Lock/RLock BEFORE any server lock
     # exists so every lock participates in lockset race tracking
-    from ..obs import tsan
+    from ..obs import process, tsan
     tsan.maybe_install()
+    # count and time the cyclic collector's pauses from the start
+    process.install()
 
     ap = argparse.ArgumentParser(prog="gsky-ows",
                                  description="GSKY-TPU OGC web server")

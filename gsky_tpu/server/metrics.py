@@ -279,15 +279,36 @@ class MetricsLogger:
     # staged-tile span folding (pipeline/tile_stages.py), mirroring the
     # export aggregates above: per-stage seconds sum, queue high-water
     # marks max, the raw per-request record kept as "last"
-    _TILE_SUMS = ("plan_s", "index_s", "decode_s", "dispatch_s",
-                  "readback_s", "encode_s", "granules")
+    _TILE_STAGES = ("plan_s", "index_s", "decode_s", "dispatch_s",
+                    "readback_s", "encode_s")
+    _TILE_SUMS = _TILE_STAGES + (
+        "granules", "plan_cpu_s", "index_cpu_s", "decode_cpu_s",
+        "dispatch_cpu_s", "readback_cpu_s", "encode_cpu_s", "wall_s")
     _TILE_MAXES = ("decode_queue_max", "dispatch_queue_max",
                    "encode_queue_max")
+    # tile_stages key <- the span whose thread CPU it sums; `tile.plan`
+    # holds `tile.index`, and plan_cpu_s is the rest, as plan_s is
+    _TILE_CPU = (("plan_cpu_s", "tile.plan"), ("index_cpu_s", "tile.index"),
+                 ("decode_cpu_s", "tile.decode"),
+                 ("dispatch_cpu_s", "tile.dispatch"),
+                 ("readback_cpu_s", "tile.readback"))
 
-    def record_tile(self, spans: Dict) -> None:
+    def record_tile(self, spans: Dict, cpu: Optional[Dict[str, float]] = None,
+                    wall_s: Optional[float] = None) -> None:
         """Fold one staged GetMap render's stage spans into the /debug
-        `tile_stages` aggregates."""
+        `tile_stages` aggregates.  ``cpu`` is the request's trace folded
+        by span name (`Trace.cpu_by_name`), ``wall_s`` its root span's
+        age when the encode landed; both None untraced, and then the
+        keys made of them are absent."""
         try:
+            if cpu is not None:
+                spans.update({k: cpu[name] for k, name in self._TILE_CPU
+                              if name in cpu})
+                if "plan_cpu_s" in spans:
+                    spans["plan_cpu_s"] = max(0.0, spans["plan_cpu_s"]
+                                              - spans.get("index_cpu_s", 0.0))
+            if wall_s is not None:
+                spans["wall_s"] = wall_s
             with self._summary_lock:
                 e = self._tiles
                 e["tiles"] = e.get("tiles", 0) + 1
@@ -299,8 +320,8 @@ class MetricsLogger:
                         e[k] = max(e.get(k, 0), spans[k])
                 e["last"] = dict(spans)
             from ..obs.metrics import STAGE_SECONDS
-            for k in self._TILE_SUMS:
-                if k.endswith("_s") and k in spans:
+            for k in self._TILE_STAGES:
+                if k in spans:
                     STAGE_SECONDS.labels(stage=k[:-2]).observe(spans[k])
         except Exception:   # observability must never fail a request
             pass
@@ -327,19 +348,25 @@ class MetricsLogger:
                      ("format_s", "wps.format"))
 
     def record_drill(self, stages: Dict[str, float], wall_s: float,
-                     files: int, windows: int) -> None:
+                     files: int, windows: int,
+                     cpu: Optional[Dict[str, float]] = None) -> None:
         """Fold one answered WPS Execute into the /debug `drill_stages`
         aggregates.  ``stages`` is the request's trace folded by span
         name (`Trace.seconds_by_name`), ``wall_s`` the root span's age,
         ``files`` the files drilled (answered by the device or by host
         reads), ``windows`` the windows and masks made for them (the
-        `drill.prepare` spans: files on one grid share one)."""
+        `drill.prepare` spans: files on one grid share one).  ``cpu``
+        (`Trace.cpu_by_name`) adds `<stage>_cpu_s` for each stage whose
+        spans carry thread CPU (those run off the event loop)."""
         try:
             last = {k: round(stages.get(name, 0.0), 6)
                     for k, name in self._DRILL_STAGES}
             last["wall_s"] = round(wall_s, 6)
             last["files"] = files
             last["windows"] = windows
+            for k, name in self._DRILL_STAGES:
+                if cpu and name in cpu:
+                    last[k[:-2] + "_cpu_s"] = round(cpu[name], 6)
             with self._summary_lock:
                 e = self._drills
                 e["requests"] = e.get("requests", 0) + 1
@@ -348,7 +375,7 @@ class MetricsLogger:
                 e["last"] = last
             from ..obs.metrics import STAGE_SECONDS
             for k, v in last.items():
-                if k.endswith("_s"):
+                if k.endswith("_s") and not k.endswith("_cpu_s"):
                     STAGE_SECONDS.labels(stage="drill_" + k[:-2]).observe(v)
         except Exception:   # observability must never fail a request
             pass
@@ -388,6 +415,12 @@ class MetricsLogger:
                     pass
             out["rgb_routes"] = dict(self._rgb_routes)
         out["cache"] = _cache_stats()
+        try:
+            # the process's CPU clock and the cyclic collector's pauses
+            from ..obs.process import process_stats
+            out["process"] = process_stats()
+        except Exception:   # observability must never fail a request
+            pass
         try:
             from ..resilience import registry as _resilience
             out["resilience"] = _resilience.stats()

@@ -1377,7 +1377,14 @@ class OWSServer:
             return await encode_async(fn, *args, spans=spans, **kw)
         finally:
             if spans is not None:
-                self.metrics.record_tile(spans)
+                # a traced request adds its stages' thread CPU and the
+                # root's age: wall_s less the stages is what none covers
+                trace = obs.current_trace()
+                if trace is None:
+                    self.metrics.record_tile(spans)
+                else:
+                    self.metrics.record_tile(spans, trace.cpu_by_name(),
+                                             trace.age_s())
 
     async def _feature_info(self, cfg: Config, p):
         if not p.layers:
@@ -1946,7 +1953,8 @@ class OWSServer:
             self.metrics.record_drill(trace.seconds_by_name(),
                                       trace.age_s(),
                                       files=trace.total("files"),
-                                      windows=trace.count("drill.prepare"))
+                                      windows=trace.count("drill.prepare"),
+                                      cpu=trace.cpu_by_name())
         return resp
 
 
