@@ -23,15 +23,21 @@ A native C++ fast path for tile decode lives in `gsky_tpu/native`
 from __future__ import annotations
 
 import math
+import os
 import struct
+import threading
+import time
 import zlib
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
-from typing import BinaryIO, Dict, List, Optional, Sequence, Tuple, Union
+from typing import (BinaryIO, Callable, Dict, List, Optional, Sequence, Tuple,
+                    Union)
 
 import numpy as np
 
 from ..geo.crs import CRS, EPSG4326, parse_crs
 from ..geo.transform import BBox, GeoTransform
+from ..obs import set_attr as obs_set_attr
 
 # TIFF tag ids
 T_WIDTH, T_HEIGHT = 256, 257
@@ -632,6 +638,40 @@ class GeoTIFF:
 
 _SAMPLE_FMT = {"u": 1, "i": 2, "f": 3}
 
+# -- shared deflate pool -----------------------------------------------------
+# A whole-image write deflates its blocks here instead of one after
+# another: zlib releases the GIL and a block's bytes do not depend on the
+# thread that compressed it, so blobs appended in row-major order give
+# the serial writer's file byte for byte.  Sized from the CPUs this
+# process may run on; created by the first compressed multi-block write.
+
+_deflate_pool: Optional[ThreadPoolExecutor] = None
+_deflate_lock = threading.Lock()
+_deflate_stats: Dict = {"workers": 0, "writes": 0, "blocks": 0,
+                        "blocks_pooled": 0, "busy_s": 0.0}
+
+
+def deflate_pool() -> ThreadPoolExecutor:
+    global _deflate_pool
+    if _deflate_pool is None:
+        with _deflate_lock:
+            if _deflate_pool is None:
+                n = min(8, len(os.sched_getaffinity(0)))
+                _deflate_stats["workers"] = n
+                _deflate_pool = ThreadPoolExecutor(
+                    max_workers=n, thread_name_prefix="gsky-tiff")
+    return _deflate_pool
+
+
+def deflate_pool_stats() -> Dict:
+    """Compressed `write_geotiff` calls, their blocks, how many of those
+    were deflated on the pool, and the pool's busy seconds summed over
+    its threads."""
+    with _deflate_lock:
+        out = dict(_deflate_stats)
+    out["busy_s"] = round(out["busy_s"], 6)
+    return out
+
 
 class GeoTIFFWriter:
     """Streaming tiled GeoTIFF writer.
@@ -683,12 +723,45 @@ class GeoTIFFWriter:
     def write_tile(self, tx: int, ty: int, block: np.ndarray) -> None:
         """block: (bands, th, tw) in storage dtype; edge tiles may be
         smaller than tile_size (padded with nodata)."""
-        blob = self._encode_block(np.asarray(block, self.dtype))
+        self._append(tx, ty, self._encode_block(np.asarray(block, self.dtype)))
+
+    def _append(self, tx: int, ty: int, blob: bytes) -> None:
         with self._lock:
             off = self._pos
             self._fp.write(blob)
             self._pos += len(blob)
             self._tiles[(ty, tx)] = (off, len(blob))
+
+    def write_blocks(self, items: Sequence[Tuple[int, int, Callable]]) -> int:
+        """Write whole-image blocks given as (tx, ty, make) in the order
+        they are to lie in the file; ``make()`` cuts the (bands, th, tw)
+        block.  Compressed and more than one, the cut and deflate run on
+        the shared pool and the blobs append in the given order, so the
+        file is the one ``write_tile`` in that order gives.  Returns the
+        threads the deflate ran on (0 uncompressed)."""
+        pooled = self.compress and len(items) > 1
+        if self.compress:
+            with _deflate_lock:
+                _deflate_stats["writes"] += 1
+                _deflate_stats["blocks"] += len(items)
+                _deflate_stats["blocks_pooled"] += len(items) if pooled else 0
+        if not pooled:
+            for tx, ty, make in items:
+                self.write_tile(tx, ty, make())
+            return int(self.compress)
+
+        def encode(item):
+            t0 = time.perf_counter()
+            blob = self._encode_block(np.asarray(item[2](), self.dtype))
+            busy = time.perf_counter() - t0
+            with _deflate_lock:
+                _deflate_stats["busy_s"] += busy
+            return blob
+
+        pool = deflate_pool()
+        for (tx, ty, _), blob in zip(items, pool.map(encode, items)):
+            self._append(tx, ty, blob)
+        return _deflate_stats["workers"]
 
     def write_region(self, x0: int, y0: int, data: np.ndarray) -> None:
         """Write a tile-aligned region (bands, h, w) at pixel (x0, y0);
@@ -926,13 +999,17 @@ def write_geotiff(path: str, data, gt: GeoTransform, crs: CRS,
     w = GeoTIFFWriter(path, bands, H, W, dt, gt, crs, nodata=nodata,
                       tile_size=tile_size, compress=compress)
     ts = tile_size
-    for ty in range(w.tiles_y):
-        for tx in range(w.tiles_x):
-            r1 = min((ty + 1) * ts, H)
-            c1 = min((tx + 1) * ts, W)
-            block = np.stack([np.asarray(b)[ty * ts:r1, tx * ts:c1]
-                              for b in data]).astype(dt)
-            w.write_tile(tx, ty, block)
+
+    def cut(tx, ty):
+        r1 = min((ty + 1) * ts, H)
+        c1 = min((tx + 1) * ts, W)
+        return lambda: np.stack([np.asarray(b)[ty * ts:r1, tx * ts:c1]
+                                 for b in data]).astype(dt)
+    items = [(tx, ty, cut(tx, ty)) for ty in range(w.tiles_y)
+             for tx in range(w.tiles_x)]
+    workers = w.write_blocks(items)
+    # on the span the write runs in (`export.write` for a WCS export)
+    obs_set_attr(blocks=len(items), deflate_workers=workers)
     for f in sorted(overviews):
         if f < 2 or H // f < 1 or W // f < 1:
             continue

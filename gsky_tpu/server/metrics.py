@@ -401,7 +401,9 @@ class MetricsLogger:
                     "device_ms_total": round(s["device_ms"], 1),
                     "pipeline_ms_total": round(s["rpc_ms"], 1)}
             if self._export.get("exports"):
-                out["export_pipeline"] = dict(self._export)
+                from ..io.geotiff import deflate_pool_stats
+                out["export_pipeline"] = dict(self._export,
+                                              deflate=deflate_pool_stats())
             if self._drills.get("requests"):
                 out["drill_stages"] = dict(self._drills)
             if self._tiles.get("tiles"):

@@ -217,6 +217,27 @@ def test_trace_holds_a_span_a_tile_and_the_write(env):
     assert write["t0"] >= warp["t0"] + warp["dur_s"] - 1e-3
 
 
+def test_the_write_deflates_its_blocks_on_the_pool(env):
+    """An export of 2 x 2 GeoTIFF blocks (256 + 64 a side): every block
+    is deflated on the shared pool, in `/debug` and on the write's span."""
+    size = 320
+    obs.reset_recorder()
+    before = _export_stats(env).get("deflate", {})
+    req = _request(env["gen"], "inside_at_0.7", size)
+    status, body = _get(env["server"], req.path)
+    assert status == 200 and wcs_exports.tiff_ok(status, body), body[:300]
+    after = _export_stats(env)["deflate"]
+
+    def moved(key):
+        return after[key] - before.get(key, 0)
+    assert moved("blocks_pooled") == moved("blocks") == 4
+    assert moved("writes") == 1 and moved("busy_s") > 0
+    write, = [sp for t in obs.default_recorder().traces()
+              for sp in t.get("spans", []) if sp["name"] == "export.write"]
+    assert write["attrs"]["blocks"] == 4
+    assert write["attrs"]["deflate_workers"] == after["workers"] >= 1
+
+
 # --- what the rule refuses ----------------------------------------------------
 
 def _body(tmp_path, plane, bbox, shift_px=0.0):

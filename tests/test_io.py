@@ -11,6 +11,7 @@ from PIL import Image
 from gsky_tpu.geo.crs import EPSG4326, parse_crs
 from gsky_tpu.geo.transform import BBox, GeoTransform
 from gsky_tpu.io import GeoTIFF, write_geotiff, encode_png
+from gsky_tpu.io.geotiff import GeoTIFFWriter, deflate_pool_stats
 from gsky_tpu.io.netcdf import (NetCDF, cf_times_to_unix, crs_from_cf,
                                 parse_cf_time_units, write_netcdf3)
 from gsky_tpu.io.png import decode_png, empty_tile_png, encode_jpeg
@@ -134,6 +135,117 @@ class TestGeoTIFFvsPIL:
         Image.fromarray(data, "F").save(p)
         with GeoTIFF(p) as g:
             np.testing.assert_allclose(g.read(1), data)
+
+
+def _serial_geotiff(path, data, gt, crs, nodata, tile_size, compress):
+    """The serial writer: each block cut, deflated and appended in
+    row-major order on the calling thread."""
+    w = GeoTIFFWriter(path, data.shape[0], data.shape[1], data.shape[2],
+                      data.dtype, gt, crs, nodata=nodata,
+                      tile_size=tile_size, compress=compress)
+    ts = tile_size
+    for ty in range(w.tiles_y):
+        for tx in range(w.tiles_x):
+            w.write_tile(tx, ty, data[:, ty * ts:(ty + 1) * ts,
+                                      tx * ts:(tx + 1) * ts])
+    w.close()
+
+
+def _field(shape, dtype, seed):
+    """Imagery-like: a smooth ramp plus noise, so deflate has work."""
+    rng = np.random.default_rng(seed)
+    b, h, w = shape
+    base = np.add.outer(np.arange(h), np.arange(w))[None] * 0.25 \
+        + rng.normal(0, 4, shape)
+    if np.dtype(dtype).kind == "u":
+        return (base % 200).astype(dtype)
+    return (base - 300).astype(dtype)
+
+
+def _read_bytes(path):
+    with open(path, "rb") as fp:
+        return fp.read()
+
+
+class TestPooledDeflate:
+    """A whole-image write deflates its blocks on the shared pool and
+    gives the serial writer's file byte for byte."""
+
+    GT = GeoTransform(1000.0, 25.0, 0.0, 5000.0, 0.0, -25.0)
+    CRS = parse_crs("EPSG:32755")
+
+    @pytest.mark.parametrize("compress", [True, False])
+    @pytest.mark.parametrize("nodata", [None, -9999])
+    @pytest.mark.parametrize("tile_size", [64, 128, 256])
+    @pytest.mark.parametrize("dtype", ["float32", "int16", "uint8"])
+    @pytest.mark.parametrize("bands", [1, 3])
+    def test_byte_identical_to_serial(self, tmp_path, bands, dtype,
+                                      tile_size, nodata, compress):
+        if dtype == "uint8" and nodata is not None:
+            nodata = 255            # -9999 has no uint8 value
+        # 3 x 2 blocks, the last row and column partial
+        data = _field((bands, 2 * tile_size + 37, tile_size + 19), dtype,
+                      seed=bands + tile_size)
+        ref, got = str(tmp_path / "serial.tif"), str(tmp_path / "pooled.tif")
+        _serial_geotiff(ref, data, self.GT, self.CRS, nodata, tile_size,
+                        compress)
+        before = deflate_pool_stats()
+        write_geotiff(got, data[0] if bands == 1 else data, self.GT,
+                      self.CRS, nodata=nodata, tile_size=tile_size,
+                      compress=compress)
+        after = deflate_pool_stats()
+        assert _read_bytes(got) == _read_bytes(ref)
+        pooled = after["blocks_pooled"] - before["blocks_pooled"]
+        assert pooled == (6 if compress else 0)
+        assert after["writes"] - before["writes"] == int(compress)
+        if compress:
+            assert 1 <= after["workers"] <= 8
+        with GeoTIFF(got) as g:
+            for b in range(bands):
+                np.testing.assert_array_equal(g.read(b + 1), data[b])
+
+    def test_two_writes_at_once_each_give_their_own_file(self, tmp_path):
+        import threading
+        images = [_field((1, 700, 600), "float32", 1),
+                  _field((3, 520, 530), "int16", 2)]
+        alone = []
+        for i, data in enumerate(images):
+            p = str(tmp_path / f"alone{i}.tif")
+            write_geotiff(p, data, self.GT, self.CRS, nodata=-9999)
+            alone.append(_read_bytes(p))
+        start = threading.Barrier(2)
+        errors = []
+
+        def write(i):
+            try:
+                start.wait()
+                write_geotiff(str(tmp_path / f"both{i}.tif"), images[i],
+                              self.GT, self.CRS, nodata=-9999)
+            except Exception as exc:   # surfaced by the assert below
+                errors.append(exc)
+        threads = [threading.Thread(target=write, args=(i,))
+                   for i in range(2)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        assert errors == []
+        for i in range(2):
+            assert _read_bytes(str(tmp_path / f"both{i}.tif")) == alone[i]
+
+    @pytest.mark.parametrize("shape,compress,blocks", [
+        ((200, 250), True, 1),          # one block: deflated in place
+        ((600, 600), False, 0),         # uncompressed: a copy, not counted
+    ])
+    def test_serial_path_leaves_the_pool_out(self, tmp_tif, shape, compress,
+                                             blocks):
+        before = deflate_pool_stats()
+        write_geotiff(tmp_tif, np.ones(shape, np.float32), self.GT,
+                      self.CRS, compress=compress)
+        after = deflate_pool_stats()
+        assert after["blocks_pooled"] == before["blocks_pooled"]
+        assert after["blocks"] - before["blocks"] == blocks
+        assert after["writes"] - before["writes"] == int(compress)
 
 
 class TestCFTime:
