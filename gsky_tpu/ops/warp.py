@@ -539,53 +539,91 @@ def _resample_c(src, nodata, rows, cols, method: str):
     return out, ok
 
 
+def _grid_taps(bands, params, win0, at, sx, sy, method: str, win):
+    """Warp indices, tap weights and the vector taps of ONE pixel grid
+    of a band set: ``bands`` the (sh, sw) scenes of the channels on that
+    grid, ``params[at]`` its param row, ``win0[at]`` its window origin
+    -> `_resample_c`'s (out (h, w, c), ok (h, w, c))."""
+    p = params[at]
+    cols = (p[0] + p[1] * sx + p[2] * sy) - 0.5
+    rows = (p[3] + p[4] * sx + p[5] * sy) - 0.5
+    oob = (rows < -0.5) | (rows > p[6] - 0.5) \
+        | (cols < -0.5) | (cols > p[7] - 0.5)
+    rows = jnp.where(oob, jnp.nan, rows)
+    if win is not None:
+        cut = [_window_slice(b, win, win0[at], axis=0) for b in bands]
+        bands = [c[0] for c in cut]
+        rows = rows - cut[0][1]
+        cols = cols - cut[0][2]
+    return _resample_c(jnp.stack(bands, axis=-1), p[8], rows, cols,
+                       method)
+
+
 def _mosaic_band_sets(granules, ctrl, params, prios, method: str,
-                      out_hw: Tuple[int, int], step: int, win, win0):
+                      out_hw: Tuple[int, int], step: int, win, win0,
+                      grid_of: Optional[Tuple[int, ...]] = None):
     """The warp + mosaic the channel-packed kernels share: from the band
-    scenes of G granule sets (each a C-tuple of (sh, sw) arrays on one
-    grid) to (data (h, w, C) f32, best (h, w, C) f32): warp indices and
-    tap weights once a set, C-vector gathers from the packed gather
-    windows, and per channel the valid tap of the highest priority
-    (``best`` is -inf where no set holds the channel)."""
+    scenes of G granule sets (each a C-tuple of (sh, sw) arrays) to
+    (data (h, w, C) f32, best (h, w, C) f32): warp indices and tap
+    weights once a pixel grid of a set, vector gathers from the packed
+    gather windows, and per channel the valid tap of the highest
+    priority (``best`` is -inf where no set holds the channel).
+
+    ``grid_of`` (static) maps channel -> pixel grid where a set's bands
+    lie on R > 1 grids of one footprint (Sentinel-2's 10 m and 20 m
+    bands; grid 0 the finest): params is then (G, R, 11), a row a (set,
+    grid), ``win`` a tuple of R windows and win0 (G, R, 2).  Each grid's
+    channels are gathered as one vector a tap from that grid's window,
+    keep their own validity, and are put back in channel order at the
+    end.  None (one grid) keeps params (G, 11), one window and win0
+    (G, 2): a one-grid set traces the program it traced before grids
+    existed."""
     h, w = out_hw
     C = len(granules[0])
+    grid_of = grid_of or (0,) * C
+    grids = [[c for c in range(C) if grid_of[c] == r]
+             for r in range(max(grid_of) + 1)]
+    one = len(grids) == 1
     sx = _bilerp_grid(ctrl[0], h, w, step)
     sy = _bilerp_grid(ctrl[1], h, w, step)
-    data = jnp.zeros((h, w, C), jnp.float32)
-    best = jnp.full((h, w, C), -jnp.inf, jnp.float32)
+    data = [jnp.zeros((h, w, len(cs)), jnp.float32) for cs in grids]
+    best = [jnp.full((h, w, len(cs)), -jnp.inf, jnp.float32)
+            for cs in grids]
     for k, bands in enumerate(granules):
-        p = params[k]
-        cols = (p[0] + p[1] * sx + p[2] * sy) - 0.5
-        rows = (p[3] + p[4] * sx + p[5] * sy) - 0.5
-        oob = (rows < -0.5) | (rows > p[6] - 0.5) \
-            | (cols < -0.5) | (cols > p[7] - 0.5)
-        rows = jnp.where(oob, jnp.nan, rows)
-        if win is not None:
-            cut = [_window_slice(b, win, win0[k], axis=0) for b in bands]
-            bands = [c[0] for c in cut]
-            rows = rows - cut[0][1]
-            cols = cols - cut[0][2]
-        d, o = _resample_c(jnp.stack(bands, axis=-1), p[8], rows, cols,
-                           method)
-        score = jnp.where(o, prios[k], -jnp.inf)
-        take = score > best
-        data = jnp.where(take, d, data)
-        best = jnp.where(take, score, best)
-    return data, best
+        for r, cs in enumerate(grids):
+            d, o = _grid_taps([bands[c] for c in cs], params, win0,
+                              k if one else (k, r), sx, sy, method,
+                              win if one or win is None else win[r])
+            score = jnp.where(o, prios[k] if one
+                              else prios[k, np.asarray(cs)], -jnp.inf)
+            take = score > best[r]
+            data[r] = jnp.where(take, d, data[r])
+            best[r] = jnp.where(take, score, best[r])
+    if one:
+        return data[0], best[0]
+    place = {c: (r, j) for r, cs in enumerate(grids)
+             for j, c in enumerate(cs)}
+
+    def in_order(planes):
+        return jnp.stack([planes[place[c][0]][..., place[c][1]]
+                          for c in range(C)], axis=-1)
+    return in_order(data), in_order(best)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("method", "out_hw", "step", "auto",
-                                    "colour_scale", "win"))
+                                    "colour_scale", "win", "grid_of"))
 def render_rgba_ctrl(granules, ctrl, params, prios, scale_params,
                      method: str = "near",
                      out_hw: Tuple[int, int] = (256, 256),
                      step: int = 16, auto: bool = True,
                      colour_scale: int = 0,
-                     win: Optional[Tuple[int, int]] = None, win0=None):
+                     win: Optional[Tuple[int, int]] = None, win0=None,
+                     grid_of: Optional[Tuple[int, ...]] = None):
     """RGB fast path: one dispatch from the band scenes of G granules,
-    each a 3-tuple of (sh, sw) arrays on one grid as the scene cache
-    holds them, to the PNG-ready (h, w, 4) RGBA tile.  Only the gather
+    each a 3-tuple of (sh, sw) arrays as the scene cache holds them (on
+    one grid, or on the grids ``grid_of`` names: `_mosaic_band_sets`),
+    to the PNG-ready (h, w, 4) RGBA tile.  Only the gather
     window of each band is packed channel-last (without a window the
     packed scene is a temporary of this program), so no second copy of
     a raster stays on the device.  Compared with
@@ -605,12 +643,14 @@ def render_rgba_ctrl(granules, ctrl, params, prios, scale_params,
     and namespace id unused here).  prios: (G, 3) f32, the mosaic
     priority of each granule's band in each channel (the per-band
     kernel's newest-wins, channel by channel); a row of -inf is a
-    padding granule.  win0: (G, 2), an origin a granule.
+    padding granule.  win0: (G, 2), an origin a granule.  With
+    ``grid_of`` params is (G, R, 11) and win0 (G, R, 2), a row and an
+    origin a (granule, grid), and ``win`` R windows.
     scale_params (3,) as elsewhere.
     """
     from .scale import auto_byte_scale, scale_to_byte
     data, best = _mosaic_band_sets(granules, ctrl, params, prios, method,
-                                   out_hw, step, win, win0)
+                                   out_hw, step, win, win0, grid_of)
     ok = best > -jnp.inf
     if auto:
         if colour_scale == 1:
@@ -637,18 +677,20 @@ def render_rgba_ctrl(granules, ctrl, params, prios, scale_params,
 
 @functools.partial(jax.jit,
                    static_argnames=("fp", "method", "out_hw", "step",
-                                    "auto", "colour_scale", "win"))
+                                    "auto", "colour_scale", "win",
+                                    "grid_of"))
 def render_expr_ctrl(granules, ctrl, params, prios, scale_params, consts,
                      fp: tuple, method: str = "near",
                      out_hw: Tuple[int, int] = (256, 256),
                      step: int = 16, auto: bool = True,
                      colour_scale: int = 0,
-                     win: Optional[Tuple[int, int]] = None, win0=None):
+                     win: Optional[Tuple[int, int]] = None, win0=None,
+                     grid_of: Optional[Tuple[int, ...]] = None):
     """Band-algebra fast path in `render_rgba_ctrl`'s form: one dispatch
     from the band scenes of G granule sets, each a C-tuple of (sh, sw)
-    arrays on one grid as the scene cache holds them (slot i of the
-    tuple is variable i of the expression), to the PNG-ready (h, w)
-    uint8 tile, 255 = no data.  The per-channel newest-wins mosaic is
+    arrays as the scene cache holds them (slot i of the tuple is
+    variable i of the expression), to the PNG-ready (h, w) uint8 tile,
+    255 = no data.  The per-channel newest-wins mosaic is
     `_mosaic_band_sets`; the expression is evaluated AFTER it, as the
     merger does (`processor/tile_merger.go:523-731`), by the traced
     epilogue every fused leg shares (`ops.paged.expr_epilogue`: valid
@@ -665,11 +707,12 @@ def render_expr_ctrl(granules, ctrl, params, prios, scale_params, consts,
     (`ops.expr.fingerprint`): one program a structure, a window bucket
     and a granule count, never one an expression string; ``consts``
     (K,) f32 are its lifted literals.  params, prios (G, C), win0 (G, 2)
-    and scale_params as `render_rgba_ctrl`."""
+    and scale_params as `render_rgba_ctrl`, and as there a set's bands
+    may lie on the grids ``grid_of`` names."""
     from .paged import expr_epilogue
     from .scale import scale_to_byte
     data, best = _mosaic_band_sets(granules, ctrl, params, prios, method,
-                                   out_hw, step, win, win0)
+                                   out_hw, step, win, win0, grid_of)
     plane, ok = expr_epilogue(jnp.moveaxis(data, -1, 0)[None],
                               jnp.moveaxis(best, -1, 0)[None], fp,
                               consts[None])

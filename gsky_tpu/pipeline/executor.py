@@ -218,12 +218,12 @@ def _inv_gt_params(gt: GeoTransform, ox: float, oy: float):
 
 
 def _complete_sets(grids, ns_ids, n_chan: int):
-    """Band sets of a granule list: ``grids[i]`` names the grid granule
-    i lies on, ``ns_ids[i]`` its channel.  Returns one list per grid,
-    in first-seen order, of the granule index of each channel
-    0..n_chan-1, or None unless every grid holds exactly one granule
-    per channel (two dates on one grid, or a grid that lacks a band,
-    are the per-band kernels')."""
+    """Band sets of a granule list: ``grids[i]`` names the footprint
+    granule i covers (`_footprint`), ``ns_ids[i]`` its channel.  Returns
+    one list per footprint, in first-seen order, of the granule index of
+    each channel 0..n_chan-1, or None unless every footprint holds
+    exactly one granule per channel (two dates on one footprint, or a
+    footprint that lacks a band, are the per-band kernels')."""
     sets: Dict[tuple, Dict[int, int]] = {}
     for i, grid in enumerate(grids):
         members = sets.setdefault(grid, {})
@@ -236,41 +236,163 @@ def _complete_sets(grids, ns_ids, n_chan: int):
     return [[m[c] for c in want] for m in sets.values()]
 
 
+# pixel grids a band set may span: Sentinel-2 has three (10, 20, 60 m),
+# and each is one more operand row and window in the program's key
+_MAX_GRIDS = 3
+
+
+def _footprint(s) -> tuple:
+    """The ground a cached scene covers: its CRS, outer corner and the
+    two edge vectors, W (dx, ry) and H (rx, dy), to a micrometre.  A
+    Sentinel-2 granule's 10 m and 20 m rasters share it (10,980 x 10 m
+    = 5,490 x 20 m = 109,800 m); adjacent granules never do."""
+    gt = s.gt
+    return (s.crs.name(), round(gt.x0, 6), round(gt.y0, 6),
+            round(s.width * gt.dx, 6), round(s.width * gt.ry, 6),
+            round(s.height * gt.rx, 6), round(s.height * gt.dy, 6))
+
+
+def _grid_key(s) -> tuple:
+    """What the scenes of one pixel grid of a band set share, so that
+    one param row and one gather window serve them: pixel size and
+    shear, true and bucket shape, dtype and nodata."""
+    gt = s.gt
+    return (gt.dx, gt.dy, gt.rx, gt.ry, s.height, s.width,
+            tuple(s.bucket), str(s.dtype),
+            None if np.isnan(s.nodata) else float(s.nodata))
+
+
+def _grid_sets(scenes, chans, n_chan: int, order=None, footprints=None):
+    """(sets, grid_of) for the fused band-set kernels, or None.  Sets
+    group the scenes by footprint (`_footprint`, or ``footprints[i]``
+    where the caller knows better; `_complete_sets`: one scene a
+    channel), each a list of scene indices in channel order (``order``
+    picks and orders the channels: an RGB request's `out_sel`);
+    ``grid_of`` maps a channel to its pixel grid within a set, grids
+    numbered finest first, and is None where all lie on one.  Every set
+    must split into grids alike and all lie in one CRS (one control
+    grid), and a set spans at most `_MAX_GRIDS` grids."""
+    sets = _complete_sets(footprints or [_footprint(s) for s in scenes],
+                          chans, n_chan)
+    if sets is None:
+        return None
+    if order is not None:
+        sets = [[m[c] for c in order] for m in sets]
+    keys = [_grid_key(scenes[i]) for i in sets[0]]
+    crs = scenes[sets[0][0]].crs
+    if any(scenes[m[0]].crs != crs
+           or [_grid_key(scenes[i]) for i in m] != keys for m in sets[1:]):
+        return None
+    grids = sorted(dict.fromkeys(keys), key=lambda k: (
+        abs(k[0] * k[1] - k[2] * k[3]), keys.index(k)))
+    if len(grids) > _MAX_GRIDS:
+        return None
+    grid_of = tuple(grids.index(k) for k in keys)
+    return sets, (grid_of if len(grids) > 1 else None)
+
+
+# the extra rows and columns a coarser grid's window takes over the
+# finest one's, scaled: its footprint is the finest's over the pixel
+# ratio plus both margins and a pixel each for floor and clip
+_GRID_WIN_PAD = 2 * _WIN_MARGIN + 4
+
+
+def _grid_windows(params64: np.ndarray, cx: np.ndarray, cy: np.ndarray,
+                  buckets):
+    """`_gather_windows` for sets on R grids: (wins, win0 (G, R, 2)) or
+    None.  ``params64`` (G, R, 11); ``buckets`` each grid's bucket.  The
+    finest grid's window is `_gather_windows`'; a coarser grid's is
+    derived from it alone (its size over the pixel ratio, padded, then
+    bucketed), so one window of the finest grid keys one program and the
+    program lattice does not multiply with R.  Each (set, grid) has its
+    own origin; None where a coarser footprint would not fit."""
+    made = _gather_windows(params64[:, 0], cx, cy, *buckets[0])
+    if made is None:
+        return None
+    (wr, wc), origin = made
+    G, R = params64.shape[:2]
+    win0 = np.zeros((G, R, 2), np.int32)
+    win0[:, 0] = origin
+    real = params64[params64[:, 0, 10] >= 0]
+    wins = [(wr, wc)]
+    for r in range(1, R):
+        bh, bw = buckets[r]
+        # one footprint: the pixel ratio is the ratio of the sizes
+        size = (min(_win_bucket(math.ceil(wr * real[0, r, 6]
+                                          / real[0, 0, 6])
+                                + _GRID_WIN_PAD), bh),
+                min(_win_bucket(math.ceil(wc * real[0, r, 7]
+                                          / real[0, 0, 7])
+                                + _GRID_WIN_PAD), bw))
+        for k in range(G):
+            p = params64[k, r]
+            b = None if p[10] < 0 else _granule_bounds(p, cx, cy)
+            if b is None:
+                continue
+            if b[1] - b[0] > size[0] or b[3] - b[2] > size[1]:
+                return None
+            win0[k, r] = (min(max(b[0], 0), bh - size[0]),
+                          min(max(b[2], 0), bw - size[1]))
+        wins.append(size)
+    return tuple(wins), win0
+
+
 class BandSets(NamedTuple):
     """What a channel-packed kernel (`ops.warp.render_rgba_ctrl`,
     `render_expr_ctrl`) takes for G granule sets of C bands each."""
     bands: tuple                # G C-tuples of scene arrays, as cached
-    params: np.ndarray          # (G, 11) f32, a row a set
+    params: np.ndarray          # (G, 11) f32, a row a set; (G, R, 11)
     prios: np.ndarray           # (G, C) f32, -inf rows are padding
-    key: tuple                  # (G, bh, bw, C): the dispatch key's shape
-    win: Optional[Tuple[int, int]]
-    win0: Optional[np.ndarray]  # (G, 2)
+    key: tuple                  # (G, bh, bw, C); (G, R, C) on R grids
+    win: Optional[tuple]        # (wr, wc); R of them on R grids
+    win0: Optional[np.ndarray]  # (G, 2); (G, R, 2) on R grids
+    grid_of: Optional[tuple]    # channel -> grid, None on one grid
 
 
-def _band_sets(chans, rows, chan_prios, windows) -> BandSets:
-    """Kernel operands for ``chans``, a list of sets, each the C
-    scenes (`DeviceScene`s of one bucket) of one grid in channel order:
-    ``rows[k]`` is set k's (affine6, height, width, nodata) param row,
-    ``chan_prios[k]`` its C mosaic priorities.  G is a power of two
-    (bounded jit variants): the filling repeats the first set with a
-    priority that never wins.  ``windows(params64)`` gives the gather
-    windows, (win, win0 (G, 2)) or None, of the SAME param rows the
-    kernel consumes."""
-    G = _bucket_pow2(len(chans))
-    C = len(chans[0])
-    bands = tuple(tuple(s.dev for s in got) for got in chans)
-    bands += (bands[0],) * (G - len(chans))
-    params = np.zeros((G, 11), np.float64)
-    params[:, 10] = -1.0
-    for k, row in enumerate(rows):
-        params[k, :9] = row
-        params[k, 10] = 0.0
-    prios = np.full((G, C), -np.inf, np.float32)
-    prios[:len(chans)] = chan_prios
-    win, win0 = (windows(params) if _window_mode() else None) \
-        or (None, None)
-    return BandSets(bands, params.astype(np.float32), prios,
-                    (G,) + tuple(chans[0][0].dev.shape) + (C,), win, win0)
+def _band_sets(scenes, sets, grid_of, prios, rows, cx, cy) -> BandSets:
+    """Kernel operands for ``sets`` (`_grid_sets`): each the indices of
+    its C scenes (`DeviceScene`s) in ``scenes``, in channel order, with
+    mosaic priorities ``prios[i]`` and param rows ``rows[i]`` (affine6
+    relative to the control grid's origin, height, width, nodata).  A
+    param row a (set, grid), (G, 11) where the sets lie on one grid.  G
+    is a power of two (bounded jit variants): the filling repeats the
+    first set with a priority that never wins.  The gather windows are
+    those of the same param rows, from the host control coordinates
+    ``cx``, ``cy`` (origin-relative, f64)."""
+    G = _bucket_pow2(len(sets))
+    C = len(sets[0])
+    lead = [grid_of.index(r) for r in range(max(grid_of) + 1)] \
+        if grid_of else [0]
+    bands = tuple(tuple(scenes[i].dev for i in m) for m in sets)
+    bands += (bands[0],) * (G - len(sets))
+    params = np.zeros((G, len(lead), 11), np.float64)
+    params[..., 10] = -1.0
+    for k, m in enumerate(sets):
+        for r, c in enumerate(lead):
+            params[k, r, :9] = rows[m[c]]
+            params[k, r, 10] = 0.0
+    chan_prios = np.full((G, C), -np.inf, np.float32)
+    chan_prios[:len(sets)] = [[prios[i] for i in m] for m in sets]
+    buckets = [tuple(scenes[sets[0][c]].dev.shape) for c in lead]
+    if grid_of is None:
+        params = params[:, 0]
+        key = (G,) + buckets[0] + (C,)
+    else:
+        key = (G, len(lead), C)
+    made = None
+    if _window_mode():
+        made = _gather_windows(params, cx, cy, *buckets[0]) \
+            if grid_of is None else _grid_windows(params, cx, cy, buckets)
+    win, win0 = made or (None, None)
+    return BandSets(bands, params.astype(np.float32), chan_prios, key,
+                    win, win0, grid_of)
+
+
+def _grids_arg(b: BandSets) -> dict:
+    """The kernels' ``grid_of`` keyword, left out on one grid: a call
+    with it and one without are two entries of jit's cache, and a
+    one-grid set must run the program prewarm and its past made."""
+    return {} if b.grid_of is None else {"grid_of": b.grid_of}
 
 
 class SceneGroup(NamedTuple):
@@ -288,6 +410,9 @@ class SceneGroup(NamedTuple):
     win: Optional[Tuple[int, int]]      # gather window, None = whole
     win0: Optional[np.ndarray]  # its origin: (2,), or (B, 2) unstacked
     scenes: list                # the real granules' DeviceScenes
+    # a geolocation grid's group: ctrl holds pixel coordinates and the
+    # param rows an identity affine
+    curvilinear: bool = False
 
 
 class WarpExecutor:
@@ -318,6 +443,25 @@ class WarpExecutor:
         # pressure / multi-CRS)
         self.paged_engaged = 0
         self.paged_declined = 0
+        # band sets the channel-packed kernels took, by how many pixel
+        # grids a set spans, and granule lists on several pixel sizes
+        # that formed none (/debug `band_grids`)
+        self.band_grids = {"sets_one_grid": 0, "sets_multi_grid": 0,
+                           "multi_grid_declined": 0}
+
+    def _note_grids(self, made, scenes) -> None:
+        """Count what `_grid_sets` made of ``scenes``: its sets by their
+        grids, or a decline where the scenes have several pixel sizes.
+        The open span (`tile.dispatch`) carries the sets' grid count."""
+        with self._lock:
+            if made is None:
+                if len({(abs(s.gt.dx), abs(s.gt.dy)) for s in scenes}) > 1:
+                    self.band_grids["multi_grid_declined"] += 1
+                return
+            sets, grid_of = made
+            self.band_grids["sets_one_grid" if grid_of is None
+                            else "sets_multi_grid"] += len(sets)
+        obs_set_attr(grids=1 if grid_of is None else max(grid_of) + 1)
 
     def _note_win(self, win) -> None:
         """Engagement telemetry, recorded at the dispatches that
@@ -596,7 +740,7 @@ class WarpExecutor:
         return combine_scored(canvs, bests)
 
 
-    def _choose_leg(self, group: "SceneGroup", n_pad: int,
+    def _choose_leg(self, group: Optional["SceneGroup"], n_pad: int,
                     lane_union: bool = False):
         """Which leg serves one scene group, from what the code can
         observe: ("spmd", the mesh dispatcher) under GSKY_SPMD compat
@@ -604,12 +748,14 @@ class WarpExecutor:
         paged kernels run (interpret mode only today: Mosaic refuses
         their gather) and the page pool takes the group, a wave where
         the tick scheduler is on; else ("bucketed", None), the leg a
-        TPU takes.  The ONE place the tile legs consult
-        `compat_spmd()`, `paged_enabled()` and `waves_enabled()`."""
+        TPU takes.  ``group`` None: scenes of several groups (band sets
+        over several pixel grids), which no page table serves.  The ONE
+        place the tile legs consult `compat_spmd()`, `paged_enabled()`
+        and `waves_enabled()`."""
         spmd = compat_spmd()
         if spmd is not None:
             return "spmd", spmd
-        if paged_enabled():
+        if group is not None and paged_enabled():
             made_p = self._paged_from_group(group, n_pad, lane_union)
             self._note_paged(made_p is not None)
             if made_p is not None:
@@ -803,22 +949,26 @@ class WarpExecutor:
         Returns a uint8 (H, W) array or None — the caller then runs
         the unfused `evaluate_expressions` leg (multi-CRS granule sets,
         granules that form no band sets, page budget, SPMD compat
-        mode)."""
-        g = self._scene_inputs(granules, ns_ids, prios, dst_gt,
-                               dst_crs, height, width, cache,
-                               stacked=False)
-        if g is None:
+        mode).  Scenes of several scene groups (a variable's band on a
+        coarser pixel grid: Sentinel-2's 20 m SWIR beside 10 m NIR) go
+        to the bucketed leg's band sets, which take up to three grids
+        a set."""
+        groups = self._scene_groups(granules, ns_ids, prios, dst_gt,
+                                    dst_crs, height, width, cache,
+                                    stacked=False, windowed=False)
+        if groups is None:
             return None
         n_pad = _bucket_pow2(n_slots)
-        leg, how = self._choose_leg(g, n_pad, lane_union=True)
+        leg, how = self._choose_leg(groups[0] if len(groups) == 1 else None,
+                                    n_pad, lane_union=True)
         if leg == "spmd":
             return None
         if leg == "bucketed":
             return self._render_expr_sets(
-                g, n_slots, fp, method, (height, width), offset, scale,
-                clip, colour_scale, auto)
+                groups, n_slots, fp, method, (height, width), offset,
+                scale, clip, colour_scale, auto)
         # the paged forms race a stacked XLA reference
-        g = self._stacked(g, cache)
+        g = self._stacked(groups[0], cache)
         sp = np.array([offset, scale, clip], np.float32)
         consts = fp.const_array()
         statics = (method, n_pad, (height, width), g.step, auto,
@@ -861,47 +1011,54 @@ class WarpExecutor:
                              lambda: _unfused_xla()[0],
                              wave=wave, paged=paged)
 
-    def _render_expr_sets(self, g: "SceneGroup", n_slots: int, fp,
-                          method: str, out_hw: Tuple[int, int],
+    def _render_expr_sets(self, groups: List["SceneGroup"], n_slots: int,
+                          fp, method: str, out_hw: Tuple[int, int],
                           offset: float, scale: float, clip: float,
                           colour_scale: int, auto: bool):
-        """`render_expr_byte`'s bucketed leg: the unstacked group's
-        scenes as band sets, one per grid with a scene per slot (rows
-        of one grid share their affine, size and nodata), through ONE
-        `ops.warp.render_expr_ctrl` dispatch.  None where the scenes
-        form no such sets (a grid that lacks a variable's band, two
-        dates on one grid): the unfused leg's."""
-        n = len(g.scenes)
-        p64 = g.params64
-        sets = _complete_sets([p64[k, :9].tobytes() for k in range(n)],
-                              [int(p64[k, 10]) for k in range(n)],
-                              n_slots)
-        if sets is None:
+        """`render_expr_byte`'s bucketed leg: the unstacked groups'
+        scenes as band sets (`_grid_sets`: one a footprint with a scene
+        a slot, on up to three pixel grids), through ONE
+        `ops.warp.render_expr_ctrl` dispatch on the first group's
+        control grid.  None where they form no such sets (a footprint
+        that lacks a variable's band, two dates on one footprint, sets
+        in several CRSs): the unfused leg's.  A curvilinear group's
+        control grid holds pixel coordinates: its sets are the bands of
+        one file (rows alike), and it shares a dispatch with no other."""
+        g0 = groups[0]
+        scenes = [s for g in groups for s in g.scenes]
+        p64 = np.concatenate([g.params64[:len(g.scenes)] for g in groups])
+        chans = p64[:, 10].astype(int).tolist()
+        made = None
+        if len(groups) == 1:
+            rows = p64[:, :9]           # on the group's own origin
+            made = _grid_sets(scenes, chans, n_slots, footprints=[
+                r.tobytes() for r in rows] if g0.curvilinear else None)
+        elif not any(g.curvilinear for g in groups):
+            # every row on the first group's origin, as its grid is
+            ox, oy = g0.scenes[0].gt.x0, g0.scenes[0].gt.y0
+            rows = [_inv_gt_params(s.gt, ox, oy)
+                    + (s.height, s.width, s.nodata) for s in scenes]
+            made = _grid_sets(scenes, chans, n_slots)
+        self._note_grids(made, scenes)
+        if made is None:
             return None
-
-        def windows(params):
-            # the group's own: a set's scenes share one row, so one
-            # footprint, and the group made a window for each scene
-            if g.win is None:
-                return None
-            win0 = np.zeros((len(params), 2), np.int32)
-            win0[:len(sets)] = g.win0[[m[0] for m in sets]]
-            return g.win, win0
-
-        b = _band_sets([[g.scenes[i] for i in m] for m in sets],
-                       [p64[m[0], :9] for m in sets],
-                       [[p64[i, 9] for i in m] for m in sets], windows)
+        sets, grid_of = made
+        b = _band_sets(scenes, sets, grid_of, p64[:, 9], rows,
+                       np.asarray(g0.ctrl[0], np.float64),
+                       np.asarray(g0.ctrl[1], np.float64))
         from ..ops.paged import note_expr_fused
         from ..ops.warp import render_expr_ctrl
-        self._count("render_expr", (b.key, b.win))
+        self._count("render_expr" if grid_of is None else "render_expr_mg",
+                    (b.key, b.win))
         self._note_win(b.win)
         note_expr_fused("bucketed")
         sp = np.array([offset, scale, clip], np.float32)
         return _prefetch(render_expr_ctrl(
-            b.bands, g.ctrl_dev, jnp.asarray(b.params),
+            b.bands, g0.ctrl_dev, jnp.asarray(b.params),
             jnp.asarray(b.prios), jnp.asarray(sp),
-            jnp.asarray(fp.const_array()), fp.key, method, out_hw, g.step,
-            auto, colour_scale, win=b.win, win0=_dev_win0(b.win0)))
+            jnp.asarray(fp.const_array()), fp.key, method, out_hw, g0.step,
+            auto, colour_scale, win=b.win, win0=_dev_win0(b.win0),
+            **_grids_arg(b)))
 
     def render_bands_byte(self, granules, ns_ids: Sequence[int],
                           prios: Sequence[float], dst_gt: GeoTransform,
@@ -951,43 +1108,33 @@ class WarpExecutor:
         falls back to the per-band path)."""
         if len(out_sel) != 3 or sorted(out_sel) != [0, 1, 2]:
             return None
-        if any(g.geo_loc or g.srs != granules[0].srs for g in granules):
+        if any(g.geo_loc for g in granules):
             return None
-        sets = _complete_sets([tuple(g.geo_transform) for g in granules],
-                              ns_ids, 3)
-        if sets is None:
-            return None
-        from ..geo.crs import parse_crs
         from .scene_cache import default_scene_cache
         cache = cache or default_scene_cache
-        g0 = granules[0]
-        try:
-            src_crs = parse_crs(g0.srs) if g0.srs else None
-        except ValueError:
-            return None
-        if src_crs is None:
-            return None
-        stride = self._granule_stride(g0, dst_gt, dst_crs, height, width)
         rgba_bbox = dst_gt.bbox(width, height)
+        # one cache level a pixel size, the first granule's of that size
+        # picks it, so that a set's bands of one grid share a level
+        strides: Dict[tuple, float] = {}
+        scenes = []
+        for g in granules:
+            size = (abs(g.geo_transform[1]), abs(g.geo_transform[5]))
+            if size not in strides:
+                strides[size] = self._granule_stride(g, dst_gt, dst_crs,
+                                                     height, width)
+            s = cache.get(g, strides[size], dst_bbox=rgba_bbox,
+                          dst_crs=dst_crs)
+            if s is None:
+                return None
+            scenes.append(s)
         # out_sel maps expression order -> ns id: channel k of a set
         # comes from its granule whose ns id equals out_sel[k]
-        chans, chan_prios = [], []
-        for members in sets:
-            picked = [members[ns] for ns in out_sel]
-            got = [cache.get(granules[i], stride, dst_bbox=rgba_bbox,
-                             dst_crs=dst_crs) for i in picked]
-            if any(s is None for s in got):
-                return None
-            chans.append(got)
-            chan_prios.append([prios[i] for i in picked])
-        s0 = chans[0][0]
-        for s in (s for got in chans for s in got):
-            if s.bucket != s0.bucket or s.dtype != s0.dtype \
-                    or s.crs != s0.crs \
-                    or not (np.isnan(s.nodata) and np.isnan(s0.nodata)
-                            or s.nodata == s0.nodata) \
-                    or (s.height, s.width) != (s0.height, s0.width):
-                return None
+        made = _grid_sets(scenes, ns_ids, 3, out_sel)
+        self._note_grids(made, scenes)
+        if made is None:
+            return None
+        sets, grid_of = made
+        s0 = scenes[sets[0][0]]
         sx, sy, step = self._ctrl_geo_coords(dst_gt, dst_crs, height,
                                              width, s0.crs, 16)
         ox, oy = s0.gt.x0, s0.gt.y0
@@ -998,19 +1145,20 @@ class WarpExecutor:
             ctrl_dev = jnp.asarray(
                 np.stack([sx - ox, sy - oy]).astype(np.float32))
             self._geo_cache_put(dkey, ctrl_dev)
-        b = _band_sets(
-            chans, [_inv_gt_params(got[0].gt, ox, oy)
-                    + (s0.height, s0.width, s0.nodata) for got in chans],
-            chan_prios, lambda params: _gather_windows(
-                params, sx - ox, sy - oy, *s0.bucket))
+        b = _band_sets(scenes, sets, grid_of, prios,
+                       [_inv_gt_params(s.gt, ox, oy)
+                        + (s.height, s.width, s.nodata) for s in scenes],
+                       sx - ox, sy - oy)
         from ..ops.warp import render_rgba_ctrl
-        self._count("render_rgba", (b.key, b.win))
+        self._count("render_rgba" if grid_of is None else "render_rgba_mg",
+                    (b.key, b.win))
         self._note_win(b.win)
         sp = np.array([offset, scale, clip], np.float32)
         return _prefetch(render_rgba_ctrl(
             b.bands, ctrl_dev, jnp.asarray(b.params), jnp.asarray(b.prios),
             jnp.asarray(sp), method, (height, width), step, auto,
-            colour_scale, win=b.win, win0=_dev_win0(b.win0)))
+            colour_scale, win=b.win, win0=_dev_win0(b.win0),
+            **_grids_arg(b)))
 
     def _note_paged(self, engaged: bool) -> None:
         with self._lock:
@@ -1182,7 +1330,8 @@ class WarpExecutor:
         return out
 
     def _scene_groups(self, granules, ns_ids, prios, dst_gt, dst_crs,
-                      height, width, cache=None, stacked=True):
+                      height, width, cache=None, stacked=True,
+                      windowed=True):
         """Device inputs for the fused scene kernels, grouped by
         (source CRS, bucket shape, dtype) — curvilinear granules group
         by their geolocation arrays instead: one `SceneGroup` each;
@@ -1197,7 +1346,8 @@ class WarpExecutor:
         gather windows (its `win0` is then (B, 2), an origin a scene:
         `_gather_windows`).  B is a power of two (bounded jit variants);
         the filling repeats the first scene, which costs the tuple
-        nothing."""
+        nothing.  ``windowed=False`` leaves an unstacked group without a
+        window (its caller makes its own)."""
         from .scene_cache import default_scene_cache
         cache = cache or default_scene_cache
         scenes = []
@@ -1270,7 +1420,7 @@ class WarpExecutor:
             skey = tuple(s.serial for s in gs) + (B,)
             devs = tuple(s.dev for s in gs) + (s0.dev,) * (B - len(gs))
             win = win0 = None
-            if _window_mode() and not stacked:
+            if _window_mode() and not stacked and windowed:
                 made_w = _gather_windows(
                     params, np.asarray(ctrl[0], np.float64),
                     np.asarray(ctrl[1], np.float64), *s0.bucket)
@@ -1279,7 +1429,8 @@ class WarpExecutor:
             group = SceneGroup(
                 stack=devs, ctrl=ctrl, ctrl_dev=ctrl_dev,
                 params=params.astype(np.float32), params64=params,
-                step=step, skey=skey, win=win, win0=win0, scenes=gs)
+                step=step, skey=skey, win=win, win0=win0, scenes=gs,
+                curvilinear=is_gl)
             groups.append(self._stacked(group, cache) if stacked
                           else group)
         return groups

@@ -445,6 +445,10 @@ class OWSServer:
                         pages._default.stats()
             except Exception:  # no page pool allocated yet
                 pass
+            # band sets the channel-packed kernels formed, by the pixel
+            # grids a set spans (docs/OBSERVABILITY.md)
+            with ex._lock:
+                doc["band_grids"] = dict(ex.band_grids)
             doc["scene_cache_bytes"] = sc._bytes
             doc["drill_cache_bytes"] = dc._bytes
         except Exception:  # executor tier unbooted - /debug still serves

@@ -429,7 +429,10 @@ def _fake_group(B=3, sh=200, sw=220, h=96, w=96, step=16, shift=True):
     """A crafted `executor.SceneGroup` so executor tests drive the real
     `_paged_from_group` span logic without a scene cache: B granules,
     one with its affine shifted off the top-left edge (partial page
-    coverage)."""
+    coverage), each scene on a footprint of its own (what the band-set
+    leg groups by)."""
+    from gsky_tpu.geo.crs import EPSG3857
+    from gsky_tpu.geo.transform import GeoTransform
     from gsky_tpu.pipeline.executor import SceneGroup, _bucket_pow2
     rng = np.random.default_rng(21)
     scenes = rng.uniform(0.0, 100.0, (B, sh, sw)).astype(np.float32)
@@ -450,7 +453,10 @@ def _fake_group(B=3, sh=200, sw=220, h=96, w=96, step=16, shift=True):
                     dtype=np.float32)[None, :].repeat(gh, 0),
         np.linspace(4.0, sh - 10.0, gh,
                     dtype=np.float32)[:, None].repeat(gw, 1)])
-    gs = [SimpleNamespace(dev=jnp.asarray(scenes[k]), serial=500 + k)
+    gs = [SimpleNamespace(dev=jnp.asarray(scenes[k]), serial=500 + k,
+                          gt=GeoTransform(1000.0 * k, 1.0, 0.0, 0.0, 0.0,
+                                          -1.0),
+                          crs=EPSG3857, height=sh, width=sw, nodata=-999.0)
           for k in range(B)]
     devs = [g.dev for g in gs] + [gs[0].dev] * (Bp - B)
     stack = jnp.stack(devs)

@@ -531,3 +531,78 @@ def _():
         np.testing.assert_allclose(out_b[k][both],
                                    np.asarray(o)[both], rtol=1e-5)
 
+
+
+# --- band sets over two pixel grids (Sentinel-2 10 m + 20 m) -------------
+
+@check("multigrid_sets_match_reference")
+def _():
+    """A four-granule false-colour tile (SWIR at 20 m beside NIR and
+    green at 10 m) and an NBR tile, each set on two grids, through the
+    served path's kernels ON THE CHIP (each grid from its own gather
+    window) against the plain references, within the benchmark cell's
+    bound."""
+    import datetime as dt
+    import tempfile
+
+    from benchmarks import reference, reference_expr, reference_rgb
+    from benchmarks.archives import sentinel2_bands_by_res as s2r
+    from gsky_tpu.geo.crs import EPSG3857
+    from gsky_tpu.geo.transform import BBox
+    from gsky_tpu.index import MASClient, MASStore
+    from gsky_tpu.pipeline import GeoTileRequest, TilePipeline
+    from gsky_tpu.pipeline.executor import WarpExecutor
+    from gsky_tpu.pipeline.tile_stages import render_staged
+
+    x0, y0 = 399960.0, 6200020.0
+    archive = {
+        "kind": "sentinel2_bands_by_res", "collection": "s2",
+        "file_prefix": "S2A_T55H", "crs": "EPSG:32755", "origin": [x0, y0],
+        "pitch_m": 6380.0, "grid": [2, 2], "date": "2020-01-10",
+        "resolutions": {
+            "r10m": {"res": 10.0, "granule_hw": [700, 700], "wedge_px": 44},
+            "r20m": {"res": 20.0, "granule_hw": [350, 350],
+                     "wedge_px": 22}},
+        "bands": [{"name": n, "namespace": ns, "base": b, "resolution": r}
+                  for n, ns, b, r in (
+                      ("green", "nbart_green", 900, "r10m"),
+                      ("nir", "nbart_nir_1", 3200, "r10m"),
+                      ("swir2", "nbart_swir_2", 2400, "r20m"),
+                      ("swir3", "nbart_swir_3", 1600, "r20m"))],
+        "nodata": -999, "compress": False}
+    stamp = dt.datetime(2020, 1, 10, tzinfo=dt.timezone.utc).timestamp()
+    store = MASStore()
+    with tempfile.TemporaryDirectory() as root:
+        for rec in s2r.build(archive, 43, root):
+            store.ingest(rec)
+        sources = s2r.sources(archive, 43)
+        xs = np.array([x0 + 6380.0 - 900.0, x0 + 6380.0 + 1300.0])
+        ys = np.array([y0 - 6380.0 - 1100.0, y0 - 6380.0 + 1100.0])
+        mx, my = reference.project(xs, ys, "EPSG:32755", "EPSG:3857")
+        bbox = (float(mx[0]), float(my[0]), float(mx[1]), float(my[1]))
+        fc = ["nbart_swir_2", "nbart_nir_1", "nbart_green"]
+        nbr = "(nbart_nir_1 - nbart_swir_3) / (nbart_nir_1 + nbart_swir_3)"
+        for bands, n, style in ((fc, 3, (0.0, 254.0 / 4500.0, 4500.0)),
+                                (["nbr = " + nbr], 1, (1.0, 127.0, 2.0))):
+            pipe = TilePipeline(MASClient(store), executor=WarpExecutor())
+            req = GeoTileRequest(
+                collection=root + "/s2", bands=bands, bbox=BBox(*bbox),
+                crs=EPSG3857, width=256, height=256, start_time=stamp,
+                end_time=None, resample="bilinear")
+            kind, got = render_staged(pipe, req, n, *style, 0, False)
+            (leg, _), = pipe.executor.bucket_stats.items()
+            assert leg.startswith(("render_rgba_mg:((4, 2, 3), ((",
+                                   "render_expr_mg:((4, 2, 2), ((")), leg
+            if n == 3:
+                want = reference_rgb.render_rgba(
+                    reference_rgb.select_rgb(sources, fc, stamp), bbox,
+                    "EPSG:3857", 256, 256, "bilinear", *style)
+                rec = reference_rgb.compare(got, want)
+            else:
+                names = reference_expr.variables(reference_expr.parse(nbr))
+                want = reference_expr.render_byte(
+                    nbr, reference_expr.select_vars(sources, names, stamp),
+                    bbox, "EPSG:3857", 256, 256, "bilinear", *style)
+                rec = reference_expr.compare(got, want)
+            assert rec["mismatch"] <= 0.005 and rec["max_byte_diff"] <= 1, \
+                (leg, rec)
