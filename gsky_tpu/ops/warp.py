@@ -454,6 +454,93 @@ def _gather2d_c(src, ri, ci):
     return src.reshape(-1, C)[ri * W + ci]
 
 
+# The most one program's neighbourhood fetches may add to its device
+# memory by `_unfold_bytes`, summed over every source it resamples:
+# above it the program gathers its cubic taps one at a time.  The
+# export's tile (a 1536² int16 window, depth 1) counts 71 MB and its
+# compiled temp falls (81 -> 50 MB); a 4096² int16 window counts 302 MB
+# but took 1.3 GB of temp, and ten whole 7680 x 7936 scenes (the
+# executor declines a window that would be the whole stack) did not
+# compile in 16 GB (PERF.md, section 6; `tools/tap_probe.py`).
+_UNFOLD_BYTES = 128 << 20
+
+
+def _unfold_bytes(shape, dtype, n_out: int) -> int:
+    """What `_tap_pairs` adds for one (H, W, C) source of ``dtype``
+    resampled at ``n_out`` pixels: the unfolded copy (8 values a value
+    of the source padded by 3) and the two rows of 8 C gathered a
+    pixel, for each plane the kernel form gathers (tap-side: the
+    native values; mask-gather: f32 values and a validity byte)."""
+    H, W, C = shape
+    size = np.dtype(dtype).itemsize if _use_tapside() else 5
+    return ((H + 5) * (W + 3) + 2 * n_out) * 8 * C * size
+
+
+def _unfolds(method: str, sources, n_out: int) -> bool:
+    """Whether a program that resamples each of ``sources`` ((H, W, C)
+    shape, dtype) at ``n_out`` pixels fetches its cubic taps as
+    neighbourhoods (`_tap_pairs`): only a cubic tap set, and only while
+    the copies fit `_UNFOLD_BYTES` together.  Decided from static shapes
+    at trace time, so each program has one form."""
+    return method == "cubic" and sum(
+        _unfold_bytes(s, d, n_out) for s, d in sources) <= _UNFOLD_BYTES
+
+
+def _scored_sources(stack, win):
+    """The (shape, dtype) of each source `_warp_scenes_scored` resamples
+    from ``stack`` under gather window ``win``."""
+    if isinstance(stack, (tuple, list)):
+        return [(tuple(win or s.shape) + (1,), s.dtype) for s in stack]
+    return [(tuple(win or stack.shape[1:]) + (1,), stack.dtype)] \
+        * stack.shape[0]
+
+
+def tap_form(method: str, stack, win, out_hw: Tuple[int, int]) -> str:
+    """How `warp_scenes_ctrl_scored` over ``stack`` (B, H, W), or a
+    tuple of B (H, W) scenes, with gather window ``win`` fetches a
+    pixel's taps for an ``out_hw`` tile: ``neighbourhood`` (a cubic set
+    in two row gathers, `_tap_pairs`) or ``per_tap`` (a gather a tap:
+    nearest, bilinear, and a cubic set whose copies would not fit
+    `_UNFOLD_BYTES`; `_unfolds`)."""
+    return "neighbourhood" if _unfolds(
+        method, _scored_sources(stack, win), out_hw[0] * out_hw[1]) \
+        else "per_tap"
+
+
+def _tap_pairs(src, r0, c0):
+    """Every pixel's 4 x 4 cubic neighbourhood in two gathers: src
+    (H, W, C), r0/c0 (h, w) int32 the floor of each pixel's coordinates
+    -> 16 arrays (h, w, C), tap (dr, dc) at 4 dr + dc holding
+    src[r0 + dr - 1, c0 + dc - 1] wherever that lies inside src (a tap
+    outside reads the zero padding or some other pixel: the caller's
+    bounds test masks it, as it masks the clipped per-tap index).
+
+    XLA's TPU gather resolves a slice that spans two dimensions, or part
+    of the minor one, by a loop of one dynamic slice an index: for the
+    1,048,576 pixels of a 1024² tile a (4, 4) slice took 1.8 s and four
+    (1, 4) slices 8.3 s, where the 16 scalar gathers took 122 ms.  Whole
+    rows of a 2D operand it gathers at about a scalar's cost.  So the
+    source, padded by 3 on each side, is unfolded first: row k of
+    ``pairs`` holds the 2 x 4 block whose corner is pixel k of the
+    padded source, and one gather of rows fetches tap rows 0-1, a second
+    tap rows 2-3 two rows on (8.0 ms; PERF.md, section 6).  The copy holds 8
+    values a source value: 76 MB for a 1536² f32 gather window."""
+    H, W, C = src.shape
+    sp = jnp.pad(src, ((3, 3), (3, 3), (0, 0)))
+    # the corners of the blocks that reach the source; tap rows 2-3
+    # start two corner rows on, so the unfolded rows run to H + 4
+    rs = jnp.clip(r0, -2, H) + 2
+    cs = jnp.clip(c0, -2, W) + 2
+    hp, wp = H + 5, W + 3
+    pairs = jnp.stack([sp[dr:dr + hp, dc:dc + wp]
+                       for dr in (0, 1) for dc in range(4)], axis=2)
+    pairs = pairs.reshape(hp * wp, 8 * C)
+    k = rs * wp + cs
+    halves = [pairs[k].reshape(r0.shape + (8, C)),
+              pairs[k + 2 * wp].reshape(r0.shape + (8, C))]
+    return [h[..., j, :] for h in halves for j in range(8)]
+
+
 def _use_tapside() -> bool:
     """Kernel form selector, evaluated at TRACE time (the backend is
     fixed for the life of the process): tap-side validation avoids the
@@ -466,22 +553,30 @@ def _use_tapside() -> bool:
     return tpu_like_backend()
 
 
-def _resample_c(src, nodata, rows, cols, method: str):
+def _resample_c(src, nodata, rows, cols, method: str,
+                unfold: bool = False):
     """Channel-vectorised resample from a NATIVE-dtype channel-last
     source: src (H, W, C), rows/cols (h, w) -> (out (h, w, C) f32, ok
     (h, w, C) bool).  The index math runs ONCE for all C channels.
     Validity semantics are identical in both kernel forms (it is a pure
     function of the stored value); `_use_tapside` picks the form that
-    fits the backend."""
+    fits the backend.  ``unfold`` (static; the program decides it,
+    `_unfolds`) fetches a cubic tap set as neighbourhoods (`_tap_pairs`):
+    the same 16 values, weights, validity test and accumulation order as
+    a gather a tap, bit for bit."""
     if method not in ("near", "nearest", "bilinear", "cubic"):
         # the tap table below would silently render an unknown name as
         # cubic; keep the old _METHODS[method] KeyError contract
         raise KeyError(f"unknown resample method {method!r}")
     H, W, C = src.shape
 
+    # planes: what a tap gathers; tap(fetch, inb): fetch(i) gives plane
+    # i at the tap -> (value zeroed where invalid, ok)
     if _use_tapside():
-        def tap(ri, ci, inb):
-            v = _gather2d_c(src, ri, ci).astype(jnp.float32)
+        planes = (src,)
+
+        def tap(fetch, inb):
+            v = fetch(0).astype(jnp.float32)
             ok = inb[..., None] & jnp.isfinite(v) & (v != nodata)
             return jnp.where(ok, v, 0.0), ok
     else:
@@ -489,21 +584,25 @@ def _resample_c(src, nodata, rows, cols, method: str):
         # gather the zeroed values + a precomputed validity plane
         sf = src.astype(jnp.float32)
         validp = jnp.isfinite(sf) & (sf != nodata)
-        srcz = jnp.where(validp, sf, 0.0)
+        planes = (jnp.where(validp, sf, 0.0), validp)
 
-        def tap(ri, ci, inb):
-            v = _gather2d_c(srcz, ri, ci)
-            ok = inb[..., None] & _gather2d_c(validp, ri, ci)
+        def tap(fetch, inb):
+            v = fetch(0)
+            ok = inb[..., None] & fetch(1)
             # zero values where ok is False: raw outputs at invalid
             # pixels stay identical between the two kernel forms
             return jnp.where(ok, v, 0.0), ok
+
+    def gathered(ri, ci):
+        return lambda i: _gather2d_c(planes[i], ri, ci)
 
     if method in ("near", "nearest"):
         ri = jnp.floor(rows + (0.5 + 1e-10)).astype(jnp.int32)
         ci = jnp.floor(cols + (0.5 + 1e-10)).astype(jnp.int32)
         inb = (ri >= 0) & (ri < H) & (ci >= 0) & (ci < W) \
             & jnp.isfinite(rows) & jnp.isfinite(cols)
-        return tap(jnp.clip(ri, 0, H - 1), jnp.clip(ci, 0, W - 1), inb)
+        return tap(gathered(jnp.clip(ri, 0, H - 1),
+                            jnp.clip(ci, 0, W - 1)), inb)
     finite = jnp.isfinite(rows) & jnp.isfinite(cols)
     rows = jnp.where(finite, rows, -10.0)
     cols = jnp.where(finite, cols, -10.0)
@@ -523,14 +622,17 @@ def _resample_c(src, nodata, rows, cols, method: str):
         taps = [(dr - 1, dc - 1, wr[dr] * wc[dc])
                 for dr in range(4) for dc in range(4)]
         thresh = 0.05
+    nbs = [_tap_pairs(p, r0, c0) for p in planes] \
+        if unfold and method == "cubic" else None
     acc = jnp.zeros(rows.shape + (C,), jnp.float32)
     wacc = jnp.zeros(rows.shape + (C,), jnp.float32)
-    for dr, dc, w in taps:
+    for k, (dr, dc, w) in enumerate(taps):
         ri = r0 + dr
         ci = c0 + dc
         inb = (ri >= 0) & (ri < H) & (ci >= 0) & (ci < W)
-        v, okt = tap(jnp.clip(ri, 0, H - 1), jnp.clip(ci, 0, W - 1),
-                     inb)
+        fetch = gathered(jnp.clip(ri, 0, H - 1), jnp.clip(ci, 0, W - 1)) \
+            if nbs is None else (lambda i, k=k: nbs[i][k])
+        v, okt = tap(fetch, inb)
         okf = okt.astype(jnp.float32)
         acc = acc + w[..., None] * okf * v
         wacc = wacc + w[..., None] * okf
@@ -539,7 +641,8 @@ def _resample_c(src, nodata, rows, cols, method: str):
     return out, ok
 
 
-def _grid_taps(bands, params, win0, at, sx, sy, method: str, win):
+def _grid_taps(bands, params, win0, at, sx, sy, method: str, win,
+               unfold: bool):
     """Warp indices, tap weights and the vector taps of ONE pixel grid
     of a band set: ``bands`` the (sh, sw) scenes of the channels on that
     grid, ``params[at]`` its param row, ``win0[at]`` its window origin
@@ -556,7 +659,7 @@ def _grid_taps(bands, params, win0, at, sx, sy, method: str, win):
         rows = rows - cut[0][1]
         cols = cols - cut[0][2]
     return _resample_c(jnp.stack(bands, axis=-1), p[8], rows, cols,
-                       method)
+                       method, unfold)
 
 
 def _mosaic_band_sets(granules, ctrl, params, prios, method: str,
@@ -586,6 +689,12 @@ def _mosaic_band_sets(granules, ctrl, params, prios, method: str,
     one = len(grids) == 1
     sx = _bilerp_grid(ctrl[0], h, w, step)
     sy = _bilerp_grid(ctrl[1], h, w, step)
+    wins = [win if one or win is None else win[r]
+            for r in range(len(grids))]
+    unfold = _unfolds(method, [
+        (tuple(wins[r] or bands[cs[0]].shape) + (len(cs),),
+         jnp.result_type(*[bands[c] for c in cs]))
+        for bands in granules for r, cs in enumerate(grids)], h * w)
     data = [jnp.zeros((h, w, len(cs)), jnp.float32) for cs in grids]
     best = [jnp.full((h, w, len(cs)), -jnp.inf, jnp.float32)
             for cs in grids]
@@ -593,7 +702,7 @@ def _mosaic_band_sets(granules, ctrl, params, prios, method: str,
         for r, cs in enumerate(grids):
             d, o = _grid_taps([bands[c] for c in cs], params, win0,
                               k if one else (k, r), sx, sy, method,
-                              win if one or win is None else win[r])
+                              wins[r], unfold)
             score = jnp.where(o, prios[k] if one
                               else prios[k, np.asarray(cs)], -jnp.inf)
             take = score > best[r]
@@ -779,7 +888,8 @@ def warp_scenes_batch(stack, sxy, params, method: str = "near",
     return _warp_scenes_core(stack, sxy[0], sxy[1], params, method, n_ns)
 
 
-def _resample_native(src, nodata, rows, cols, method: str):
+def _resample_native(src, nodata, rows, cols, method: str,
+                     unfold: bool = False):
     """Resample directly from a NATIVE-dtype (H, W) source, deriving
     validity from each gathered tap's VALUE (finite and != nodata)
     instead of pre-materialising full-scene f32 + validity arrays.  For
@@ -789,7 +899,8 @@ def _resample_native(src, nodata, rows, cols, method: str):
     function of the stored value.  Implemented as the C=1 case of
     `_resample_c` (XLA folds the size-1 channel axis away), so the tap
     machinery exists once."""
-    out, ok = _resample_c(src[..., None], nodata, rows, cols, method)
+    out, ok = _resample_c(src[..., None], nodata, rows, cols, method,
+                          unfold)
     return out[..., 0], ok[..., 0]
 
 
@@ -810,8 +921,11 @@ def _warp_scenes_scored(stack, sx, sy, params, method: str, n_ns: int,
     the taps the unwindowed one does (nearest: bit-identical;
     interpolated: 1-ulp XLA-contraction differences between the two
     programs).  ``stack`` may also be a tuple of (H, W) scenes, with
-    win0 (B, 2): see below.
+    win0 (B, 2): see below.  A cubic tap set comes as neighbourhoods
+    while their copies fit (`_unfolds`, `tap_form`).
     """
+    unfold = _unfolds(method, _scored_sources(stack, win), sx.size)
+
     def per(scene, p, r0=None, c0=None):
         cols = (p[0] + p[1] * sx + p[2] * sy) - 0.5
         rows = (p[3] + p[4] * sx + p[5] * sy) - 0.5
@@ -821,7 +935,7 @@ def _warp_scenes_scored(stack, sx, sy, params, method: str, n_ns: int,
         if r0 is not None:
             rows = rows - r0
             cols = cols - c0
-        return _resample_native(scene, p[8], rows, cols, method)
+        return _resample_native(scene, p[8], rows, cols, method, unfold)
 
     if isinstance(stack, (tuple, list)):
         # the scenes as the scene cache holds them, one (H, W) array

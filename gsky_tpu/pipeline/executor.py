@@ -10,6 +10,7 @@ host and only the cheap affine part is per-granule.
 
 from __future__ import annotations
 
+import contextvars
 import math
 import os
 import threading
@@ -25,7 +26,7 @@ from ..geo.transform import GeoTransform
 from ..ops.paged import PARAMS_W as PAGED_PARAMS_W
 from ..ops.paged import paged_enabled
 from ..ops.pallas_tpu import render_byte_raced, warp_scored_raced
-from ..ops.warp import (combine_scored, render_scenes_bands_ctrl,
+from ..ops.warp import (combine_scored, render_scenes_bands_ctrl, tap_form,
                         warp_gather_batch)
 from ..mesh.dispatch import compat_spmd
 from ..obs import set_attr as obs_set_attr
@@ -33,6 +34,11 @@ from .decode import DecodedWindow
 
 # padded source-window shape buckets (H and W independently bucketed)
 _BUCKETS = (64, 128, 256, 512, 1024, 2048, 4096)
+
+# how the scored warps a context dispatched last fetched their taps
+# (`WarpExecutor._note_taps`); the export reads it back for each tile
+TAP_FORM: contextvars.ContextVar = contextvars.ContextVar(
+    "gsky_tap_form", default=None)
 
 
 def _prefetch(x):
@@ -463,6 +469,15 @@ class WarpExecutor:
                             else "sets_multi_grid"] += len(sets)
         obs_set_attr(grids=1 if grid_of is None else max(grid_of) + 1)
 
+    @staticmethod
+    def _note_taps(method: str, parts, out_hw) -> None:
+        """Record in `TAP_FORM` how the XLA scored program fetches its
+        taps over each (stack, window) of ``parts`` (`ops.warp.tap_form`,
+        decided from the shapes it is handed): ``per_tap`` where any
+        part gathers a tap at a time, else ``neighbourhood``."""
+        forms = {tap_form(method, st, win, out_hw) for st, win in parts}
+        TAP_FORM.set("per_tap" if "per_tap" in forms else "neighbourhood")
+
     def _note_win(self, win) -> None:
         """Engagement telemetry, recorded at the dispatches that
         actually pass ``win`` to a kernel."""
@@ -706,7 +721,7 @@ class WarpExecutor:
         for i, wdw in enumerate(windows):
             by_crs.setdefault(wdw.src_crs, []).append(i)
         n_pad = _bucket_pow2(n_ns)
-        parts = []
+        parts, srcs = [], []
         for crs, idxs in by_crs.items():
             sx, sy, step = self._ctrl_geo_coords(dst_gt, dst_crs, height,
                                                  width, crs, 16)
@@ -728,10 +743,12 @@ class WarpExecutor:
                 params[k, 8] = np.nan   # validity is NaN-encoded in src
                 params[k, 9] = prios[i]
                 params[k, 10] = ns_ids[i]
+            srcs.append((src, None))
             parts.append(warp_scored_raced(
                 jnp.asarray(src), jnp.asarray(ctrl),
                 jnp.asarray(params.astype(np.float32)), method, n_pad,
                 (height, width), step))
+        self._note_taps(method, srcs, (height, width))
         if len(parts) == 1:
             canv, best = parts[0]
             return canv, best > -jnp.inf
@@ -838,6 +855,8 @@ class WarpExecutor:
             self._count("scene_mosaic_multicrs", len(groups))
             for g in groups:
                 self._note_win(g.win)
+            self._note_taps(method, [(g.stack, g.win) for g in groups],
+                            (height, width))
             parts = [scored(g) for g in groups]
             return combine_scored(jnp.stack([p[0] for p in parts]),
                                   jnp.stack([p[1] for p in parts]))
@@ -871,8 +890,11 @@ class WarpExecutor:
                 _xla)
             return canvs[0], bests[0]
 
+        leg, how = self._choose_leg(g, n_pad)
+        if leg == "bucketed":
+            self._note_taps(method, [(g.stack, g.win)], (height, width))
         return self._run_leg(
-            *self._choose_leg(g, n_pad), g, "scene_mosaic",
+            leg, how, g, "scene_mosaic",
             lambda cb: (cb[0], cb[1] > -jnp.inf),
             lambda: scored(g),
             spmd=lambda mesh: mesh.mosaic_scored(

@@ -77,7 +77,7 @@ from ..geo.transform import BBox, transform_bbox
 from ..obs import span as obs_span
 from ..resilience import check_partial
 from .decode import decode_window
-from .executor import _prefetch
+from .executor import TAP_FORM, _prefetch
 from .tile import _empty_result, evaluate_expressions, ns_prio
 from .types import Granule
 
@@ -159,8 +159,11 @@ class ExportPipeline:
         # scene keys whose memo decode RAISED (vs. merely not
         # intersecting): feeds the partial-failure degradation policy
         self._memo_failed: set = set()
-        # route -> tiles rendered that way; a batch renders concurrently
+        # route -> tiles rendered that way, and tap form -> tiles whose
+        # scored kernel fetched its taps that way; a batch renders
+        # concurrently
         self._routes: Dict[str, int] = {}
+        self._tap_forms: Dict[str, int] = {}
         self._routes_lock = threading.Lock()
         # tile index -> co-submission batch id (filled by _plan)
         self._batch_of: List[int] = list(range(len(self.tiles)))
@@ -356,12 +359,22 @@ class ExportPipeline:
         route `_render_route` took: `resident` (the fused kernel over
         scenes the device holds), `fallback` (the export-wide
         host-decoded windows), `modular` or `empty`.  The first two
-        reach the stats (`tiles_resident` / `tiles_fallback`)."""
+        reach the stats (`tiles_resident` / `tiles_fallback`).  Where
+        the tile dispatched the XLA scored kernel, how that program
+        fetches its taps as the executor recorded it (`executor.TAP_FORM`:
+        `neighbourhood` or `per_tap`) reaches them too, and the span as
+        `tap_form`."""
         with obs_span("export.tile") as sp:
+            TAP_FORM.set(None)
             res, route = self._render_route(req, gs)
+            form = TAP_FORM.get()
             sp.set(route=route)
+            if form is not None:
+                sp.set(tap_form=form)
         with self._routes_lock:
             self._routes[route] = self._routes.get(route, 0) + 1
+            if form is not None:
+                self._tap_forms[form] = self._tap_forms.get(form, 0) + 1
         return res
 
     def _render_route(self, req, gs: List[Granule]):
@@ -604,6 +617,9 @@ class ExportPipeline:
         self.stats["readback_bytes"] = sum(b[1] for b in enc_busy)
         with self._routes_lock:
             routes = dict(self._routes)
+            forms = dict(self._tap_forms)
+        self.stats["tap_form"] = {f: forms.get(f, 0)
+                                  for f in ("neighbourhood", "per_tap")}
         self.stats["tiles_resident"] = routes.get("resident", 0)
         self.stats["tiles_fallback"] = routes.get("fallback", 0)
         self.stats["wall_s"] = round(time.monotonic() - t0, 6)

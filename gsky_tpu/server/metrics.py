@@ -266,6 +266,10 @@ class MetricsLogger:
                 for k in self._EXPORT_MAXES:
                     if k in stats:
                         e[k] = max(e.get(k, 0), stats[k])
+                # tiles by how their kernel fetched its taps
+                forms = e.setdefault("tap_form", {})
+                for k, n in stats.get("tap_form", {}).items():
+                    forms[k] = forms.get(k, 0) + n
                 e["last"] = dict(stats)
             from ..obs.metrics import STAGE_SECONDS
             for k in ("plan_s", "decode_s", "warp_s", "encode_s",
@@ -402,8 +406,10 @@ class MetricsLogger:
                     "pipeline_ms_total": round(s["rpc_ms"], 1)}
             if self._export.get("exports"):
                 from ..io.geotiff import deflate_pool_stats
-                out["export_pipeline"] = dict(self._export,
-                                              deflate=deflate_pool_stats())
+                out["export_pipeline"] = dict(
+                    self._export,
+                    tap_form=dict(self._export.get("tap_form", {})),
+                    deflate=deflate_pool_stats())
             if self._drills.get("requests"):
                 out["drill_stages"] = dict(self._drills)
             if self._tiles.get("tiles"):

@@ -186,14 +186,14 @@ def _program(kernel):
     out_hw, step = (64, 64), 16
     sp = jnp.asarray(np.array([0.0, 0.1, 3000.0], np.float32))
     if kernel == "warp_scenes_ctrl_scored":
-        # the export tile: cubic, a depth-1 stack, windowed; 4 x 4 taps
-        # (24 gathers of 1,048,576 elements a tile in PR 37's trace: 16
-        # and the eight)
+        # the export tile: cubic, a depth-1 stack, windowed; its 4 x 4
+        # taps in two gathers of two tap rows each (`_tap_pairs`), where
+        # a gather a tap took 16 of 1,048,576 elements a tile
         stack, ctrl, params = _scene_inputs()
         fn = lambda s, c, p, w0: warp_scenes_ctrl_scored.__wrapped__(  # noqa: E731
             s, c, p, "cubic", 1, out_hw, step, win=(80, 80), win0=w0)
         return jax.make_jaxpr(fn)(stack, ctrl, params,
-                                  jnp.zeros((2,), jnp.int32)), 16, 1
+                                  jnp.zeros((2,), jnp.int32)), 2, 1
     if kernel == "render_scenes_ctrl":
         # the Landsat mosaic tile: nearest, one tap a scene (11 gathers
         # before PR 38: these three and the eight)

@@ -184,6 +184,12 @@ def test_debug_counts_tiles_bytes_and_stages(env):
         return after.get(key, 0) - before.get(key, 0)
     assert moved("exports") == 1 and moved("tiles") == 4
     assert moved("tiles_resident") == 4 and moved("tiles_fallback") == 0
+    # the layer is cubic: every resident tile's taps came as
+    # neighbourhoods (`ops.warp._tap_pairs`)
+    forms = {k: after["tap_form"].get(k, 0)
+             - before.get("tap_form", {}).get(k, 0)
+             for k in ("neighbourhood", "per_tap")}
+    assert forms == {"neighbourhood": 4, "per_tap": 0}
     # a float32 and a validity byte a pixel came off the device
     assert moved("readback_bytes") == SIZE * SIZE * 5
     for key in ("plan_s", "warp_s", "encode_s", "write_s", "wall_s"):
@@ -191,6 +197,41 @@ def test_debug_counts_tiles_bytes_and_stages(env):
     last = after["last"]
     assert last["plan_s"] < last["wall_s"]
     assert last["tiles_resident"] == 4 and last["index_queries"] == 1
+
+
+def test_the_tap_form_is_the_kernels_not_the_layers(env, monkeypatch):
+    """`tap_form` counts what the executor handed the kernel, not the
+    layer's method: where the unfolded copies would not fit the bound
+    (`ops.warp._UNFOLD_BYTES`), a cubic tile gathers a tap at a time
+    and is counted and traced so."""
+    import sys
+
+    import jax
+
+    import gsky_tpu.ops.warp  # noqa: F401
+    warp = sys.modules["gsky_tpu.ops.warp"]
+    monkeypatch.setattr(warp, "_UNFOLD_BYTES", 0)
+    jax.clear_caches()          # no program traced under the real bound
+    obs.reset_recorder()
+    before = _export_stats(env)
+    req = _request(env["gen"], "inside_at_0.7")
+    b = req.meta["bbox"]
+    d = (b[2] - b[0]) / SIZE / 32
+    req = env["gen"]._req(req.meta["ti"], (b[0] + d, b[1], b[2] + d, b[3]),
+                          (SIZE, SIZE), ("per_tap",))
+    try:
+        status, _ = _get(env["server"], req.path)
+    finally:
+        jax.clear_caches()
+    assert status == 200
+    after = _export_stats(env)
+    assert {k: after["tap_form"].get(k, 0)
+            - before.get("tap_form", {}).get(k, 0)
+            for k in ("neighbourhood", "per_tap")} \
+        == {"neighbourhood": 0, "per_tap": 4}
+    tiles = [sp for t in obs.default_recorder().traces()
+             for sp in t.get("spans", []) if sp["name"] == "export.tile"]
+    assert [sp["attrs"]["tap_form"] for sp in tiles] == ["per_tap"] * 4
 
 
 def test_trace_holds_a_span_a_tile_and_the_write(env):
@@ -207,6 +248,7 @@ def test_trace_holds_a_span_a_tile_and_the_write(env):
     tiles = [sp for sp in spans if sp["name"] == "export.tile"]
     assert len(tiles) == 4
     assert {sp["attrs"]["route"] for sp in tiles} == {"resident"}
+    assert {sp["attrs"]["tap_form"] for sp in tiles} == {"neighbourhood"}
     write, = [sp for sp in spans if sp["name"] == "export.write"]
     assert write["attrs"]["format"] == "geotiff" and write["dur_s"] > 0
     names = {sp["name"] for sp in spans}

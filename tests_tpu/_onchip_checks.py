@@ -606,3 +606,125 @@ def _():
                 rec = reference_expr.compare(got, want)
             assert rec["mismatch"] <= 0.005 and rec["max_byte_diff"] <= 1, \
                 (leg, rec)
+
+
+# --- cubic taps fetched as neighbourhoods --------------------------------
+
+@check("cubic_neighbourhoods_match_per_tap")
+def _():
+    """The export's kernel ON THE CHIP, a 1024² cubic tile from a 1536²
+    gather window of a 2048² scene (NaN nodata, a nodata block, the tile
+    over the scene's top and left edges), fetching its taps as
+    neighbourhoods (`ops.warp._tap_pairs`) against the same program
+    with the per-tap `_resample_c` kept in `tests/test_cubic_neighbourhood.py`:
+    bit for bit.  Then a cubic export served over HTTP: every
+    `export.tile` span reads `tap_form=neighbourhood`, and `/debug`
+    counts each resident tile so."""
+    import asyncio
+    import json
+    import os
+    import sys
+    import tempfile
+
+    from benchmarks.archives import geotiff_scenes
+    from gsky_tpu import obs
+    from gsky_tpu.index import MASClient, MASStore
+    from gsky_tpu.ops.warp import warp_scenes_ctrl_scored
+    from gsky_tpu.server.config import ConfigWatcher
+    from gsky_tpu.server.metrics import MetricsLogger
+    from gsky_tpu.server.ows import OWSServer
+    from tests.test_cubic_neighbourhood import _resample_c_per_tap
+    warp = sys.modules["gsky_tpu.ops.warp"]
+
+    S, h, step = 2048, 1024, 16
+    scene = rng.uniform(100.0, 3000.0, (1, S, S)).astype(np.float32)
+    scene[rng.uniform(0, 1, scene.shape) < 0.03] = np.nan
+    scene[:, 300:420, 200:380] = np.nan
+    gh = (h - 1 + step - 1) // step + 1
+    jj, ii = np.meshgrid(np.arange(gh) * step + 0.5,
+                         np.arange(gh) * step + 0.5)
+    th = np.deg2rad(2.0)
+    ctrl = np.stack([-3.0 + 1.3 * (np.cos(th) * jj - np.sin(th) * ii),
+                     -2.0 + 1.3 * (np.sin(th) * jj + np.cos(th) * ii)])
+    params = np.array([[0.0, 1.0, 0.0, 0.0, 0.0, 1.0, 2000.0, 2010.0,
+                        np.nan, 1.0, 0.0]], np.float32)
+    args = (jnp.asarray(scene), jnp.asarray(ctrl.astype(np.float32)),
+            jnp.asarray(params), jnp.asarray(np.array([0, 0], np.int32)))
+
+    def run():
+        fn = warp_scenes_ctrl_scored.__wrapped__
+        canv, best = jax.jit(lambda s, c, p, w0: fn(
+            s, c, p, "cubic", 1, (h, h), step, win=(1536, 1536),
+            win0=w0))(*args)
+        return np.asarray(canv), np.asarray(best)
+    canv, best = run()
+    resample_c = warp._resample_c
+    warp._resample_c = _resample_c_per_tap
+    try:
+        canv_t, best_t = run()
+    finally:
+        warp._resample_c = resample_c
+    ok = best > -np.inf
+    assert 0.5 < ok.mean() < 1.0, ok.mean()
+    differ = canv.view(np.uint32) != canv_t.view(np.uint32)
+    assert not differ.any() and np.array_equal(
+        best.view(np.uint32), best_t.view(np.uint32)), (
+        f"{int(differ.sum())} of {differ.size} pixels differ, largest "
+        f"{float(np.nanmax(np.abs(canv - canv_t)[differ]))}")
+
+    archive = {
+        "kind": "geotiff_scenes", "collection": "landsat",
+        "file_prefix": "LC08", "crs": "EPSG:32755",
+        "origin": [590000.0, 6105000.0], "res": 30.0,
+        "scene_hw": [360, 380], "scenes": 1, "shift_m": [0.0, 0.0],
+        "first_date": "2020-01-10", "step_days": 1, "namespace": "nbar",
+        "nodata": -999, "nodata_corner": 0.125, "compress": False}
+    with tempfile.TemporaryDirectory() as root:
+        store = MASStore()
+        for rec in geotiff_scenes.build(archive, 44, root):
+            store.ingest(rec)
+        conf = os.path.join(root, "conf")
+        os.mkdir(conf)
+        with open(os.path.join(conf, "config.json"), "w") as fh:
+            json.dump({"service_config": {"ows_hostname": "",
+                                          "mas_address": "inproc"},
+                       "layers": [{
+                           "name": "scene", "title": "cubic",
+                           "data_source": os.path.join(root, "landsat"),
+                           "rgb_products": ["nbar"],
+                           "time_generator": "mas", "resample": "cubic",
+                           "wcs_max_tile_width": 64,
+                           "wcs_max_tile_height": 64}]}, fh)
+        mas = MASClient(store)
+        metrics = MetricsLogger()
+        server = OWSServer(
+            ConfigWatcher(conf, mas_factory=lambda addr: mas,
+                          install_signal=False),
+            mas_factory=lambda addr: mas, metrics=metrics, gateway=None,
+            temp_dir=os.path.join(root, "tmp"))
+        x0, y0 = 590000.0 + 100 * 30.0, 6105000.0 - 100 * 30.0
+        path = ("/ows?service=WCS&request=GetCoverage&version=1.0.0"
+                "&coverage=scene&crs=EPSG:32755&format=GeoTIFF"
+                f"&bbox={x0},{y0 - 128 * 30.0},{x0 + 128 * 30.0},{y0}"
+                "&width=128&height=128&time=2020-01-10T00:00:00.000Z")
+        obs.reset_recorder()
+
+        async def get():
+            from aiohttp.test_utils import TestClient, TestServer
+            client = TestClient(TestServer(server.app()))
+            await client.start_server()
+            try:
+                resp = await client.get(path)
+                return resp.status, await resp.read()
+            finally:
+                await client.close()
+        status, body = asyncio.new_event_loop().run_until_complete(get())
+    assert status == 200, body[:300]
+    tiles = [sp for t in obs.default_recorder().traces()
+             for sp in t.get("spans", []) if sp["name"] == "export.tile"]
+    assert len(tiles) == 4, len(tiles)
+    assert {sp["attrs"].get("tap_form") for sp in tiles} \
+        == {"neighbourhood"}, [sp["attrs"] for sp in tiles]
+    stats = metrics.summary()["export_pipeline"]
+    assert stats["tap_form"] == {"neighbourhood": stats["tiles_resident"],
+                                 "per_tap": 0}, stats
