@@ -664,7 +664,8 @@ def _grid_taps(bands, params, win0, at, sx, sy, method: str, win,
 
 def _mosaic_band_sets(granules, ctrl, params, prios, method: str,
                       out_hw: Tuple[int, int], step: int, win, win0,
-                      grid_of: Optional[Tuple[int, ...]] = None):
+                      grid_of: Optional[Tuple[int, ...]] = None,
+                      crs_of=None):
     """The warp + mosaic the channel-packed kernels share: from the band
     scenes of G granule sets (each a C-tuple of (sh, sw) arrays) to
     (data (h, w, C) f32, best (h, w, C) f32): warp indices and tap
@@ -680,15 +681,37 @@ def _mosaic_band_sets(granules, ctrl, params, prios, method: str,
     keep their own validity, and are put back in channel order at the
     end.  None (one grid) keeps params (G, 11), one window and win0
     (G, 2): a one-grid set traces the program it traced before grids
-    existed."""
+    existed.
+
+    ``crs_of`` (traced (G,) int32) maps set -> control grid where the
+    sets lie in K > 1 CRSs (granules either side of a UTM zone line):
+    ``ctrl`` is then (K, 2, gh, gw), a control grid a CRS, and each
+    set's param rows are relative to its own grid's origin.  Each grid
+    is upsampled once; a set's warp reads its grid's coordinates,
+    picked by a dynamic index (a slice, no gather).  K is static, the
+    map is not: one program a (G, K), whichever set lies where.
+    Newest-wins stays one comparison over all sets by their global
+    priorities.  None (one CRS) keeps ctrl (2, gh, gw): a one-CRS tile
+    traces the program it traced before CRSs were mixed."""
     h, w = out_hw
     C = len(granules[0])
     grid_of = grid_of or (0,) * C
     grids = [[c for c in range(C) if grid_of[c] == r]
              for r in range(max(grid_of) + 1)]
     one = len(grids) == 1
-    sx = _bilerp_grid(ctrl[0], h, w, step)
-    sy = _bilerp_grid(ctrl[1], h, w, step)
+    if crs_of is None:
+        sx = _bilerp_grid(ctrl[0], h, w, step)
+        sy = _bilerp_grid(ctrl[1], h, w, step)
+
+        def coords(k):
+            return sx, sy
+    else:
+        gx = jnp.stack([_bilerp_grid(c[0], h, w, step) for c in ctrl])
+        gy = jnp.stack([_bilerp_grid(c[1], h, w, step) for c in ctrl])
+
+        def coords(k):
+            return (lax.dynamic_index_in_dim(gx, crs_of[k], 0, False),
+                    lax.dynamic_index_in_dim(gy, crs_of[k], 0, False))
     wins = [win if one or win is None else win[r]
             for r in range(len(grids))]
     unfold = _unfolds(method, [
@@ -701,7 +724,7 @@ def _mosaic_band_sets(granules, ctrl, params, prios, method: str,
     for k, bands in enumerate(granules):
         for r, cs in enumerate(grids):
             d, o = _grid_taps([bands[c] for c in cs], params, win0,
-                              k if one else (k, r), sx, sy, method,
+                              k if one else (k, r), *coords(k), method,
                               wins[r], unfold)
             score = jnp.where(o, prios[k] if one
                               else prios[k, np.asarray(cs)], -jnp.inf)
@@ -728,7 +751,8 @@ def render_rgba_ctrl(granules, ctrl, params, prios, scale_params,
                      step: int = 16, auto: bool = True,
                      colour_scale: int = 0,
                      win: Optional[Tuple[int, int]] = None, win0=None,
-                     grid_of: Optional[Tuple[int, ...]] = None):
+                     grid_of: Optional[Tuple[int, ...]] = None,
+                     crs_of=None):
     """RGB fast path: one dispatch from the band scenes of G granules,
     each a 3-tuple of (sh, sw) arrays as the scene cache holds them (on
     one grid, or on the grids ``grid_of`` names: `_mosaic_band_sets`),
@@ -754,12 +778,15 @@ def render_rgba_ctrl(granules, ctrl, params, prios, scale_params,
     kernel's newest-wins, channel by channel); a row of -inf is a
     padding granule.  win0: (G, 2), an origin a granule.  With
     ``grid_of`` params is (G, R, 11) and win0 (G, R, 2), a row and an
-    origin a (granule, grid), and ``win`` R windows.
+    origin a (granule, grid), and ``win`` R windows.  With ``crs_of``
+    (G,) int32 the sets lie in K CRSs and ctrl is (K, 2, gh, gw), a
+    control grid a CRS (`_mosaic_band_sets`).
     scale_params (3,) as elsewhere.
     """
     from .scale import auto_byte_scale, scale_to_byte
     data, best = _mosaic_band_sets(granules, ctrl, params, prios, method,
-                                   out_hw, step, win, win0, grid_of)
+                                   out_hw, step, win, win0, grid_of,
+                                   crs_of)
     ok = best > -jnp.inf
     if auto:
         if colour_scale == 1:
@@ -794,7 +821,8 @@ def render_expr_ctrl(granules, ctrl, params, prios, scale_params, consts,
                      step: int = 16, auto: bool = True,
                      colour_scale: int = 0,
                      win: Optional[Tuple[int, int]] = None, win0=None,
-                     grid_of: Optional[Tuple[int, ...]] = None):
+                     grid_of: Optional[Tuple[int, ...]] = None,
+                     crs_of=None):
     """Band-algebra fast path in `render_rgba_ctrl`'s form: one dispatch
     from the band scenes of G granule sets, each a C-tuple of (sh, sw)
     arrays as the scene cache holds them (slot i of the tuple is
@@ -817,11 +845,13 @@ def render_expr_ctrl(granules, ctrl, params, prios, scale_params, consts,
     and a granule count, never one an expression string; ``consts``
     (K,) f32 are its lifted literals.  params, prios (G, C), win0 (G, 2)
     and scale_params as `render_rgba_ctrl`, and as there a set's bands
-    may lie on the grids ``grid_of`` names."""
+    may lie on the grids ``grid_of`` names and the sets in the CRSs
+    ``crs_of`` names."""
     from .paged import expr_epilogue
     from .scale import scale_to_byte
     data, best = _mosaic_band_sets(granules, ctrl, params, prios, method,
-                                   out_hw, step, win, win0, grid_of)
+                                   out_hw, step, win, win0, grid_of,
+                                   crs_of)
     plane, ok = expr_epilogue(jnp.moveaxis(data, -1, 0)[None],
                               jnp.moveaxis(best, -1, 0)[None], fp,
                               consts[None])

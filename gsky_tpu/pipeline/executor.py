@@ -172,9 +172,11 @@ def _gather_windows(params64: np.ndarray, cx: np.ndarray, cy: np.ndarray,
     touches that granule.  Neighbouring granules of one grid lie a
     granule's pitch apart in each other's pixel coordinates, so one
     origin for all would span a window over everything between them
-    (11,008 x 512 px where 512 x 512 do)."""
-    bounds = [None if p[10] < 0 else _granule_bounds(p, cx, cy)
-              for p in params64]
+    (11,008 x 512 px where 512 x 512 do).  ``cx``, ``cy`` one grid for
+    every granule, or one a granule (`_grid_at`)."""
+    bounds = [None if p[10] < 0 else _granule_bounds(
+        p, _grid_at(cx, k), _grid_at(cy, k))
+        for k, p in enumerate(params64)]
     real = [b for b in bounds if b is not None]
     if not real:
         return None
@@ -246,6 +248,11 @@ def _complete_sets(grids, ns_ids, n_chan: int):
 # and each is one more operand row and window in the program's key
 _MAX_GRIDS = 3
 
+# CRSs a tile's band sets may lie in, a control grid each: two where a
+# tile crosses a UTM zone line, three where it also meets a zone's
+# other neighbour near a corner of the grid; more decline
+_MAX_CRS = 3
+
 
 def _footprint(s) -> tuple:
     """The ground a cached scene covers: its CRS, outer corner and the
@@ -276,8 +283,9 @@ def _grid_sets(scenes, chans, n_chan: int, order=None, footprints=None):
     picks and orders the channels: an RGB request's `out_sel`);
     ``grid_of`` maps a channel to its pixel grid within a set, grids
     numbered finest first, and is None where all lie on one.  Every set
-    must split into grids alike and all lie in one CRS (one control
-    grid), and a set spans at most `_MAX_GRIDS` grids."""
+    must split into grids alike, a set spans at most `_MAX_GRIDS` grids,
+    and the sets lie in at most `_MAX_CRS` CRSs, a control grid each
+    (`_set_crs`)."""
     sets = _complete_sets(footprints or [_footprint(s) for s in scenes],
                           chans, n_chan)
     if sets is None:
@@ -285,9 +293,8 @@ def _grid_sets(scenes, chans, n_chan: int, order=None, footprints=None):
     if order is not None:
         sets = [[m[c] for c in order] for m in sets]
     keys = [_grid_key(scenes[i]) for i in sets[0]]
-    crs = scenes[sets[0][0]].crs
-    if any(scenes[m[0]].crs != crs
-           or [_grid_key(scenes[i]) for i in m] != keys for m in sets[1:]):
+    if any([_grid_key(scenes[i]) for i in m] != keys for m in sets[1:]) \
+            or len({scenes[m[0]].crs for m in sets}) > _MAX_CRS:
         return None
     grids = sorted(dict.fromkeys(keys), key=lambda k: (
         abs(k[0] * k[1] - k[2] * k[3]), keys.index(k)))
@@ -303,6 +310,37 @@ def _grid_sets(scenes, chans, n_chan: int, order=None, footprints=None):
 _GRID_WIN_PAD = 2 * _WIN_MARGIN + 4
 
 
+def _set_crs(scenes, sets):
+    """(origins, crs_of) of band sets (`_grid_sets`): ``origins`` one
+    (CRS, x0, y0) a CRS the sets lie in, in first-seen order, the outer
+    corner of its first set's first scene, on which that CRS's control
+    grid and param rows are taken; ``crs_of`` the index into it of each
+    set, None where all lie in one CRS."""
+    origins, at = [], {}
+    for m in sets:
+        s = scenes[m[0]]
+        if s.crs not in at:
+            at[s.crs] = len(origins)
+            origins.append((s.crs, s.gt.x0, s.gt.y0))
+    crs_of = tuple(at[scenes[m[0]].crs] for m in sets)
+    return origins, (crs_of if len(origins) > 1 else None)
+
+
+def _rows_on(scenes, origins):
+    """Every scene's param row (affine6, height, width, nodata) relative
+    to the origin of its CRS (`_set_crs`)."""
+    at = {crs: (ox, oy) for crs, ox, oy in origins}
+    return [_inv_gt_params(s.gt, *at[s.crs]) + (s.height, s.width, s.nodata)
+            for s in scenes]
+
+
+def _grid_at(c: np.ndarray, k: int) -> np.ndarray:
+    """Row k's host control coordinates: ``c`` is one grid (gh, gw) for
+    every row, or one a row (B, gh, gw) where the rows lie in several
+    CRSs."""
+    return c[k] if c.ndim == 3 else c
+
+
 def _grid_windows(params64: np.ndarray, cx: np.ndarray, cy: np.ndarray,
                   buckets):
     """`_gather_windows` for sets on R grids: (wins, win0 (G, R, 2)) or
@@ -311,7 +349,8 @@ def _grid_windows(params64: np.ndarray, cx: np.ndarray, cy: np.ndarray,
     derived from it alone (its size over the pixel ratio, padded, then
     bucketed), so one window of the finest grid keys one program and the
     program lattice does not multiply with R.  Each (set, grid) has its
-    own origin; None where a coarser footprint would not fit."""
+    own origin; None where a coarser footprint would not fit.  ``cx``,
+    ``cy`` one grid for every set, or one a set (`_grid_at`)."""
     made = _gather_windows(params64[:, 0], cx, cy, *buckets[0])
     if made is None:
         return None
@@ -332,7 +371,8 @@ def _grid_windows(params64: np.ndarray, cx: np.ndarray, cy: np.ndarray,
                                 + _GRID_WIN_PAD), bw))
         for k in range(G):
             p = params64[k, r]
-            b = None if p[10] < 0 else _granule_bounds(p, cx, cy)
+            b = None if p[10] < 0 else _granule_bounds(
+                p, _grid_at(cx, k), _grid_at(cy, k))
             if b is None:
                 continue
             if b[1] - b[0] > size[0] or b[3] - b[2] > size[1]:
@@ -349,13 +389,17 @@ class BandSets(NamedTuple):
     bands: tuple                # G C-tuples of scene arrays, as cached
     params: np.ndarray          # (G, 11) f32, a row a set; (G, R, 11)
     prios: np.ndarray           # (G, C) f32, -inf rows are padding
-    key: tuple                  # (G, bh, bw, C); (G, R, C) on R grids
+    key: tuple                  # (G, bh, bw, C); (G, R, C) on R grids;
+                                # (G, K, C), (G, K, R, C) in K CRSs
     win: Optional[tuple]        # (wr, wc); R of them on R grids
     win0: Optional[np.ndarray]  # (G, 2); (G, R, 2) on R grids
     grid_of: Optional[tuple]    # channel -> grid, None on one grid
+    crs_of: Optional[np.ndarray] = None     # (G,) int32 set -> control
+                                            # grid, None in one CRS
 
 
-def _band_sets(scenes, sets, grid_of, prios, rows, cx, cy) -> BandSets:
+def _band_sets(scenes, sets, grid_of, prios, rows, cx, cy,
+               crs_of=None) -> BandSets:
     """Kernel operands for ``sets`` (`_grid_sets`): each the indices of
     its C scenes (`DeviceScene`s) in ``scenes``, in channel order, with
     mosaic priorities ``prios[i]`` and param rows ``rows[i]`` (affine6
@@ -364,7 +408,10 @@ def _band_sets(scenes, sets, grid_of, prios, rows, cx, cy) -> BandSets:
     is a power of two (bounded jit variants): the filling repeats the
     first set with a priority that never wins.  The gather windows are
     those of the same param rows, from the host control coordinates
-    ``cx``, ``cy`` (origin-relative, f64)."""
+    ``cx``, ``cy`` (origin-relative, f64).  Where the sets lie in K
+    CRSs (``crs_of``, `_set_crs`) ``cx``, ``cy`` are K grids (K, gh,
+    gw), each row relative to its own CRS's origin, and a set's window
+    is read on its own grid."""
     G = _bucket_pow2(len(sets))
     C = len(sets[0])
     lead = [grid_of.index(r) for r in range(max(grid_of) + 1)] \
@@ -385,20 +432,40 @@ def _band_sets(scenes, sets, grid_of, prios, rows, cx, cy) -> BandSets:
         key = (G,) + buckets[0] + (C,)
     else:
         key = (G, len(lead), C)
+    at = None
+    if crs_of is not None:
+        # the filling sets are the first one again, on its grid
+        at = np.zeros(G, np.int32)
+        at[:len(sets)] = crs_of
+        cx, cy = cx[at], cy[at]
+        key = (G, max(crs_of) + 1) + ((C,) if grid_of is None else key[1:])
     made = None
     if _window_mode():
         made = _gather_windows(params, cx, cy, *buckets[0]) \
             if grid_of is None else _grid_windows(params, cx, cy, buckets)
     win, win0 = made or (None, None)
     return BandSets(bands, params.astype(np.float32), chan_prios, key,
-                    win, win0, grid_of)
+                    win, win0, grid_of, at)
+
+
+def _sets_leg(name: str, b: BandSets) -> str:
+    """The dispatch leg of a band-set kernel call: ``name``, ``_mg``
+    where a set spans several pixel grids, ``_mc`` where the sets lie in
+    several CRSs (with or without several grids)."""
+    if b.crs_of is not None:
+        return name + "_mc"
+    return name if b.grid_of is None else name + "_mg"
 
 
 def _grids_arg(b: BandSets) -> dict:
-    """The kernels' ``grid_of`` keyword, left out on one grid: a call
-    with it and one without are two entries of jit's cache, and a
-    one-grid set must run the program prewarm and its past made."""
-    return {} if b.grid_of is None else {"grid_of": b.grid_of}
+    """The kernels' ``grid_of`` and ``crs_of`` keywords, each left out
+    on one grid and in one CRS: a call with one and one without are two
+    entries of jit's cache, and a one-grid, one-CRS set must run the
+    program prewarm and its past made."""
+    out = {} if b.grid_of is None else {"grid_of": b.grid_of}
+    if b.crs_of is not None:
+        out["crs_of"] = jnp.asarray(b.crs_of)
+    return out
 
 
 class SceneGroup(NamedTuple):
@@ -454,20 +521,32 @@ class WarpExecutor:
         # that formed none (/debug `band_grids`)
         self.band_grids = {"sets_one_grid": 0, "sets_multi_grid": 0,
                            "multi_grid_declined": 0}
+        # the same sets by how many CRSs a tile's sets lie in, and
+        # granule lists in several CRSs that formed none (/debug
+        # `band_crs`)
+        self.band_crs = {"sets_one_crs": 0, "sets_multi_crs": 0,
+                         "multi_crs_declined": 0}
 
     def _note_grids(self, made, scenes) -> None:
         """Count what `_grid_sets` made of ``scenes``: its sets by their
-        grids, or a decline where the scenes have several pixel sizes.
-        The open span (`tile.dispatch`) carries the sets' grid count."""
+        grids and by the CRSs they lie in, or a decline where the scenes
+        have several pixel sizes, or lie in several CRSs.  The open span
+        (`tile.dispatch`) carries the sets' grid and CRS counts."""
         with self._lock:
             if made is None:
                 if len({(abs(s.gt.dx), abs(s.gt.dy)) for s in scenes}) > 1:
                     self.band_grids["multi_grid_declined"] += 1
+                if len({s.crs for s in scenes}) > 1:
+                    self.band_crs["multi_crs_declined"] += 1
                 return
             sets, grid_of = made
+            n_crs = len({scenes[m[0]].crs for m in sets})
             self.band_grids["sets_one_grid" if grid_of is None
                             else "sets_multi_grid"] += len(sets)
-        obs_set_attr(grids=1 if grid_of is None else max(grid_of) + 1)
+            self.band_crs["sets_one_crs" if n_crs == 1
+                          else "sets_multi_crs"] += len(sets)
+        obs_set_attr(grids=1 if grid_of is None else max(grid_of) + 1,
+                     crs=n_crs)
 
     @staticmethod
     def _note_taps(method: str, parts, out_hw) -> None:
@@ -570,6 +649,36 @@ class WarpExecutor:
             step //= 2
         self._geo_cache_put(key, (sx, sy, step))
         return sx, sy, step
+
+    def _ctrl_sets(self, dst_gt: GeoTransform, dst_crs: CRS, height: int,
+                   width: int, origins):
+        """The control grids of band sets in the CRSs of ``origins``
+        (`_set_crs`), each `_ctrl_geo_coords`' (cached per (dst, src
+        CRS)) relative to its CRS's origin and all at one step, the
+        finest any of them needs.  Returns (cx, cy, ctrl_dev, step):
+        host f64 (gh, gw) and the device (2, gh, gw) f32 in one CRS, (K,
+        gh, gw) and (K, 2, gh, gw) in K; the device copy is kept in the
+        geo cache, under the one-CRS key `_scene_groups` uses too."""
+        step = 16
+        while True:
+            made = [self._ctrl_geo_coords(dst_gt, dst_crs, height, width,
+                                          crs, step)
+                    for crs, _, _ in origins]
+            if all(m[2] == step for m in made):
+                break
+            step = min(m[2] for m in made)
+        cx = np.stack([m[0] - o[1] for m, o in zip(made, origins)])
+        cy = np.stack([m[1] - o[2] for m, o in zip(made, origins)])
+        if len(origins) == 1:
+            cx, cy = cx[0], cy[0]
+        dkey = ("ctrldev", dst_gt.to_gdal(), dst_crs, height, width) \
+            + sum(origins, ())
+        ctrl_dev = self._geo_cache_get(dkey)
+        if ctrl_dev is None:
+            ctrl_dev = jnp.asarray(
+                np.stack([cx, cy], axis=-3).astype(np.float32))
+            self._geo_cache_put(dkey, ctrl_dev)
+        return cx, cy, ctrl_dev, step
 
     @staticmethod
     def _ctrl_err_px(sx: np.ndarray, sy: np.ndarray, dst_gt: GeoTransform,
@@ -969,12 +1078,12 @@ class WarpExecutor:
         ``ns_ids`` are fingerprint SLOT indices (variable i of ``fp``
         is mosaic slot i); ``fp`` is the `ops.expr.ExprFingerprint`.
         Returns a uint8 (H, W) array or None — the caller then runs
-        the unfused `evaluate_expressions` leg (multi-CRS granule sets,
-        granules that form no band sets, page budget, SPMD compat
-        mode).  Scenes of several scene groups (a variable's band on a
-        coarser pixel grid: Sentinel-2's 20 m SWIR beside 10 m NIR) go
-        to the bucketed leg's band sets, which take up to three grids
-        a set."""
+        the unfused `evaluate_expressions` leg (granules that form no
+        band sets, page budget, SPMD compat mode).  Scenes of several
+        scene groups (a variable's band on a coarser pixel grid:
+        Sentinel-2's 20 m SWIR beside 10 m NIR; granules either side of
+        a UTM zone line) go to the bucketed leg's band sets, which take
+        up to three grids a set and three CRSs a tile."""
         groups = self._scene_groups(granules, ns_ids, prios, dst_gt,
                                     dst_crs, height, width, cache,
                                     stacked=False, windowed=False)
@@ -987,8 +1096,8 @@ class WarpExecutor:
             return None
         if leg == "bucketed":
             return self._render_expr_sets(
-                groups, n_slots, fp, method, (height, width), offset,
-                scale, clip, colour_scale, auto)
+                groups, n_slots, fp, method, dst_gt, dst_crs,
+                (height, width), offset, scale, clip, colour_scale, auto)
         # the paged forms race a stacked XLA reference
         g = self._stacked(groups[0], cache)
         sp = np.array([offset, scale, clip], np.float32)
@@ -1034,18 +1143,22 @@ class WarpExecutor:
                              wave=wave, paged=paged)
 
     def _render_expr_sets(self, groups: List["SceneGroup"], n_slots: int,
-                          fp, method: str, out_hw: Tuple[int, int],
+                          fp, method: str, dst_gt: GeoTransform,
+                          dst_crs: CRS, out_hw: Tuple[int, int],
                           offset: float, scale: float, clip: float,
                           colour_scale: int, auto: bool):
         """`render_expr_byte`'s bucketed leg: the unstacked groups'
         scenes as band sets (`_grid_sets`: one a footprint with a scene
         a slot, on up to three pixel grids), through ONE
-        `ops.warp.render_expr_ctrl` dispatch on the first group's
-        control grid.  None where they form no such sets (a footprint
-        that lacks a variable's band, two dates on one footprint, sets
-        in several CRSs): the unfused leg's.  A curvilinear group's
-        control grid holds pixel coordinates: its sets are the bands of
-        one file (rows alike), and it shares a dispatch with no other."""
+        `ops.warp.render_expr_ctrl` dispatch, on the first group's
+        control grid where the sets lie in one CRS, on a control grid a
+        CRS where they lie in several (`_ctrl_sets`: a tile across a UTM
+        zone line).  None where they form no such sets (a footprint that
+        lacks a variable's band, two dates on one footprint, sets in
+        more than `_MAX_CRS` CRSs): the unfused leg's.  A curvilinear
+        group's control grid holds pixel coordinates: its sets are the
+        bands of one file (rows alike), and it shares a dispatch with no
+        other."""
         g0 = groups[0]
         scenes = [s for g in groups for s in g.scenes]
         p64 = np.concatenate([g.params64[:len(g.scenes)] for g in groups])
@@ -1056,29 +1169,35 @@ class WarpExecutor:
             made = _grid_sets(scenes, chans, n_slots, footprints=[
                 r.tobytes() for r in rows] if g0.curvilinear else None)
         elif not any(g.curvilinear for g in groups):
-            # every row on the first group's origin, as its grid is
-            ox, oy = g0.scenes[0].gt.x0, g0.scenes[0].gt.y0
-            rows = [_inv_gt_params(s.gt, ox, oy)
-                    + (s.height, s.width, s.nodata) for s in scenes]
             made = _grid_sets(scenes, chans, n_slots)
         self._note_grids(made, scenes)
         if made is None:
             return None
         sets, grid_of = made
-        b = _band_sets(scenes, sets, grid_of, p64[:, 9], rows,
-                       np.asarray(g0.ctrl[0], np.float64),
-                       np.asarray(g0.ctrl[1], np.float64))
+        crs_of = None
+        if len(groups) == 1:
+            cx = np.asarray(g0.ctrl[0], np.float64)
+            cy = np.asarray(g0.ctrl[1], np.float64)
+            ctrl_dev, step = g0.ctrl_dev, g0.step
+        else:
+            # scenes of several groups (pixel grids, CRSs): a control
+            # grid a CRS, every set's rows on its own CRS's origin
+            origins, crs_of = _set_crs(scenes, sets)
+            rows = _rows_on(scenes, origins)
+            cx, cy, ctrl_dev, step = self._ctrl_sets(
+                dst_gt, dst_crs, out_hw[0], out_hw[1], origins)
+        b = _band_sets(scenes, sets, grid_of, p64[:, 9], rows, cx, cy,
+                       crs_of)
         from ..ops.paged import note_expr_fused
         from ..ops.warp import render_expr_ctrl
-        self._count("render_expr" if grid_of is None else "render_expr_mg",
-                    (b.key, b.win))
+        self._count(_sets_leg("render_expr", b), (b.key, b.win))
         self._note_win(b.win)
         note_expr_fused("bucketed")
         sp = np.array([offset, scale, clip], np.float32)
         return _prefetch(render_expr_ctrl(
-            b.bands, g0.ctrl_dev, jnp.asarray(b.params),
+            b.bands, ctrl_dev, jnp.asarray(b.params),
             jnp.asarray(b.prios), jnp.asarray(sp),
-            jnp.asarray(fp.const_array()), fp.key, method, out_hw, g0.step,
+            jnp.asarray(fp.const_array()), fp.key, method, out_hw, step,
             auto, colour_scale, win=b.win, win0=_dev_win0(b.win0),
             **_grids_arg(b)))
 
@@ -1127,7 +1246,9 @@ class WarpExecutor:
         cache holds them and renders the PNG-ready (H, W, 4) RGBA tile
         in one dispatch, computing warp indices once a set for all
         three bands.  Returns a device uint8 (H, W, 4) or None (caller
-        falls back to the per-band path)."""
+        falls back to the per-band path).  Sets in several CRSs (a tile
+        across a UTM zone line) are one dispatch too, each set warped on
+        its own CRS's control grid (`_ctrl_sets`)."""
         if len(out_sel) != 3 or sorted(out_sel) != [0, 1, 2]:
             return None
         if any(g.geo_loc for g in granules):
@@ -1156,24 +1277,15 @@ class WarpExecutor:
         if made is None:
             return None
         sets, grid_of = made
-        s0 = scenes[sets[0][0]]
-        sx, sy, step = self._ctrl_geo_coords(dst_gt, dst_crs, height,
-                                             width, s0.crs, 16)
-        ox, oy = s0.gt.x0, s0.gt.y0
-        dkey = ("ctrldev", dst_gt.to_gdal(), dst_crs, height, width,
-                s0.crs, ox, oy)
-        ctrl_dev = self._geo_cache_get(dkey)
-        if ctrl_dev is None:
-            ctrl_dev = jnp.asarray(
-                np.stack([sx - ox, sy - oy]).astype(np.float32))
-            self._geo_cache_put(dkey, ctrl_dev)
+        # a control grid a CRS the sets lie in (granules either side of
+        # a UTM zone line), each set's rows on its own CRS's origin
+        origins, crs_of = _set_crs(scenes, sets)
+        cx, cy, ctrl_dev, step = self._ctrl_sets(dst_gt, dst_crs, height,
+                                                 width, origins)
         b = _band_sets(scenes, sets, grid_of, prios,
-                       [_inv_gt_params(s.gt, ox, oy)
-                        + (s.height, s.width, s.nodata) for s in scenes],
-                       sx - ox, sy - oy)
+                       _rows_on(scenes, origins), cx, cy, crs_of)
         from ..ops.warp import render_rgba_ctrl
-        self._count("render_rgba" if grid_of is None else "render_rgba_mg",
-                    (b.key, b.win))
+        self._count(_sets_leg("render_rgba", b), (b.key, b.win))
         self._note_win(b.win)
         sp = np.array([offset, scale, clip], np.float32)
         return _prefetch(render_rgba_ctrl(

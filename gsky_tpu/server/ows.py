@@ -449,6 +449,8 @@ class OWSServer:
             # grids a set spans (docs/OBSERVABILITY.md)
             with ex._lock:
                 doc["band_grids"] = dict(ex.band_grids)
+                # ... and by the CRSs a tile's sets lie in
+                doc["band_crs"] = dict(ex.band_crs)
             doc["scene_cache_bytes"] = sc._bytes
             doc["drill_cache_bytes"] = dc._bytes
         except Exception:  # executor tier unbooted - /debug still serves

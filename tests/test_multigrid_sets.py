@@ -268,10 +268,12 @@ def test_the_bound_sees_a_wrong_grid_or_a_dropped_band(env):
 # --- a one-grid set is the program it was ---------------------------------------
 
 def _parent_mosaic_band_sets(granules, ctrl, params, prios, method,
-                             out_hw, step, win, win0, grid_of=None):
+                             out_hw, step, win, win0, grid_of=None,
+                             crs_of=None):
     """`ops.warp._mosaic_band_sets` as it was before a set could span
-    several grids, line for line."""
-    assert grid_of is None
+    several grids, line for line (and before sets could lie in several
+    CRSs: ``crs_of`` is the later keyword, None here)."""
+    assert grid_of is None and crs_of is None
     h, w = out_hw
     C = len(granules[0])
     sx = warp._bilerp_grid(ctrl[0], h, w, step)
@@ -403,7 +405,7 @@ def test_adjacent_granules_never_merge():
 
 
 @pytest.mark.parametrize("spoil", [
-    "lacks_a_channel", "two_dates", "two_crs", "other_split", "four_grids"])
+    "lacks_a_channel", "two_dates", "four_crs", "other_split", "four_grids"])
 def test_what_declines(spoil):
     scenes = _granule(0.0, 0.0) + _granule(100000.0, 0.0)
     chans = [0, 1, 2] * 2
@@ -412,8 +414,11 @@ def test_what_declines(spoil):
         scenes, chans = scenes[:5], chans[:5]
     elif spoil == "two_dates":
         scenes, chans = scenes + _granule(0.0, 0.0), chans + [0, 1, 2]
-    elif spoil == "two_crs":
-        scenes = _granule(0.0, 0.0) + _granule(100000.0, 0.0, "EPSG:32756")
+    elif spoil == "four_crs":
+        # sets in more CRSs than `executor._MAX_CRS` control grids
+        scenes = [s for z in range(4)
+                  for s in _granule(100000.0 * z, 0.0, f"EPSG:3275{4 + z}")]
+        chans = [0, 1, 2] * 4
     elif spoil == "other_split":
         # the second granule's green at 20 m: its sets split otherwise
         scenes[5] = _scene(100000.0, 0.0, 20.0, 5490)

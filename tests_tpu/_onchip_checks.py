@@ -728,3 +728,70 @@ def _():
     stats = metrics.summary()["export_pipeline"]
     assert stats["tap_form"] == {"neighbourhood": stats["tiles_resident"],
                                  "per_tap": 0}, stats
+
+
+# --- band sets in two CRSs (a tile across a UTM zone line) ---------------
+
+@check("multicrs_sets_match_reference")
+def _():
+    """Tiles over both rows of Sentinel-2 granules either side of the
+    line between UTM zones 55 and 56, true colour and NDVI, through the
+    served path's kernels ON THE CHIP (four sets in two CRSs, each set
+    warped on its own zone's control grid from its own gather window)
+    against the plain references, within the benchmark cell's bound;
+    then one-CRS calls, on one pixel grid and on two, against the same
+    calls through the parent's `_mosaic_band_sets` (kept in
+    `tests/test_multicrs_sets.py`): bit for bit."""
+    import os
+    import sys
+    import tempfile
+
+    from benchmarks import reference_expr, reference_rgb
+    from benchmarks.archives import sentinel2_zone_seam as seam
+    from gsky_tpu.geo.crs import EPSG3857
+    from gsky_tpu.geo.transform import BBox
+    from gsky_tpu.index import MASClient, MASStore
+    from gsky_tpu.pipeline import GeoTileRequest, TilePipeline
+    from gsky_tpu.pipeline.executor import WarpExecutor
+    from gsky_tpu.pipeline.tile_stages import render_staged
+    from tests import test_multicrs_sets as t
+    warp = sys.modules["gsky_tpu.ops.warp"]
+
+    store = MASStore()
+    with tempfile.TemporaryDirectory() as root:
+        for rec in seam.build(t.ARCHIVE, t.SEED, root):
+            store.ingest(rec)
+        sources = seam.sources(t.ARCHIVE, t.SEED)
+        bbox = t._bbox("seam_both_rows")
+        for layer, leg0 in (("truecolour", "render_rgba_mc:((4, 2, 3), ("),
+                            ("ndvi", "render_expr_mc:((4, 2, 2), (")):
+            products, offset, scale, clip, _ = t.LAYERS[layer]
+            pipe = TilePipeline(MASClient(store), executor=WarpExecutor())
+            req = GeoTileRequest(
+                collection=os.path.join(root, "s2"), bands=products,
+                bbox=BBox(*bbox), crs=EPSG3857, width=256, height=256,
+                start_time=t.STAMP, end_time=None, resample="bilinear")
+            _, got = render_staged(pipe, req, 1 if t._is_expr(layer) else 3,
+                                   offset, scale, clip, 0, False)
+            (leg, _), = pipe.executor.bucket_stats.items()
+            assert leg.startswith(leg0), leg
+            want = t._want(sources, layer, bbox)
+            compare = reference_expr.compare if t._is_expr(layer) \
+                else reference_rgb.compare
+            rec = compare(np.asarray(got), want)
+            assert rec["mismatch"] <= 0.005 and rec["max_byte_diff"] <= 1, \
+                (leg, rec)
+
+    mosaic = warp._mosaic_band_sets
+    for kernel in t.KERNELS:
+        for grids in (1, 2):
+            operands, kw = t._operands(4, t.KERNELS[kernel][0], grids, True)
+            run = t._program(kernel, operands, kw)
+            now_jaxpr, now = run()
+            warp._mosaic_band_sets = t._parent_mosaic_band_sets
+            try:
+                then_jaxpr, then = run()
+            finally:
+                warp._mosaic_band_sets = mosaic
+            assert now_jaxpr == then_jaxpr, (kernel, grids)
+            assert np.array_equal(now, then), (kernel, grids)
