@@ -457,33 +457,35 @@ def _gather2d_c(src, ri, ci):
 # The most one program's neighbourhood fetches may add to its device
 # memory by `_unfold_bytes`, summed over every source it resamples:
 # above it the program gathers its cubic taps one at a time.  The
-# export's tile (a 1536² int16 window, depth 1) counts 71 MB and its
-# compiled temp falls (81 -> 50 MB); a 4096² int16 window counts 302 MB
-# but took 1.3 GB of temp, and ten whole 7680 x 7936 scenes (the
-# executor declines a window that would be the whole stack) did not
-# compile in 16 GB (PERF.md, section 6; `tools/tap_probe.py`).
+# export's tile (a 1536² f32 window of the scene cache's NaN-encoded
+# scenes, depth 1, 1024² out) counts 76 MB and its compiled temp falls
+# (81 -> 71 MB); a 4096² int16 window counts 269 MB but took 1.3 GB of
+# temp, and ten whole 7680 x 7936 scenes (the executor declines a
+# window that would be the whole stack) did not compile in 16 GB
+# (PERF.md, section 6; `tools/tap_probe.py`).
 _UNFOLD_BYTES = 128 << 20
 
 
-def _unfold_bytes(shape, dtype, n_out: int) -> int:
-    """What `_tap_pairs` adds for one (H, W, C) source of ``dtype``
-    resampled at ``n_out`` pixels: the unfolded copy (8 values a value
-    of the source padded by 3) and the two rows of 8 C gathered a
-    pixel, for each plane the kernel form gathers (tap-side: the
-    native values; mask-gather: f32 values and a validity byte)."""
+def _unfold_bytes(shape, dtype) -> int:
+    """What `_tap_pairs` adds for one (H, W, C) source of ``dtype``: the
+    unfolded copy, 8 values a value of the source padded by 3, for each
+    plane the kernel form gathers (tap-side: the native values;
+    mask-gather: f32 values and a validity byte).  The two rows of 8 C
+    it gathers a pixel stand in for the 16 C tap values the per-tap
+    program holds, so they add nothing."""
     H, W, C = shape
     size = np.dtype(dtype).itemsize if _use_tapside() else 5
-    return ((H + 5) * (W + 3) + 2 * n_out) * 8 * C * size
+    return (H + 5) * (W + 3) * 8 * C * size
 
 
-def _unfolds(method: str, sources, n_out: int) -> bool:
+def _unfolds(method: str, sources) -> bool:
     """Whether a program that resamples each of ``sources`` ((H, W, C)
-    shape, dtype) at ``n_out`` pixels fetches its cubic taps as
-    neighbourhoods (`_tap_pairs`): only a cubic tap set, and only while
-    the copies fit `_UNFOLD_BYTES` together.  Decided from static shapes
-    at trace time, so each program has one form."""
+    shape, dtype) fetches its cubic taps as neighbourhoods
+    (`_tap_pairs`): only a cubic tap set, and only while the copies fit
+    `_UNFOLD_BYTES` together.  Decided from static shapes at trace
+    time, so each program has one form."""
     return method == "cubic" and sum(
-        _unfold_bytes(s, d, n_out) for s, d in sources) <= _UNFOLD_BYTES
+        _unfold_bytes(s, d) for s, d in sources) <= _UNFOLD_BYTES
 
 
 def _scored_sources(stack, win):
@@ -495,16 +497,15 @@ def _scored_sources(stack, win):
         * stack.shape[0]
 
 
-def tap_form(method: str, stack, win, out_hw: Tuple[int, int]) -> str:
+def tap_form(method: str, stack, win) -> str:
     """How `warp_scenes_ctrl_scored` over ``stack`` (B, H, W), or a
     tuple of B (H, W) scenes, with gather window ``win`` fetches a
-    pixel's taps for an ``out_hw`` tile: ``neighbourhood`` (a cubic set
-    in two row gathers, `_tap_pairs`) or ``per_tap`` (a gather a tap:
-    nearest, bilinear, and a cubic set whose copies would not fit
-    `_UNFOLD_BYTES`; `_unfolds`)."""
+    pixel's taps: ``neighbourhood`` (a cubic set in two row gathers,
+    `_tap_pairs`) or ``per_tap`` (a gather a tap: nearest, bilinear, and
+    a cubic set whose copies would not fit `_UNFOLD_BYTES`;
+    `_unfolds`)."""
     return "neighbourhood" if _unfolds(
-        method, _scored_sources(stack, win), out_hw[0] * out_hw[1]) \
-        else "per_tap"
+        method, _scored_sources(stack, win)) else "per_tap"
 
 
 def _tap_pairs(src, r0, c0):
@@ -717,7 +718,7 @@ def _mosaic_band_sets(granules, ctrl, params, prios, method: str,
     unfold = _unfolds(method, [
         (tuple(wins[r] or bands[cs[0]].shape) + (len(cs),),
          jnp.result_type(*[bands[c] for c in cs]))
-        for bands in granules for r, cs in enumerate(grids)], h * w)
+        for bands in granules for r, cs in enumerate(grids)])
     data = [jnp.zeros((h, w, len(cs)), jnp.float32) for cs in grids]
     best = [jnp.full((h, w, len(cs)), -jnp.inf, jnp.float32)
             for cs in grids]
@@ -954,7 +955,7 @@ def _warp_scenes_scored(stack, sx, sy, params, method: str, n_ns: int,
     win0 (B, 2): see below.  A cubic tap set comes as neighbourhoods
     while their copies fit (`_unfolds`, `tap_form`).
     """
-    unfold = _unfolds(method, _scored_sources(stack, win), sx.size)
+    unfold = _unfolds(method, _scored_sources(stack, win))
 
     def per(scene, p, r0=None, c0=None):
         cols = (p[0] + p[1] * sx + p[2] * sy) - 0.5

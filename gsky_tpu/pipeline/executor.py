@@ -549,12 +549,12 @@ class WarpExecutor:
                      crs=n_crs)
 
     @staticmethod
-    def _note_taps(method: str, parts, out_hw) -> None:
+    def _note_taps(method: str, parts) -> None:
         """Record in `TAP_FORM` how the XLA scored program fetches its
         taps over each (stack, window) of ``parts`` (`ops.warp.tap_form`,
         decided from the shapes it is handed): ``per_tap`` where any
         part gathers a tap at a time, else ``neighbourhood``."""
-        forms = {tap_form(method, st, win, out_hw) for st, win in parts}
+        forms = {tap_form(method, st, win) for st, win in parts}
         TAP_FORM.set("per_tap" if "per_tap" in forms else "neighbourhood")
 
     def _note_win(self, win) -> None:
@@ -857,7 +857,7 @@ class WarpExecutor:
                 jnp.asarray(src), jnp.asarray(ctrl),
                 jnp.asarray(params.astype(np.float32)), method, n_pad,
                 (height, width), step))
-        self._note_taps(method, srcs, (height, width))
+        self._note_taps(method, srcs)
         if len(parts) == 1:
             canv, best = parts[0]
             return canv, best > -jnp.inf
@@ -964,8 +964,7 @@ class WarpExecutor:
             self._count("scene_mosaic_multicrs", len(groups))
             for g in groups:
                 self._note_win(g.win)
-            self._note_taps(method, [(g.stack, g.win) for g in groups],
-                            (height, width))
+            self._note_taps(method, [(g.stack, g.win) for g in groups])
             parts = [scored(g) for g in groups]
             return combine_scored(jnp.stack([p[0] for p in parts]),
                                   jnp.stack([p[1] for p in parts]))
@@ -1001,7 +1000,7 @@ class WarpExecutor:
 
         leg, how = self._choose_leg(g, n_pad)
         if leg == "bucketed":
-            self._note_taps(method, [(g.stack, g.win)], (height, width))
+            self._note_taps(method, [(g.stack, g.win)])
         return self._run_leg(
             leg, how, g, "scene_mosaic",
             lambda cb: (cb[0], cb[1] > -jnp.inf),

@@ -276,41 +276,56 @@ def test_nearest_and_bilinear_trace_the_per_tap_program(method, tapside,
                                True))(src, rows, cols))
     assert jaxpr(warp._resample_c) == jaxpr(_resample_c_per_tap)
     stack = jax.ShapeDtypeStruct((1, S, S), jnp.float32)
-    assert warp.tap_form(method, stack, None, (64, 64)) == "per_tap"
-    assert warp.tap_form("cubic", stack, None, (64, 64)) \
-        == "neighbourhood"
+    assert warp.tap_form(method, stack, None) == "per_tap"
+    assert warp.tap_form("cubic", stack, None) == "neighbourhood"
 
 
-def _bytes(H, W, C, itemsize, n_out):
-    """What the unfolded copy and the gathered rows of one source hold:
-    8 values a value of the source padded by 3, two rows of 8 C a
-    pixel."""
-    return ((H + 5) * (W + 3) + 2 * n_out) * 8 * C * itemsize
+def _bytes(H, W, C, itemsize):
+    """What the unfolded copy of one source holds: 8 values a value of
+    the source padded by 3."""
+    return (H + 5) * (W + 3) * 8 * C * itemsize
 
 
 def _sds(shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
-# (stack, window, out_hw, the form on the chip's tap-side kernel)
+# The stack the export's scored kernel reads: a 7,601 x 7,761 Landsat
+# scene as the scene cache holds it, NaN-encoded f32 padded to 256-px
+# buckets, at depth 1 (`test_the_export_stack_is_the_scene_caches`).
+EXPORT_STACK = (1, 7680, 7936)
+
+# (stack, dtype, window, the form on the chip's tap-side kernel); the
+# tile's size does not enter
 SIZES = {
-    # the export cell's tile: 71 MB
-    "export_tile": ((1, 2048, 2048), jnp.int16, (1536, 1536), 1024,
+    # the export cell's tile, at each of its window buckets: 76 MB at
+    # the largest
+    "export_tile": (EXPORT_STACK, jnp.float32, (1536, 1536),
                     "neighbourhood"),
-    "depth2_1536": ((2, 2048, 2048), jnp.int16, (1536, 1536), 1024,
-                    "per_tap"),
-    "win2048_int16": ((1, 4096, 4096), jnp.int16, (2048, 2048), 1024,
+    "export_win1024x1536": (EXPORT_STACK, jnp.float32, (1024, 1536),
+                            "neighbourhood"),
+    "export_win1024": (EXPORT_STACK, jnp.float32, (1024, 1024),
+                       "neighbourhood"),
+    "export_win768": (EXPORT_STACK, jnp.float32, (768, 768),
                       "neighbourhood"),
-    "win1024_f32": ((1, 2048, 2048), jnp.float32, (1024, 1024), 1024,
+    # a 1536² int16 window: 38 MB a scene
+    "win1536_int16": ((1, 2048, 2048), jnp.int16, (1536, 1536),
+                      "neighbourhood"),
+    "depth2_1536": ((2, 2048, 2048), jnp.int16, (1536, 1536),
                     "neighbourhood"),
-    "win4096_int16": ((1, 7680, 7936), jnp.int16, (4096, 4096), 1024,
-                      "per_tap"),
-    "win4096_f32": ((1, 7680, 7936), jnp.float32, (4096, 4096), 1024,
-                    "per_tap"),
+    "depth4_1536": ((4, 2048, 2048), jnp.int16, (1536, 1536), "per_tap"),
+    "depth2_1536_f32": ((2, 2048, 2048), jnp.float32, (1536, 1536),
+                        "per_tap"),
+    "win2048_int16": ((1, 4096, 4096), jnp.int16, (2048, 2048),
+                      "neighbourhood"),
+    "win1024_f32": ((1, 2048, 2048), jnp.float32, (1024, 1024),
+                    "neighbourhood"),
+    "win4096_int16": ((1, 7680, 7936), jnp.int16, (4096, 4096), "per_tap"),
+    "win4096_f32": ((1, 7680, 7936), jnp.float32, (4096, 4096), "per_tap"),
     # no window: the footprint covers the whole stack
-    "whole_scenes": ((10, 7680, 7936), jnp.int16, None, 256, "per_tap"),
-    "whole_scene": ((1, 7680, 7936), jnp.int16, None, 256, "per_tap"),
-    "unstacked": (((1024, 1024),) * 4, jnp.int16, (384, 384), 256,
+    "whole_scenes": ((10, 7680, 7936), jnp.int16, None, "per_tap"),
+    "whole_scene": ((1, 7680, 7936), jnp.int16, None, "per_tap"),
+    "unstacked": (((1024, 1024),) * 4, jnp.int16, (384, 384),
                   "neighbourhood"),
 }
 
@@ -319,25 +334,63 @@ SIZES = {
 def test_the_form_fits_the_memory_the_copies_take(size, monkeypatch):
     """`tap_form` (what the executor records) sums `_unfold_bytes` over
     the sources a scored program resamples and unfolds only within
-    `_UNFOLD_BYTES`: large windows, deep stacks and whole scenes keep a
+    `_UNFOLD_BYTES`: the export's tiles fetch neighbourhoods at every
+    window bucket; large windows, deep stacks and whole scenes keep a
     gather a tap."""
     monkeypatch.setattr(warp, "_use_tapside", lambda: True)
-    shape, dt, win, h, want = SIZES[size]
+    shape, dt, win, want = SIZES[size]
     if isinstance(shape[0], tuple):
         stack = tuple(_sds(s, dt) for s in shape)
         hw = [win or s for s in shape]
     else:
         stack = _sds(shape, dt)
         hw = [win or shape[1:]] * shape[0]
-    need = sum(_bytes(a, b, 1, np.dtype(dt).itemsize, h * h)
-               for a, b in hw)
+    need = sum(_bytes(a, b, 1, np.dtype(dt).itemsize) for a, b in hw)
     assert (need <= warp._UNFOLD_BYTES) == (want == "neighbourhood")
-    assert warp.tap_form("cubic", stack, win, (h, h)) == want
+    assert warp.tap_form("cubic", stack, win) == want
     # the bound, to the byte
     monkeypatch.setattr(warp, "_UNFOLD_BYTES", need)
-    assert warp.tap_form("cubic", stack, win, (h, h)) == "neighbourhood"
+    assert warp.tap_form("cubic", stack, win) == "neighbourhood"
     monkeypatch.setattr(warp, "_UNFOLD_BYTES", need - 1)
-    assert warp.tap_form("cubic", stack, win, (h, h)) == "per_tap"
+    assert warp.tap_form("cubic", stack, win) == "per_tap"
+
+
+def test_the_export_stack_is_the_scene_caches(tmp_path, monkeypatch):
+    """The gate counts the dtype the device holds.  An int16 Landsat
+    scene with a nodata value reaches the scored kernel as the scene
+    cache holds it, NaN-encoded f32 padded to 256-px buckets, stacked
+    at depth 1 as `WarpExecutor._stacked` stacks it: at the cell's
+    scene size that is `EXPORT_STACK`, and its 1024² tiles from the
+    largest window bucket, 1536², fetch their taps as neighbourhoods."""
+    from gsky_tpu.geo.crs import parse_crs
+    from gsky_tpu.geo.transform import GeoTransform
+    from gsky_tpu.io import write_geotiff
+    from gsky_tpu.pipeline import scene_cache
+    from gsky_tpu.pipeline.types import Granule
+
+    monkeypatch.setattr(warp, "_use_tapside", lambda: True)
+    data = np.random.default_rng(46).uniform(
+        100.0, 3000.0, (300, 380)).astype(np.int16)
+    data[:40, :40] = -999
+    gt = GeoTransform(590000.0, 30.0, 0.0, 6105000.0, 0.0, -30.0)
+    path = str(tmp_path / "LC08_scene.tif")
+    write_geotiff(path, data, gt, parse_crs("EPSG:32755"), nodata=-999)
+    g = Granule(path=path, ds_name=path, namespace="nbar",
+                base_namespace="nbar", band=1, time_index=None,
+                timestamp=0.0, geo_transform=list(gt.to_gdal()),
+                srs="EPSG:32755", nodata=-999.0)
+    cache = scene_cache.SceneCache(max_bytes=1 << 30)
+    scene = cache.get(g)
+    stack = cache.stack((scene.serial, 1), lambda: jnp.stack([scene.dev]))
+    assert stack.shape == (1, 512, 512) and stack.dtype == jnp.float32
+    got = np.asarray(stack[0])
+    assert np.isnan(got[:40, :40]).all() and np.isnan(got[300:]).all()
+    np.testing.assert_array_equal(got[40:300, 40:380], data[40:, 40:])
+
+    served = _sds((1, scene_cache._bucket(7601), scene_cache._bucket(7761)),
+                  stack.dtype)
+    assert served.shape == EXPORT_STACK
+    assert warp.tap_form("cubic", served, (1536, 1536)) == "neighbourhood"
 
 
 def _gathers(jaxpr) -> int:
@@ -390,7 +443,7 @@ def test_a_program_over_the_bound_gathers_a_tap_at_a_time(case,
         if win0 is None:
             win0 = jnp.zeros((2,), jnp.int32)
         hw = win or (S, S)
-        need = B * _bytes(*hw, 1, 4, 64 * 64)
+        need = B * _bytes(*hw, 1, 4)
         fn = warp.warp_scenes_ctrl_scored.__wrapped__
 
         def program():
@@ -403,7 +456,7 @@ def test_a_program_over_the_bound_gathers_a_tap_at_a_time(case,
         G, C = int(n[1:]), int(rest[0][1:])
         win = (48, 48) if where == "window" else None
         hw = win or (S, S)
-        need = G * _bytes(*hw, C, 2, 32 * 32)
+        need = G * _bytes(*hw, C, 2)
 
         def program():
             return _sets_program(G, C, win)

@@ -432,7 +432,7 @@ def _parent_mosaic_band_sets(granules, ctrl, params, prios, method,
     unfold = warp._unfolds(method, [
         (tuple(wins[r] or bands[cs[0]].shape) + (len(cs),),
          jnp.result_type(*[bands[c] for c in cs]))
-        for bands in granules for r, cs in enumerate(grids)], h * w)
+        for bands in granules for r, cs in enumerate(grids)])
     data = [jnp.zeros((h, w, len(cs)), jnp.float32) for cs in grids]
     best = [jnp.full((h, w, len(cs)), -jnp.inf, jnp.float32)
             for cs in grids]

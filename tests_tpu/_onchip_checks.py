@@ -613,13 +613,14 @@ def _():
 @check("cubic_neighbourhoods_match_per_tap")
 def _():
     """The export's kernel ON THE CHIP, a 1024² cubic tile from a 1536²
-    gather window of a 2048² scene (NaN nodata, a nodata block, the tile
-    over the scene's top and left edges), fetching its taps as
-    neighbourhoods (`ops.warp._tap_pairs`) against the same program
-    with the per-tap `_resample_c` kept in `tests/test_cubic_neighbourhood.py`:
-    bit for bit.  Then a cubic export served over HTTP: every
-    `export.tile` span reads `tap_form=neighbourhood`, and `/debug`
-    counts each resident tile so."""
+    f32 gather window of a 2048² scene (NaN nodata, a nodata block, the
+    tile over the scene's top and left edges), fetching its taps as
+    neighbourhoods (`ops.warp._tap_pairs`; `tap_form` says so first)
+    against the same program with the per-tap `_resample_c` kept in
+    `tests/test_cubic_neighbourhood.py`: bit for bit.  Then a cubic
+    export served over HTTP: every `export.tile` span reads
+    `tap_form=neighbourhood`, and `/debug` counts each resident tile
+    so."""
     import asyncio
     import json
     import os
@@ -650,6 +651,12 @@ def _():
                         np.nan, 1.0, 0.0]], np.float32)
     args = (jnp.asarray(scene), jnp.asarray(ctrl.astype(np.float32)),
             jnp.asarray(params), jnp.asarray(np.array([0, 0], np.int32)))
+
+    # the program under test is the neighbourhood form: an f32 1536²
+    # window at depth 1 unfolds, so the comparison below is not the
+    # per-tap program against itself
+    assert warp.tap_form("cubic", args[0], (1536, 1536)) \
+        == "neighbourhood"
 
     def run():
         fn = warp_scenes_ctrl_scored.__wrapped__

@@ -26,10 +26,11 @@ Parts:
             against neighbourhoods (three-channel: rows of 8 C values,
             or 8 a channel).
 ``memory``  compile only: the temporary bytes of the export kernel and
-            of `render_rgba_ctrl` at the largest inputs the executor
-            hands them (a 4096² window, whole-scene stacks of depth 10,
-            three channels over four granules) and near the bound, in
-            the form the kernel picks and in the other.
+            of `render_rgba_ctrl` at the export cell's tile (a 1536² f32
+            window, depth 1), at the largest inputs the executor hands
+            them (a 4096² window, whole-scene stacks of depth 10, three
+            channels over four granules) and near the bound, in the form
+            the kernel picks and in the other.
 
 The last stdout line is the result as one JSON object; it also goes to
 ``<out>/result.json``.  The process takes the chip unless
@@ -113,7 +114,7 @@ class _Form:
 
     def __enter__(self):
         self.saved = (warp._unfolds, warp._tap_pairs)
-        warp._unfolds = lambda method, sources, n_out: \
+        warp._unfolds = lambda method, sources: \
             self.unfold and method == "cubic"
         if self.fetch is not None:
             warp._tap_pairs = self.fetch
@@ -217,7 +218,7 @@ def part_forms(res, small, reps):
             forms += ["slice_1x4", "slice_4x4"]
 
         def make():
-            unfold = warp._unfolds("cubic", [], 0)
+            unfold = warp._unfolds("cubic", [])
             return jax.jit(lambda s, n, r, c: warp._resample_c(
                 s, n, r, c, "cubic", unfold=unfold))
         _run_forms(f"forms:{np.dtype(dt).name}:{win}", forms, make, args,
@@ -292,7 +293,7 @@ def part_memory(res, small):
         w = None if win is None else (win // f, win // f)
         args = (stack, sds((2, h // 16 + 1, h // 16 + 1), jnp.float32),
                 sds((B, 11), jnp.float32), sds((2,), jnp.int32))
-        form = warp.tap_form("cubic", stack, w, (h, h))
+        form = warp.tap_form("cubic", stack, w)
         return form, lambda: jax.jit(lambda s, c, p, w0: fn(
             s, c, p, "cubic", 1, (h, h), 16, win=w, win0=w0)), args
 
@@ -306,14 +307,15 @@ def part_memory(res, small):
                 sds((G, 11), jnp.float32), sds((G, 3), jnp.float32),
                 sds((3,), jnp.float32), sds((G, 2), jnp.int32))
         form = "neighbourhood" if warp._unfolds(
-            "cubic", [((w[0], w[1], 3), dt)] * G, h * h) \
-            else "per_tap"
+            "cubic", [((w[0], w[1], 3), dt)] * G) else "per_tap"
         return form, lambda: jax.jit(lambda b, c, p, q, s, w0: fn(
             b, c, p, q, s, "cubic", (h, h), 16, True, 0, win=w,
             win0=w0)), args
 
     cases = {
-        # the export cell's tile
+        # the export cell's tile: the scene cache holds f32 scenes
+        "scored:f32:B1:win1536:out1024": scored(
+            1, (2048, 2048), 1536, 1024, jnp.float32),
         "scored:int16:B1:win1536:out1024": scored(
             1, (2048, 2048), 1536, 1024, jnp.int16),
         "scored:int16:B4:win1536:out1024": scored(
@@ -335,8 +337,8 @@ def part_memory(res, small):
             1, (4096, 4096), 2048, 1024, jnp.int16),
         "scored:int16:B2:win1536:out1024": scored(
             2, (2048, 2048), 1536, 1024, jnp.int16),
-        "scored:f32:B1:win1536:out1024": scored(
-            1, (2048, 2048), 1536, 1024, jnp.float32),
+        "scored:f32:B2:win1536:out1024": scored(
+            2, (2048, 2048), 1536, 1024, jnp.float32),
         "scored:f32:B1:win1024:out1024": scored(
             1, (2048, 2048), 1024, 1024, jnp.float32),
         "scored:int16:B4:win768:out256": scored(
